@@ -323,7 +323,7 @@ def cmd_broker(args) -> int:
                     healthz_port=args.healthz_port,
                     election_id=args.election_id).start()
     print(f"broker listening on {args.host}:{broker.port} "
-          f"(datastore={args.datastore})", flush=True)
+          f"(datastore={args.datastore}) {args.role_line}", flush=True)
     try:
         while True:
             time.sleep(1.0)
@@ -632,6 +632,15 @@ def main(argv=None) -> int:
     ag.set_defaults(fn=cmd_agent)
 
     args = ap.parse_args(argv)
+    if args.cmd == "broker" or (args.cmd != "agent"
+                                and getattr(args, "broker", None)):
+        # the broker and every client of one run their own JAX (merge
+        # fragments, result decode) on the CPU by ROLE, pinned before any
+        # backend starts: the agent process owns the chip(s)
+        import pixie_tpu
+
+        args.role_line = pixie_tpu.pin_cpu_role(
+            "broker" if args.cmd == "broker" else "client")
     return args.fn(args)
 
 

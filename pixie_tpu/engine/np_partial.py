@@ -290,9 +290,7 @@ def _native():
     _NATIVE_TRIED = True
     from pixie_tpu.native.build import load_native
 
-    lib = load_native()
-    if lib is not None and hasattr(lib, "px_hist_accumulate"):
-        _NATIVE = lib
+    _NATIVE = load_native()
     return _NATIVE
 
 

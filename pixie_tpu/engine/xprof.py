@@ -400,38 +400,21 @@ def measure_device_busy(fn, trace_dir: str | None = None,
     if not force_trace and jax.devices()[0].platform == "cpu":
         return measure_device_busy_sampled(fn)
     tmp = trace_dir or tempfile.mkdtemp(prefix="px_xprof_")
-    # Drive the XLA profiler session directly with the PYTHON tracer OFF:
-    # jax.profiler.trace's default options record every Python call, which
-    # inflates a ~10 ms production query to seconds — the measurement must
-    # not deform the thing it measures.  Device/host TraceMe events (the
-    # ones occupancy is computed from) come from the C++ host tracer.
-    sess = None
-    try:
-        from jax._src.lib import xla_client as _xc
-
-        opts = _xc.profiler.ProfileOptions()
-        opts.python_tracer_level = 0
-        opts.host_tracer_level = 2
-        sess = _xc.profiler.ProfilerSession(opts)
-    except Exception:
-        pass
+    # Trace with the PYTHON tracer OFF: the default options record every
+    # Python call, which inflates a ~10 ms production query to seconds — the
+    # measurement must not deform the thing it measures.  Device/host
+    # TraceMe events (the ones occupancy is computed from) come from the
+    # C++ host tracer.
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
     t0 = time.perf_counter()
     try:
-        if sess is None:
-            ctx = jax.profiler.trace(tmp)
-            ctx.__enter__()
-        out = fn()
-        # drain async dispatches so their device time lands inside the trace
-        try:
-            jax.block_until_ready(out)
-        except Exception:
-            pass
+        with jax.profiler.trace(tmp, profiler_options=opts):
+            # drain async dispatches so their device time lands in the trace
+            jax.block_until_ready(fn())
     finally:
         wall_s = time.perf_counter() - t0
-        if sess is not None:
-            sess.stop_and_export(tmp)
-        else:
-            ctx.__exit__(None, None, None)
     paths = glob.glob(os.path.join(tmp, "**", "*.xplane.pb"), recursive=True)
     parsed = parse_busy_ns(paths)
     if trace_dir is None:

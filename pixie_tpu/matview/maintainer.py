@@ -166,6 +166,11 @@ class StandingView:
         }
 
 
+#: executor stats a folding refresh reports under info["exec"]
+_FOLD_STAT_KEYS = ("device", "autotune", "resident_feeds", "h2d_bytes",
+                   "spmd_feeds", "feed_cache_hits")
+
+
 class MatViewManager:
     """Standing views over ONE table store (one agent's data)."""
 
@@ -345,7 +350,7 @@ class MatViewManager:
                 with trace.span("matview_refresh", view=view.key,
                                 since_row_id=lo, stop_row_id=hi):
                     try:
-                        delta, rows = self._compute_partial(
+                        delta, rows, scan = self._compute_partial(
                             view.prefix, lo, hi, route_scale, mesh)
                     except Exception:
                         return None
@@ -372,6 +377,7 @@ class MatViewManager:
                     "rebuilt": rebuilt,
                 }
                 if folded:
+                    out["exec"] = scan
                     # only re-walk the state when it actually changed: the
                     # size walk is O(groups) Python (str() per object key),
                     # too slow for the empty-delta poll hot path
@@ -479,7 +485,8 @@ class MatViewManager:
 
     def _compute_partial(self, pref: ViewPrefix, lo: int, hi: int,
                          route_scale: int, mesh) -> tuple:
-        """Run the prefix over rows [lo, hi) → (PartialAggBatch, rows)."""
+        """Run the prefix over rows [lo, hi) → (PartialAggBatch, rows,
+        the fold executor's where-it-ran stats)."""
         from pixie_tpu.engine.executor import PlanExecutor
 
         p = Plan()
@@ -500,7 +507,10 @@ class MatViewManager:
         ex = PlanExecutor(p, self.store, self.registry, mesh=mesh,
                           route_scale=route_scale)
         out = ex.run_agent()
-        return out["mv"], int(ex.stats.get("rows_scanned", 0))
+        # where the fold ran travels with the answer: a view refresh that
+        # scans the table is device work like any other query's
+        scan = {k: ex.stats[k] for k in _FOLD_STAT_KEYS if k in ex.stats}
+        return out["mv"], int(ex.stats.get("rows_scanned", 0)), scan
 
     def refresh_all(self) -> int:
         """Fold pending deltas for every registered view (the cron tick).

@@ -83,10 +83,7 @@ class Program:
 def _native():
     from pixie_tpu.native.build import load_native
 
-    lib = load_native()
-    if lib is not None and hasattr(lib, "px_wholeplan_run"):
-        return lib
-    return None
+    return load_native()
 
 
 def _resolve_filter(expr, env, dtypes, dicts):

@@ -100,6 +100,7 @@ PROFILES_RELATION = Relation.of(
     ("retries", DT.INT64),
     ("chunks_discarded", DT.INT64),
     ("degraded", DT.INT64),
+    ("ran_on", DT.STRING),
 )
 
 OP_STATS_RELATION = Relation.of(
@@ -342,6 +343,27 @@ def _agent_dicts(stats: dict) -> dict[str, dict]:
             if isinstance(s, dict)}
 
 
+def _ran_on(sources: dict[str, dict]) -> str:
+    """Where a query's chains ran, from each executor's stats["device"]
+    (and a folding view refresh's info["exec"]["device"]): one
+    "<who>=<platform>/<device_kind>[engine*n,...]" term per executor that
+    ran any, e.g. "pem0=tpu/TPU v5 lite[device_chain*1]".  A pure
+    standing-view serve runs no chain and contributes no term."""
+    terms = []
+    for who, s in sources.items():
+        dev = s.get("device") or {}
+        if not dev.get("engines"):
+            dev = ((s.get("matview") or {}).get("exec") or {}).get(
+                "device") or {}
+        if not dev.get("engines"):
+            continue
+        engines = ",".join(f"{e}*{n}"
+                           for e, n in sorted(dev["engines"].items()))
+        terms.append(f"{who}={dev.get('platform')}/"
+                     f"{dev.get('device_kind')}[{engines}]")
+    return " ".join(terms)
+
+
 def build_profile(query_id: str, tenant: str, service: str,
                   start_unix_ns: int, wall_ns: int, stats: dict,
                   status: str = "ok", error: str = "",
@@ -445,6 +467,7 @@ def build_profile(query_id: str, tenant: str, service: str,
         "retries": int(fault.get("rounds", 0) or 0),
         "chunks_discarded": int(fault.get("chunks_discarded", 0) or 0),
         "degraded": int(bool(serving.get("degraded"))),
+        "ran_on": _ran_on({**agents, "merger": merger}),
     }
     # adaptive-gate provenance rides the profile as a non-relation key
     # (write_rows only persists relation columns; the full decision rows
@@ -479,6 +502,8 @@ def _provenance_lines(profile: dict) -> list[str]:
             f"{profile['matview_eligible'] or profile['matview_hits']} "
             f"agent fragments served from standing view state{stale}, "
             f"{profile['matview_rows_folded']} delta rows folded")
+    if profile.get("ran_on"):
+        out.append(f"  ran on: {profile['ran_on']}")
     if profile["resident_feeds"]:
         out.append(f"  resident tier: {profile['resident_feeds']} "
                    f"device-resident feeds (h2d {profile['h2d_bytes']}B)")

@@ -50,6 +50,10 @@ def split_codes(gids: np.ndarray, cards: list[int]) -> list[np.ndarray]:
 #: Matmul-lowered segment sums are used on TPU up to this group count; the
 #: one-hot chunk buffer is CHUNK_ROWS × groups × 4B (≤ 256 MB at the cap).
 MATMUL_MAX_GROUPS = 1 << 10
+#: Documented bound: an f64 group sum (and so a mean) from the one-hot GEMM
+#: agrees with an exact f64 sum to this relative tolerance.  chip_smoke.py
+#: holds every f64 mean it reads on the chip to it.
+F64_SUM_RTOL = 1e-6
 #: Rows per scan chunk.  Chosen so an 8-bit limb chunk sum (≤ CHUNK_ROWS × 255)
 #: stays below 2^24 and is therefore EXACT in float32 MXU accumulation.
 CHUNK_ROWS = 1 << 16
@@ -91,7 +95,8 @@ def _use_matmul(n: int, num_groups: int) -> bool:
     )
 
 
-def _chunked_onehot_sum(v32: jax.Array, gid: jax.Array, num_groups: int) -> jax.Array:
+def _chunked_onehot_sum(v32: jax.Array, gid: jax.Array, num_groups: int,
+                        precision=None) -> jax.Array:
     """sum per group of float32 contributions via MXU: for each chunk,
     v[1,CH] @ one_hot[CH,G], accumulated across chunks in float64.
 
@@ -101,36 +106,70 @@ def _chunked_onehot_sum(v32: jax.Array, gid: jax.Array, num_groups: int) -> jax.
     f32 accumulation exact for bounded-magnitude contributions.
     """
     return _chunked_onehot_multi_sum(
-        lambda vv: vv[None, :], v32, gid, num_groups)[0]
+        lambda vv: vv[None, :], v32, gid, num_groups, precision)[0]
+
+
+def scan_sum(fn, xs):
+    """sum over i of fn(xs[i]) along the leading axis, in order, as one
+    `lax.scan`.  The carry starts at fn(xs[0]) instead of a fresh zeros
+    array: under `jax.shard_map` a scan's carry must have the same
+    varying-axes type going in as coming out, and zeros made inside the body
+    are replicated while fn's output varies over the mesh axis.  (0 + x is x,
+    so the sum is bit-identical to the zero-seeded one.)"""
+    first = jax.tree.map(lambda a: a[0], xs)
+    rest = jax.tree.map(lambda a: a[1:], xs)
+    out, _ = jax.lax.scan(lambda acc, x: (acc + fn(x), None), fn(first), rest)
+    return out
+
+
+#: Float lanes accumulate on the MXU in runs of this many rows (see
+#: _lanes_gemm); the runs are added in f64.
+FLOAT_RUN_ROWS = 1 << 12
+
+
+def _lanes_gemm(lanes: jax.Array, oh: jax.Array, precision) -> jax.Array:
+    """[L, CH] f32 lanes @ [CH, G] one-hot → [L, G] f64.
+
+    `precision` None: lanes whose values are bf16-exact (0/1 masks, 8-bit
+    limbs) — the MXU's default rounding of f32 operands to bf16 loses
+    nothing and f32 accumulation of a chunk is exact.  FLOAT lanes pass
+    Precision.HIGHEST, or every value is rounded to 8 significant bits
+    before it is summed; and because the MXU's f32 accumulator truncates —
+    a one-sided error that grows with the length of the run it accumulates
+    — they contract in runs of FLOAT_RUN_ROWS rows whose partial sums are
+    added in f64.  Same GEMM, same one-hot, only the accumulation is cut.
+    """
+    if precision is None:
+        return (lanes @ oh).astype(jnp.float64)
+    n_lanes, ch = lanes.shape
+    k = ch // FLOAT_RUN_ROWS if ch % FLOAT_RUN_ROWS == 0 else 1
+    runs = jnp.einsum("lkr,krg->klg", lanes.reshape(n_lanes, k, ch // k),
+                      oh.reshape(k, ch // k, oh.shape[1]),
+                      precision=precision)
+    return runs.astype(jnp.float64).sum(axis=0)
 
 
 def _chunked_onehot_multi_sum(lanes_fn, v, gid: jax.Array,
-                              num_groups: int) -> jax.Array:
+                              num_groups: int, precision=None) -> jax.Array:
     """[L, G] f64 per-group sums where lanes_fn(chunk) -> [L, CH] f32 lanes.
 
     The one-hot is the expensive part (CH x G f32 written/read from HBM per
     chunk); stacking all L lanes into ONE [L,CH] @ [CH,G] GEMM builds it
     once instead of L times — the 8-limb exact-int64 sum was measured
     HBM-bound on exactly this (8 one-hot rebuilds per column per chunk).
+    `precision`: None for bf16-exact lanes, Precision.HIGHEST for float
+    lanes (_lanes_gemm).
     """
     n = v.shape[0]
     ch = min(n, CHUNK_ROWS)
     c = n // ch
-    if c == 1:
-        oh = jax.nn.one_hot(gid, num_groups, dtype=jnp.float32)
-        return (lanes_fn(v) @ oh).astype(jnp.float64)
-    vc = v.reshape(c, ch)
-    gc = gid.reshape(c, ch)
-    L = lanes_fn(v[:ch]).shape[0]
 
-    def body(carry, xs):
+    def chunk(xs):
         vv, gg = xs
         oh = jax.nn.one_hot(gg, num_groups, dtype=jnp.float32)
-        return carry + (lanes_fn(vv) @ oh).astype(jnp.float64), None
+        return _lanes_gemm(lanes_fn(vv), oh, precision)
 
-    out, _ = jax.lax.scan(
-        body, jnp.zeros((L, num_groups), jnp.float64), (vc, gc))
-    return out
+    return scan_sum(chunk, (v.reshape(c, ch), gid.reshape(c, ch)))
 
 
 def masked_segment_sum(values: jax.Array, gid: jax.Array, num_groups: int, mask: jax.Array):
@@ -161,16 +200,19 @@ def masked_segment_sum(values: jax.Array, gid: jax.Array, num_groups: int, mask:
         return total.astype(v.dtype if d != jnp.dtype(jnp.int32) else jnp.int64)
     if d == jnp.dtype(jnp.float64):
         # hi/lo float32 split: v == hi + lo to ~2^-48 relative; residual error
-        # is the per-chunk f32 accumulation of hi (~1e-6 relative, documented).
+        # is the f32 accumulation of hi within a run (F64_SUM_RTOL).
         def hilo(vv):
             hi = vv.astype(jnp.float32)
             lo = (vv - hi.astype(jnp.float64)).astype(jnp.float32)
             return jnp.stack([hi, lo])
 
-        s = _chunked_onehot_multi_sum(hilo, v, gid, num_groups)
+        s = _chunked_onehot_multi_sum(hilo, v, gid, num_groups,
+                                      precision=jax.lax.Precision.HIGHEST)
         return s[0] + s[1]
     if d in (jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16)):
-        return _chunked_onehot_sum(v.astype(jnp.float32), gid, num_groups).astype(d)
+        return _chunked_onehot_sum(
+            v.astype(jnp.float32), gid, num_groups,
+            precision=jax.lax.Precision.HIGHEST).astype(d)
     return jax.ops.segment_sum(v, gid, num_segments=num_groups)
 
 

@@ -29,18 +29,13 @@ from pixie_tpu import flags as _flags
 
 AGENT_AXIS = "agents"
 
-#: jax moved shard_map out of experimental around 0.5; support both spellings
-#: (the tier-1 environment pins 0.4.x).
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # pragma: no cover - exercised on jax 0.4.x only
-    from jax.experimental.shard_map import shard_map
+shard_map = jax.shard_map
 
 #: XLA-CPU collectives rendezvous across ALL local participants; two
 #: concurrent multi-device programs in one process (concurrent agent
 #: executors in tests / LocalCluster) can split the intra-op thread pool
-#: between their rendezvous and deadlock (observed on jax 0.4.x: stuck
-#: AllReduceParticipantData waits).  Collective-bearing executions on a CPU
+#: between their rendezvous and deadlock (stuck AllReduceParticipantData
+#: waits).  Collective-bearing executions on a CPU
 #: mesh therefore serialize through one lock and block before releasing; on
 #: real accelerator meshes executions stay async and unlocked.
 _COLLECTIVE_EXEC_LOCK = _threading.Lock()

@@ -818,11 +818,25 @@ def main(argv=None):
         threading.Thread(target=_md_loop, args=(period, fn, mgr),
                          daemon=True).start()
 
+    # The agent OWNS this process's chip(s): start the backend now, say
+    # which, and register the width of the mesh the executor will actually
+    # shard over, so the planner widens shuffle joins to it.
+    import jax
+
+    from pixie_tpu.parallel.spmd import default_mesh
+
+    mesh = default_mesh()
+    n_devices = mesh.size if mesh is not None else 1
+    d0 = jax.devices()[0]
     agent = Agent(args.name, host, int(port), collector=collector,
                   heartbeat_s=args.heartbeat_s, auth_token=args.auth_token,
                   healthz_port=args.healthz_port,
-                  healthz_host=args.healthz_host)
+                  healthz_host=args.healthz_host, n_devices=n_devices)
     agent.start()
+    print(f"agent {args.name} registered with {args.broker} "
+          f"platform={d0.platform} device_kind={d0.device_kind!r} "
+          f"devices={len(jax.devices())} mesh_devices={n_devices}",
+          flush=True)
     try:
         while True:
             time.sleep(1.0)

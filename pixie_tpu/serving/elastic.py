@@ -152,11 +152,35 @@ def _pdeathsig_preexec() -> None:  # pragma: no cover — runs in the child
         pass  # non-Linux: atexit + terminate remain the cleanup path
 
 
+class ChipHeldError(RuntimeError):
+    """A child agent cannot be given a chip of its own: this process holds
+    (or would take) the host's chips, or every chip already has a child."""
+
+
+def _local_chips() -> int:
+    """Accelerator chips on this host, counted from their device files —
+    WITHOUT touching JAX: the spawning process is the CPU-pinned broker,
+    and enumerating through JAX would open the very chips the children
+    need."""
+    import glob
+
+    return (len(glob.glob("/dev/accel[0-9]*"))
+            or len(glob.glob("/dev/vfio/[0-9]*")))
+
+
 class ProcLauncher:
     """Spawn agents as real subprocesses (`python -m
     pixie_tpu.services.agent`), orphan-proof: PR_SET_PDEATHSIG ties each
     child's life to this process, the module atexit sweep covers clean
-    exits, and stop() terminates individually."""
+    exits, and stop() terminates individually.
+
+    Each child is given its devices EXPLICITLY (a chip belongs to one
+    process): on a host with chips, one free chip per child through
+    libtpu's TPU_VISIBLE_CHIPS + TPU_*_PROCESS_BOUNDS; on a host without,
+    the CPU platform by name.  spawn() raises ChipHeldError — which the supervisor
+    counts and logs as a spawn error — when this process's own JAX is not
+    pinned to the CPU (it holds, or on first use takes, every local chip)
+    or when no chip is free."""
 
     def __init__(self, broker_host: str, broker_port: int,
                  argv_for: Optional[Callable[[str], list]] = None,
@@ -164,7 +188,44 @@ class ProcLauncher:
         self.broker = (broker_host, int(broker_port))
         self._argv_for = argv_for
         self._extra_env = dict(extra_env or {})
+        #: chip index -> the live child that owns it; spawn() holds the
+        #: lock from picking a chip to recording its owner
+        self._chip_owner: dict[int, subprocess.Popen] = {}
+        self._spawn_lock = threading.Lock()
         _arm_atexit()
+
+    def _device_env(self) -> tuple[dict, Optional[int]]:
+        """→ (env that names the child's devices, the chip index taken)."""
+        n_chips = _local_chips()
+        if n_chips == 0:
+            return {"JAX_PLATFORMS": "cpu"}, None
+        import jax
+
+        if jax.config.jax_platforms != "cpu":
+            raise ChipHeldError(
+                f"this process's JAX is not pinned to the CPU "
+                f"(jax_platforms={jax.config.jax_platforms!r}): it holds, "
+                f"or on first use takes, this host's {n_chips} chip(s), so "
+                "a child agent would fail or hang on them — start the "
+                "broker through `cli broker` (CPU by role)")
+        for chip, owner in list(self._chip_owner.items()):
+            if owner.poll() is not None:
+                del self._chip_owner[chip]
+        free = [c for c in range(n_chips) if c not in self._chip_owner]
+        if not free:
+            raise ChipHeldError(
+                f"all {n_chips} chip(s) of this host belong to live "
+                "child agents")
+        chip = free[0]
+        # what libtpu 0.0.34 honours (read on a 4-chip v5e host, PR 21):
+        # the chip by index PLUS one-chip process bounds — with the index
+        # alone the first child locks every chip and the second dies on
+        # libtpu's multi-process lockfile
+        return {
+            "TPU_VISIBLE_CHIPS": str(chip),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+        }, chip
 
     def _argv(self, name: str) -> list:
         if self._argv_for is not None:
@@ -180,11 +241,19 @@ class ProcLauncher:
         # the flag registry is the single config surface on both sides of
         # the fork (parallel/shard_bench precedent)
         env.update(flags.env_exports())
-        env.update(self._extra_env)
-        p = subprocess.Popen(
-            self._argv(name), env=env,
-            stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL,
-            preexec_fn=_pdeathsig_preexec)
+        with self._spawn_lock:
+            device_env, chip = self._device_env()
+            env.update(device_env)
+            env.update(self._extra_env)
+            # stderr is inherited: a child that dies at start-up (a chip
+            # it could not open, an import error) must be visible in this
+            # process's log, not discarded
+            p = subprocess.Popen(
+                self._argv(name), env=env,
+                stdout=subprocess.DEVNULL, stderr=None,
+                preexec_fn=_pdeathsig_preexec)
+            if chip is not None:
+                self._chip_owner[chip] = p
         with _CHILDREN_LOCK:
             _CHILDREN[p.pid] = p
         return p

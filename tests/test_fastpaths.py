@@ -71,7 +71,7 @@ class TestDeviceMergeFinalizeParity:
     def test_grouped_all_udas(self):
         ts = _store()
         plan = _agg_plan(["service", "status"], VALUES)
-        _cmp(_run(plan, ts, "cpu"), _run(plan, ts, "tpu"),
+        _cmp(_run(plan, ts, "cpu"), _run(plan, ts, "device"),
              ["service", "status"])
 
     def test_multi_feed_merge(self, monkeypatch):
@@ -81,7 +81,7 @@ class TestDeviceMergeFinalizeParity:
         monkeypatch.setattr(X, "FEED_ROWS", 1 << 14)
         ts = _store(n=100_000)
         plan = _agg_plan(["service"], VALUES)
-        _cmp(_run(plan, ts, "cpu"), _run(plan, ts, "tpu"), ["service"])
+        _cmp(_run(plan, ts, "cpu"), _run(plan, ts, "device"), ["service"])
 
     def test_distributed_partial_state_not_finalized(self):
         """The partial wire path must ship raw mergeable state even on the

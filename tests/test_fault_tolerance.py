@@ -81,12 +81,16 @@ class _DieOnceAgent(Agent):
 
     def __init__(self, *a, **k):
         super().__init__(*a, **k)
+        self._dying = False
+        #: set once the connection is actually gone: tests that restart the
+        #: agent "after it died" must not race the chunk it sends first
+        #: (computing that chunk takes about as long as their grace sleep)
         self.died = False
 
     def _execute(self, meta):
-        if self.died:
+        if self._dying:
             return super()._execute(meta)
-        self.died = True
+        self._dying = True
         plan = Plan.from_dict(meta["plan"])
         ex = PlanExecutor(plan, self.store, self.registry)
         for channel, payload in ex.run_agent_stream(agg_chunk_groups=1):
@@ -98,6 +102,7 @@ class _DieOnceAgent(Agent):
             }))
             break
         self.conn.close()  # no exec_done, no exec_error: just gone
+        self.died = True
 
 
 class _StallDoneAgent(Agent):

@@ -195,7 +195,7 @@ class TestAutoGate:
         jd.reset_gate_for_testing()
         gate = jd.device_join_gate()
         assert gate["reason"] in ("native_cpu", "no_native_kernel",
-                                  "h2d_direct_attached", "h2d_tunneled",
+                                  "h2d_direct_attached", "h2d_below_threshold",
                                   "forced_on", "forced_off")
         assert "px_device_join_enabled" in metrics.render()
 

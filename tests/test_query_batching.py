@@ -566,13 +566,13 @@ def test_mq_gang_plain_bit_equal():
         [("q0", q1.plan), ("q1", q2.plan)], cluster.schemas())
     ap = cluster.planner.plan(fused).agent_plans["pem0"]
     ex = PlanExecutor(ap, cluster.stores["pem0"], None, mesh=None,
-                      force_backend="tpu")
+                      force_backend="device")
     out = ex.run_agent()
     assert ex.stats.get("mq_fused") == 2
     assert ex.stats.get("mq_waves", 0) >= 1
     flags.set_for_testing("PX_MQ_FUSION", 0)
     ex2 = PlanExecutor(ap, cluster.stores["pem0"], None, mesh=None,
-                       force_backend="tpu")
+                       force_backend="device")
     base = ex2.run_agent()
     for cid in out:
         assert out[cid].to_bytes() == base[cid].to_bytes(), cid
@@ -594,6 +594,6 @@ def test_mq_gang_auto_off_on_cpu_only_box():
         [("q0", q1.plan), ("q1", q2.plan)], cluster.schemas())
     ap = cluster.planner.plan(fused).agent_plans["pem0"]
     ex = PlanExecutor(ap, cluster.stores["pem0"], None, mesh=None,
-                      force_backend="tpu")
+                      force_backend="device")
     ex.run_agent()
     assert "mq_fused" not in ex.stats

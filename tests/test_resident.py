@@ -73,7 +73,7 @@ def _plan():
     return p
 
 
-def _run(ts, backend="tpu"):
+def _run(ts, backend="device"):
     # mesh=None: the single-device interactive deployment shape (the
     # 8-virtual-device test mesh would take the SPMD feed path, where the
     # resident tier intentionally does not engage)

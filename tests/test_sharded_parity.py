@@ -49,9 +49,9 @@ def test_sharded_agg_includes_dict_group_key():
     plan = shard_bench.agg_plan()
     mesh = make_mesh(N_DEV)
     sharded = PlanExecutor(plan, ts, mesh=mesh,
-                           force_backend="tpu").run()["output"]
+                           force_backend="device").run()["output"]
     single = PlanExecutor(plan, ts, mesh=None,
-                          force_backend="tpu").run()["output"]
+                          force_backend="device").run()["output"]
     assert "service" in sharded.dictionaries
     shard_bench.assert_bitequal(sharded, single)
 
@@ -67,12 +67,12 @@ def test_sharded_resident_warm_zero_h2d_and_delta_fold():
     plan = shard_bench.agg_plan()
     mesh = make_mesh(N_DEV)
 
-    cold = PlanExecutor(plan, ts, mesh=mesh, force_backend="tpu")
+    cold = PlanExecutor(plan, ts, mesh=mesh, force_backend="device")
     cold.run()
     assert cold.stats.get("resident_feeds") == 1
     assert cold.stats.get("h2d_bytes", 0) > 0  # admission uploads
 
-    warm = PlanExecutor(plan, ts, mesh=mesh, force_backend="tpu")
+    warm = PlanExecutor(plan, ts, mesh=mesh, force_backend="device")
     wout = warm.run()["output"]
     assert warm.stats.get("resident_feeds") == 1
     assert warm.stats.get("h2d_bytes", 0) == 0  # fully pinned, zero upload
@@ -87,7 +87,7 @@ def test_sharded_resident_warm_zero_h2d_and_delta_fold():
              "service": services[cols["service"]],
              "status": cols["status"], "bytes": cols["bytes"],
              "latency": cols["latency"]})
-    fold = PlanExecutor(plan, ts, mesh=mesh, force_backend="tpu")
+    fold = PlanExecutor(plan, ts, mesh=mesh, force_backend="device")
     fout = fold.run()["output"]
     # fed columns only: service i32 + status/bytes/latency i64/f64 (time_
     # is pruned — the agg has no time bounds)
@@ -95,7 +95,7 @@ def test_sharded_resident_warm_zero_h2d_and_delta_fold():
     assert fold.stats.get("h2d_bytes") == delta_bytes
     assert resident.stats["folds"] >= 1
     single = PlanExecutor(plan, ts, mesh=None,
-                          force_backend="tpu").run()["output"]
+                          force_backend="device").run()["output"]
     shard_bench.assert_bitequal(fout, single)
     assert wout.num_rows <= fout.num_rows  # sanity: delta visible
 
@@ -108,8 +108,8 @@ def test_sharded_and_single_device_entries_coexist():
     ts = shard_bench.build_store(2 * batch, batch_rows=batch)
     plan = shard_bench.agg_plan()
     mesh = make_mesh(N_DEV)
-    PlanExecutor(plan, ts, mesh=mesh, force_backend="tpu").run()
-    PlanExecutor(plan, ts, mesh=None, force_backend="tpu").run()
+    PlanExecutor(plan, ts, mesh=mesh, force_backend="device").run()
+    PlanExecutor(plan, ts, mesh=None, force_backend="device").run()
     stats = resident.tier_stats()
     assert stats["entries"] == 2  # one sharded, one single-device
     assert stats["admissions"] == 2

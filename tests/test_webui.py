@@ -211,7 +211,7 @@ def test_profiles_page_renders_every_panel():
     observe.write_rows(ts, observe.AUTOTUNE_TABLE, [{
         "time_": 10 ** 15, "query_id": "q0", "gate": "cpu_crossover",
         "plan_class": "agg", "size_bucket": "4^9", "arm": "cpu",
-        "static_arm": "tpu", "source": "model", "model_ms": 2.0,
+        "static_arm": "device", "source": "model", "model_ms": 2.0,
         "static_ms": 9.0, "observed_ms": 2.1, "reason": ""}])
     srv = LiveServer(local_runner(ts)).start()
     try:
