@@ -54,6 +54,7 @@ class SVal:
     origin: Optional[tuple] = None
 
 
+@jax.named_scope("px.md_lookup")
 def apply_lut(lut: jax.Array, codes: jax.Array, fill):
     """Safe LUT gather: codes may be -1 (null / no-translation) → fill.
     An EMPTY lut (no dictionary values yet — empty table) yields all-fill."""
@@ -437,9 +438,11 @@ class ExprCompiler:
 
             def build(env, name=name, b=b, lo=lo, hi=hi, oob=oob):
                 x = b(env)
-                in_dom = (x >= lo) & (x <= hi)
-                idx = jnp.clip(x - lo, 0, hi - lo).astype(jnp.int32)
-                return jnp.where(in_dom, jnp.take(env["luts"][name], idx), oob)
+                with jax.named_scope("px.md_lookup"):
+                    in_dom = (x >= lo) & (x <= hi)
+                    idx = jnp.clip(x - lo, 0, hi - lo).astype(jnp.int32)
+                    return jnp.where(in_dom,
+                                     jnp.take(env["luts"][name], idx), oob)
 
             return SVal(DT.STRING, build, out_dict)
         np_out = STORAGE_DTYPE[udf.out_type]
@@ -449,10 +452,11 @@ class ExprCompiler:
 
         def build_n(env, name=name, b=b, lo=lo, hi=hi, oob_v=oob_v):
             x = b(env)
-            in_dom = (x >= lo) & (x <= hi)
-            idx = jnp.clip(x - lo, 0, hi - lo).astype(jnp.int32)
-            return jnp.where(in_dom, jnp.take(env["luts"][name], idx),
-                             jnp.asarray(oob_v, dtype=lut.dtype))
+            with jax.named_scope("px.md_lookup"):
+                in_dom = (x >= lo) & (x <= hi)
+                idx = jnp.clip(x - lo, 0, hi - lo).astype(jnp.int32)
+                return jnp.where(in_dom, jnp.take(env["luts"][name], idx),
+                                 jnp.asarray(oob_v, dtype=lut.dtype))
 
         return SVal(udf.out_type, build_n)
 

@@ -111,8 +111,79 @@ FEED_ROWS = _flags.define_int(
 # identical LUTs.  Data-dependent aggregation state (intdevice key sets, window
 # origins) is covered by including the table's rows_written in agg signatures.
 import collections as _collections
+import functools as _functools
+import inspect as _inspect
 import json as _json
 import threading as _threading
+
+# ------------------------------------------------------------ compile telemetry
+#: jax's compile phases as it reports them (jax.monitoring duration events)
+_COMPILE_KINDS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+}
+_CACHE_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+#: per thread: the stats dict of the executor running there (`sink`), and
+#: whether jax read the program being compiled from its persistent cache
+_COMPILE_TLS = _threading.local()
+
+
+def _on_jax_duration(event: str, secs: float, **_kw) -> None:
+    """jax reports a compile phase on the thread that compiled: add it to
+    the exec_stats of the query running there (`compile_s`, `compiles`)
+    and, under an active trace, record it as a `jax_compile` span.  jax
+    calls this only when it compiles, so a warm query pays nothing."""
+    if event == _CACHE_RETRIEVAL_EVENT:
+        _COMPILE_TLS.cache_hit = True
+        return
+    kind = _COMPILE_KINDS.get(event)
+    stats = getattr(_COMPILE_TLS, "sink", None)
+    if kind is None or stats is None:
+        return
+    end_ns = _time.time_ns()
+    stats["compile_s"] += secs
+    attrs = {"kind": kind}
+    if kind == "backend_compile":
+        stats["compiles"] += 1
+        # the retrieval event comes from inside the backend-compile phase
+        attrs["cache_hit"] = getattr(_COMPILE_TLS, "cache_hit", False)
+        _COMPILE_TLS.cache_hit = False
+    from pixie_tpu import trace
+
+    dur_ns = int(secs * 1e9)
+    trace.event_span("jax_compile", end_ns - dur_ns, dur_ns, **attrs)
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+
+
+@_contextlib.contextmanager
+def _compile_sink(stats: dict):
+    """`stats` takes what jax compiles on this thread, for the block."""
+    prev = getattr(_COMPILE_TLS, "sink", None)
+    _COMPILE_TLS.sink = stats
+    try:
+        yield
+    finally:
+        _COMPILE_TLS.sink = prev
+
+
+def _compile_scoped(fn):
+    """Run a PlanExecutor entry point (a method or a generator method)
+    with the executor's stats as this thread's compile sink."""
+    if _inspect.isgeneratorfunction(fn):
+        @_functools.wraps(fn)
+        def scoped(self, *args, **kwargs):
+            with _compile_sink(self.stats):
+                yield from fn(self, *args, **kwargs)
+    else:
+        @_functools.wraps(fn)
+        def scoped(self, *args, **kwargs):
+            with _compile_sink(self.stats):
+                return fn(self, *args, **kwargs)
+    return scoped
+
 
 _KERNEL_CACHE: "_collections.OrderedDict[str, tuple]" = _collections.OrderedDict()
 _KERNEL_CACHE_MAX = 128
@@ -552,6 +623,7 @@ class ChainKernel:
     def luts(self) -> dict[str, np.ndarray]:
         return self.ctx.ec.luts
 
+    @jax.named_scope("px.time_mask")
     def _base_mask(self, env, n, n_valid, t_lo, t_hi):
         mask = jnp.arange(n) < n_valid
         if self.time_col is not None and self.time_col in env["cols"]:
@@ -709,11 +781,11 @@ class ChainKernel:
                 key_builders.append(k.key_sval.build)
             else:  # window: origin is a runtime scalar in luts (streaming)
                 sv, w, t0name = k.key_sval, k.width, k.lut_name
-                key_builders.append(
+                key_builders.append(jax.named_scope("px.window_bin")(
                     lambda env, sv=sv, w=w, t0name=t0name: (
                         sv.build(env) // w - env["luts"][t0name][0]
                     ).astype(jnp.int32)
-                )
+                ))
         cards = [k.card for k in keys]
 
         def step(cols, n_valid, t_lo, t_hi, limit_remaining, luts, state):
@@ -1110,7 +1182,10 @@ class PlanExecutor:
         #: reference: GRPCRouter demuxing inbound streams, grpc_router.h:52)
         self.inputs: dict[str, HostBatch] = inputs or {}
         self._materialized: dict[int, HostBatch] = {}
-        self.stats = {"rows_scanned": 0, "rows_output": 0, "batches": 0, "compile_s": 0.0}
+        #: compile_s / compiles: jax trace + lower + backend-compile seconds
+        #: and backend compiles of this query (_on_jax_duration fills them)
+        self.stats = {"rows_scanned": 0, "rows_output": 0, "batches": 0,
+                      "compile_s": 0.0, "compiles": 0}
         #: per-kernel / per-blocking-op timing records (the reference's
         #: ExecNodeStats analog, exec_node.h:41; grain = compiled unit).
         self.op_stats: list[dict] = []
@@ -1197,7 +1272,8 @@ class PlanExecutor:
             return jax.default_device(_cpu_device())
         return _contextlib.nullcontext()
 
-    def _note_engine(self, engine: str) -> None:
+    def _note_engine(self, engine: str, rec: Optional[dict] = None,
+                     src=None) -> None:
         """Record which engine ran one of this query's chains, and the
         platform and device_kind its kernels were dispatched to, in
         stats["device"] — so exec_stats, the flight recorder and EXPLAIN
@@ -1206,7 +1282,11 @@ class PlanExecutor:
         process's default device or mesh), "xla_cpu_chain" (the same
         chain pinned to XLA-CPU), "np_partial", "wholeplan" (host numpy /
         native loops; no kernel is dispatched).  "platform" names the
-        accelerator-route device when any chain ran there, else "cpu"."""
+        accelerator-route device when any chain ran there, else "cpu".
+        `rec`, the chain's _timed frame, takes the engine and the routing
+        decision `src` ran under as the attributes of its trace span."""
+        if rec is not None:
+            rec["span"] = {"engine": engine, **self._route_attrs(src)}
         dev = self.stats.setdefault("device", {})
         engines = dev.setdefault("engines", {})
         engines[engine] = engines.get(engine, 0) + 1
@@ -1222,9 +1302,24 @@ class PlanExecutor:
             d = _cpu_device()
             dev["platform"], dev["device_kind"] = d.platform, d.device_kind
 
-    def _note_chain(self, src) -> None:
+    def _note_chain(self, src, rec: Optional[dict] = None) -> None:
         self._note_engine("xla_cpu_chain" if self._backend_for(src) == "cpu"
-                          else "device_chain")
+                          else "device_chain", rec, src)
+
+    def _route_attrs(self, src) -> dict:
+        """What the router decided for a chain over `src`: the arm it ran
+        on and, where the adaptive model took the decision, its source
+        (`model`/`static`/`cold`/`explore`/`fallback`), size bucket and
+        number.  A chain span with source=explore is a router probe."""
+        n = _src_rows(src) if src is not None else None
+        if n is None:
+            return {}
+        attrs = {"arm": self._backend_for(src), "rows": n}
+        dec = self._at_route.get(_autotune.size_bucket(n * self.route_scale))
+        if dec is not None:
+            attrs.update(source=dec["source"], size_bucket=dec["size_bucket"],
+                         decision_n=dec["n"])
+        return attrs
 
     # -------------------------------------------------------------- exec stats
     @_contextlib.contextmanager
@@ -1272,7 +1367,8 @@ class PlanExecutor:
             if t0 is None:
                 continue
             trace.event_span(rec["label"], t0, rec["wall_ns"],
-                             rows_out=rec.get("rows_out", 0))
+                             rows_out=rec.get("rows_out", 0),
+                             **rec.get("span", {}))
 
     def _chain_label(self, head, chain, terminal: str = "") -> str:
         parts = []
@@ -1409,6 +1505,33 @@ class PlanExecutor:
 
     def _feed(self, src, names, cap, spmd: bool = False,
               backend: str = "device"):
+        """`_feed_batches`, and under an active trace one `feed` span a
+        chain: from the first batch asked for to the last feed yielded,
+        with how many feeds there were, how many of them the resident tier
+        served and the bytes that crossed host->device."""
+        from pixie_tpu import trace
+
+        inner = self._feed_batches(src, names, cap, spmd, backend)
+        if trace.current() is None:
+            yield from inner
+            return
+        t0 = t_last = _time.time_ns()
+        feeds = 0
+        resident0 = self.stats.get("resident_feeds", 0)
+        h2d0 = self.stats.get("h2d_bytes", 0)
+        try:
+            for item in inner:
+                feeds += 1
+                t_last = _time.time_ns()
+                yield item
+        finally:
+            trace.event_span(
+                "feed", t0, t_last - t0, feeds=feeds,
+                resident=self.stats.get("resident_feeds", 0) - resident0,
+                h2d_bytes=self.stats.get("h2d_bytes", 0) - h2d0)
+
+    def _feed_batches(self, src, names, cap, spmd: bool = False,
+                      backend: str = "device"):
         """Yield (cols np dict padded, n_valid) host batches.
 
         Cursor batches (storage granularity) are coalesced into ~FEED_ROWS
@@ -1728,7 +1851,7 @@ class PlanExecutor:
             from collections import deque
 
             with self._timed(label, op_ids) as rec, self._device_ctx(src):
-                self._note_chain(src)
+                self._note_chain(src, rec)
                 has_limit = kern.has_limit
                 remaining = kern.init_limits()
                 computing: deque = deque()  # (outs, cnt): compute dispatched
@@ -2027,9 +2150,9 @@ class PlanExecutor:
 
             upd = jax.jit(upd, donate_argnums=(0,))
             _cache_put(_json.dumps(upd_key), (upd, udas))
-        with self._timed(f"sorted_agg(by={op.groups}, G={G})", [op.id]), \
-                self._device_ctx(hb):
-            self._note_chain(hb)
+        with self._timed(f"sorted_agg(by={op.groups}, G={G})",
+                         [op.id]) as rec, self._device_ctx(hb):
+            self._note_chain(hb, rec)
             # state init happens inside the device context so the donated
             # accumulators live on the dispatch device (CPU for small batches)
             state = {name: uda.init(Gb, in_dt)
@@ -2267,6 +2390,7 @@ class PlanExecutor:
         # dominant cost at this scale); the SPMD path stays on the mesh.
         dev_ctx = (self._device_ctx(src)
                    if spmd_step is None else _contextlib.nullcontext())
+        compile_s0 = self.stats["compile_s"]
         with dev_ctx:
             t_lo, t_hi = _time_bounds(head)
             luts = {**kern.luts, **lut_over} if lut_over else kern.luts
@@ -2290,7 +2414,7 @@ class PlanExecutor:
                         np_partial.value_args(kern, op))
                     self.stats["np_fast_polls"] = self.stats.get(
                         "np_fast_polls", 0) + 1
-                    self._note_engine("np_partial")
+                    self._note_engine("np_partial", rec, src)
                 elif (prog := self._wholeplan_program(
                         sig, kern, chain, op, keys, init_specs, dtypes,
                         dicts, names, time_col, src, val_dicts,
@@ -2305,12 +2429,12 @@ class PlanExecutor:
                                             init_specs, t_lo, t_hi, luts)
                     self.stats["wholeplan_native"] = self.stats.get(
                         "wholeplan_native", 0) + 1
-                    self._note_engine("wholeplan")
+                    self._note_engine("wholeplan", rec, src)
                 else:
                     if spmd_step is not None:
-                        self._note_engine("device_chain")
+                        self._note_engine("device_chain", rec, src)
                     else:
-                        self._note_chain(src)
+                        self._note_chain(src, rec)
                     state_np = self._agg_feed_loop(
                         kern, step, partial_step, merge_fn, spmd_step,
                         init_specs, num_groups,
@@ -2327,6 +2451,10 @@ class PlanExecutor:
                 if dec is not None:
                     _autotune.MODEL.observe_decision(
                         dec, rec["wall_ns"] / 1e9)
+                    # how much of observed_ms was jax compiling (recorded
+                    # beside it; the model does not read it)
+                    dec["compile_ms"] = round(
+                        (self.stats["compile_s"] - compile_s0) * 1e3, 3)
         return keys, udas, state_np, seen_name, in_types, val_dicts
 
     def _wholeplan_program(self, sig, kern, chain, op, keys, init_specs,
@@ -2961,6 +3089,7 @@ class PlanExecutor:
         self.stats["mq_fused"] = self.stats.get("mq_fused", 0) + len(setups)
         return out
 
+    @_compile_scoped
     def run_agent(self) -> dict:
         """Execute an AGENT plan: returns {channel: payload} where payload is a
         HostBatch (rows channels) or PartialAggBatch (agg_state channels)."""
@@ -3016,6 +3145,7 @@ class PlanExecutor:
         self._emit_op_spans()
         return out
 
+    @_compile_scoped
     def run_agent_stream(self, agg_chunk_groups: int = 0):
         """Execute an AGENT plan as a chunk stream: yields (channel, payload)
         in wave order — one HostBatch per readback wave for rows channels
@@ -3392,6 +3522,7 @@ class PlanExecutor:
             self.stats["otel_spans"] = self.stats.get("otel_spans", 0) + n_spans
 
     # -------------------------------------------------------------------- run
+    @_compile_scoped
     def run(self) -> dict[str, QueryResult]:
         results = {}
         t0 = _time.perf_counter_ns()

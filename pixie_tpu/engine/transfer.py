@@ -18,7 +18,11 @@ Two shapes:
 
 Each wave that actually touches device arrays is self-telemetered: its
 latency lands in the px_readback_wave_seconds histogram and, under an active
-trace, as a `readback_wave` span.  Pipelined waves additionally carry the
+trace, as a `readback_wave` span.  In a process whose default backend is an
+accelerator, a wave whose arrays all live on XLA-CPU is the wait for a chain
+the executor pinned there (engine `xla_cpu_chain`): it is a `cpu_chain_wait`
+span and stays out of the histogram, so that `readback_wave` there means a
+pull from the accelerator.  Pipelined waves additionally carry the
 overlap split: `overlap_ns` (wall time between copy start and wait —
 compute covered by the in-flight transfer) and `block_ns` (time the host
 actually stalled on the transfer).  overlap/(overlap+block) is the overlap
@@ -152,9 +156,30 @@ def reset_probe_cache_for_testing() -> None:
 WAVE_BOUNDS = (0.001, 0.005, 0.02, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0)
 
 
-def _observe_wave(t0_ns: int, dt_ns: int, n_dev: int, **attrs) -> None:
+def _accelerator_process() -> bool:
+    """Whether this process's default backend is an accelerator: only there
+    is a pull from XLA-CPU arrays a wait for a chain the executor pinned to
+    the CPU, and not the readback itself."""
+    return jax.default_backend() != "cpu"
+
+
+def _cpu_chain(leaves) -> bool:
+    """Whether the wave's device leaves all live on XLA-CPU in an
+    accelerator process (engine `xla_cpu_chain`): blocking on them waits
+    for the chain to compute, nothing crosses a link."""
+    return _accelerator_process() and all(
+        d.platform == "cpu" for leaf in leaves if isinstance(leaf, jax.Array)
+        for d in leaf.devices())
+
+
+def _observe_wave(t0_ns: int, dt_ns: int, n_dev: int, cpu_chain: bool,
+                  **attrs) -> None:
     from pixie_tpu import metrics, trace
 
+    if cpu_chain:
+        trace.event_span("cpu_chain_wait", t0_ns, dt_ns, leaves=n_dev,
+                         **attrs)
+        return
     metrics.histogram_observe(
         "px_readback_wave_seconds", dt_ns / 1e9, WAVE_BOUNDS,
         help_="device->host readback wave latency (overlapped pull)")
@@ -180,7 +205,7 @@ def pull(tree):
         for leaf in leaves
     ]
     dt_ns = time.time_ns() - t0
-    _observe_wave(t0, dt_ns, n_dev)
+    _observe_wave(t0, dt_ns, n_dev, _cpu_chain(leaves))
     return jax.tree.unflatten(treedef, out)
 
 
@@ -218,7 +243,7 @@ class AsyncPull:
         if self._n_dev:
             _observe_wave(
                 self._t_submit, t_done - self._t_submit, self._n_dev,
-                overlap_ns=t_wait - self._t_submit,
+                _cpu_chain(self._leaves), overlap_ns=t_wait - self._t_submit,
                 block_ns=t_done - t_wait,
             )
         self._out = jax.tree.unflatten(self._treedef, out)

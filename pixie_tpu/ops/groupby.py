@@ -172,6 +172,7 @@ def _chunked_onehot_multi_sum(lanes_fn, v, gid: jax.Array,
     return scan_sum(chunk, (v.reshape(c, ch), gid.reshape(c, ch)))
 
 
+@jax.named_scope("px.groupby_sum")
 def masked_segment_sum(values: jax.Array, gid: jax.Array, num_groups: int, mask: jax.Array):
     v = jnp.where(mask, values, jnp.zeros((), dtype=values.dtype))
     if not _use_matmul(v.shape[0], num_groups):
@@ -189,14 +190,16 @@ def masked_segment_sum(values: jax.Array, gid: jax.Array, num_groups: int, mask:
         u = v.astype(jnp.uint64)
         shifts = jnp.arange(8, dtype=jnp.uint64) * jnp.uint64(8)
 
+        @jax.named_scope("px.int_limbs")
         def limbs(uu):
             return ((uu[None, :] >> shifts[:, None])
                     & jnp.uint64(0xFF)).astype(jnp.float32)
 
         s = _chunked_onehot_multi_sum(limbs, u, gid, num_groups)  # [8, G]
-        total = jnp.zeros((num_groups,), dtype=jnp.uint64)
-        for k in range(8):
-            total = total + (s[k].astype(jnp.uint64) << (8 * k))
+        with jax.named_scope("px.int_limbs"):
+            total = jnp.zeros((num_groups,), dtype=jnp.uint64)
+            for k in range(8):
+                total = total + (s[k].astype(jnp.uint64) << (8 * k))
         return total.astype(v.dtype if d != jnp.dtype(jnp.int32) else jnp.int64)
     if d == jnp.dtype(jnp.float64):
         # hi/lo float32 split: v == hi + lo to ~2^-48 relative; residual error
@@ -216,6 +219,7 @@ def masked_segment_sum(values: jax.Array, gid: jax.Array, num_groups: int, mask:
     return jax.ops.segment_sum(v, gid, num_segments=num_groups)
 
 
+@jax.named_scope("px.groupby_count")
 def masked_segment_count(gid: jax.Array, num_groups: int, mask: jax.Array) -> jax.Array:
     """Rows per group (int64, exact): f32 one-hot matmul of the mask on TPU
     (per-chunk counts ≤ CHUNK_ROWS are exact in f32), scatter elsewhere."""
@@ -227,12 +231,14 @@ def masked_segment_count(gid: jax.Array, num_groups: int, mask: jax.Array) -> ja
     return jax.ops.segment_sum(ones, gid, num_segments=num_groups)
 
 
+@jax.named_scope("px.groupby_min")
 def masked_segment_min(values: jax.Array, gid: jax.Array, num_groups: int, mask: jax.Array):
     big = _identity_for(values.dtype, "min")
     v = jnp.where(mask, values, big)
     return jax.ops.segment_min(v, gid, num_segments=num_groups)
 
 
+@jax.named_scope("px.groupby_max")
 def masked_segment_max(values: jax.Array, gid: jax.Array, num_groups: int, mask: jax.Array):
     small = _identity_for(values.dtype, "max")
     v = jnp.where(mask, values, small)

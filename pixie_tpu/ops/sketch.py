@@ -98,6 +98,7 @@ class LogHistogram:
     def _log_gamma(self):
         return math.log(self.gamma)
 
+    @jax.named_scope("px.sketch_bin")
     def bin_index(self, v: jax.Array) -> jax.Array:
         """Bin index per value (device)."""
         lg = jnp.log(jnp.maximum(v.astype(jnp.float32), self.min_value)) / self._log_gamma()
@@ -143,6 +144,7 @@ class LogHistogram:
             return self._update_gemm(hist, gid, bins, mask, num_groups)
         return self._update_segment(hist, gid, bins, mask, num_groups)
 
+    @jax.named_scope("px.sketch_update_segment")
     def _update_segment(self, hist, gid, bins, mask, num_groups):
         """Flat scatter-add (XLA-CPU native path)."""
         flat_idx = gid.astype(jnp.int32) * self.width + bins
@@ -150,6 +152,7 @@ class LogHistogram:
         add = jax.ops.segment_sum(ones, flat_idx, num_segments=num_groups * self.width)
         return hist + add.reshape(num_groups, self.width)
 
+    @jax.named_scope("px.sketch_update_sorted")
     def _update_sorted(self, hist, gid, bins, mask, num_groups):
         """Sorted segment-count: values-only sort of the flat cell key, then
         per-cell counts from a searchsorted diff over the STATIC cell edges.
@@ -172,6 +175,7 @@ class LogHistogram:
         cnt = (bounds[1:] - bounds[:-1]).astype(hist.dtype)
         return hist + cnt.reshape(num_groups, self.width)
 
+    @jax.named_scope("px.sketch_update_gemm")
     def _update_gemm(self, hist, gid, bins, mask, num_groups):
         """Limb-factored one-hot GEMM (TPU): bin = digit·LANES + lane; the
         lane is one-hot, the digit is the VALUE (1 or DIGIT) — one narrow

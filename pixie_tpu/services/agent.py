@@ -371,7 +371,8 @@ class Agent:
             # must not queue execute/heartbeat frames behind telemetry.
             threading.Thread(
                 target=self._write_shipped_spans,
-                args=(payload.get("spans") or [],), daemon=True,
+                args=(payload.get("spans") or [], payload.get("trace")),
+                daemon=True,
                 name=f"pixie-agent-spans-{self.name}",
             ).start()
         elif msg == "telemetry_rows":
@@ -379,7 +380,8 @@ class Agent:
             # stats, sampled metrics, SLO alerts): same contract as spans
             threading.Thread(
                 target=self._write_telemetry_rows,
-                args=(payload.get("table"), payload.get("rows") or []),
+                args=(payload.get("table"), payload.get("rows") or [],
+                      payload.get("trace")),
                 daemon=True,
                 name=f"pixie-agent-telemetry-{self.name}",
             ).start()
@@ -559,8 +561,13 @@ class Agent:
         if sem is not None:
             with self._windows_lock:
                 self._windows[wkey] = sem
+        #: the exec root's own wire context: telemetry_flush is recorded
+        #: under it once the root has closed
+        exec_ctx = None
         try:
             with cm:
+                exec_ctx = trace.wire_context()
+                t_decode = time.time_ns()
                 plan = Plan.from_dict(meta["plan"])
                 exec_store = self.store
                 if serve_for:
@@ -597,6 +604,9 @@ class Agent:
                     stream = ex.run_agent_stream(
                         agg_chunk_groups=int(
                             flags.get("PL_STREAM_AGG_CHUNK_GROUPS")))
+                trace.event_span("plan_decode", t_decode,
+                                 time.time_ns() - t_decode,
+                                 matview=served is not None)
                 t0 = time.perf_counter()
                 # Chunk stream: each wave/slice ships as its own frame the
                 # moment the executor yields it, so the broker's incremental
@@ -604,7 +614,10 @@ class Agent:
                 # instead of queueing behind a terminal result frame.
                 counts: dict[str, int] = {}
                 stalled = False
+                # result_send: first chunk's encode -> last chunk's send
+                t_send = t_sent = sent_bytes = 0
                 for channel, payload in stream:
+                    t_send = t_send or time.time_ns()
                     if not stalled:
                         stalled = not self._await_window(sem)
                     seq = counts.get(channel, 0)
@@ -620,6 +633,12 @@ class Agent:
                     else:
                         raise TypeError(f"unexpected payload {type(payload)}")
                     self.conn.send(frame)
+                    sent_bytes += len(frame)
+                    t_sent = time.time_ns()
+                if t_send:
+                    trace.event_span("result_send", t_send, t_sent - t_send,
+                                     chunks=sum(counts.values()),
+                                     bytes=sent_bytes)
                 stats = dict(ex.stats) if ex is not None else {}
                 if mv_info is not None:
                     stats["matview"] = mv_info
@@ -632,7 +651,7 @@ class Agent:
                 stats["exec_s"] = time.perf_counter() - t0
             # spans persist BEFORE the ack: when exec_done lands at the
             # broker, this query's spans are already scannable
-            self._flush_trace()
+            self._flush_trace(exec_ctx)
             from pixie_tpu.services.broker import _jsonable
 
             self.conn.send(wire.encode_json({
@@ -645,7 +664,7 @@ class Agent:
                 "chunks": counts,
             }))
         except Exception as e:
-            self._flush_trace()
+            self._flush_trace(exec_ctx)
             self.conn.send(wire.encode_json({
                 "msg": "exec_error", "req_id": req_id, "agent": src_name,
                 "qtoken": qtoken, "attempt": attempt, "error": str(e),
@@ -680,7 +699,8 @@ class Agent:
                 return False
         return False
 
-    def _write_shipped_spans(self, rows: list) -> None:
+    def _write_shipped_spans(self, rows: list, tctx=None) -> None:
+        t0 = time.time_ns()
         try:
             trace.write_spans(self.store, rows)
         except Exception:
@@ -689,8 +709,11 @@ class Agent:
             _metrics.counter_inc(
                 "px_agent_span_write_errors_total",
                 help_="spans that failed to persist to the local store")
+        self._telemetry_span("telemetry_write", tctx, t0, len(rows),
+                             trace.SPANS_TABLE)
 
-    def _write_telemetry_rows(self, table, rows: list) -> None:
+    def _write_telemetry_rows(self, table, rows: list, tctx=None) -> None:
+        t0 = time.time_ns()
         try:
             if table in observe.SELF_TABLES:
                 observe.write_rows(self.store, str(table), rows)
@@ -701,19 +724,39 @@ class Agent:
                 "px_agent_telemetry_write_errors_total",
                 help_="flight-recorder rows that failed to persist to the "
                       "local store")
+        self._telemetry_span("telemetry_write", tctx, t0, len(rows),
+                             str(table))
 
-    def _flush_trace(self) -> None:
+    def _flush_trace(self, tctx=None) -> None:
         """Persist buffered spans; never let telemetry failure block the
         exec_done/exec_error ack (an unacked query stalls the broker for
         the full query timeout)."""
+        if not trace.enabled():
+            # no query records a span now; what the last flush's own span
+            # left in the buffer waits for tracing to come back
+            return
+        t0 = time.time_ns()
+        rows = []
         try:
-            self.tracer.flush(store=self.store)
+            rows = self.tracer.flush(store=self.store)
         except Exception:
             from pixie_tpu import metrics as _metrics
 
             _metrics.counter_inc(
                 "px_agent_span_write_errors_total",
                 help_="spans that failed to persist to the local store")
+        self._telemetry_span("telemetry_flush", tctx, t0, len(rows),
+                             trace.SPANS_TABLE)
+
+    def _telemetry_span(self, name: str, tctx, t0_unix_ns: int, rows: int,
+                        table: str) -> None:
+        """What a self-telemetry write cost, as a span of the query that
+        caused it (`tctx`, its wire context).  Recorded after the write it
+        measures, so it is persisted with the next flush: one span a
+        write, and no write for the span."""
+        trace.remote_event_span(self.tracer, tctx, name, t0_unix_ns,
+                                time.time_ns() - t0_unix_ns, rows=rows,
+                                table=table)
 
 
 def main(argv=None):

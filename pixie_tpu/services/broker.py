@@ -1594,9 +1594,11 @@ class Broker:
     def _run_query(self, client: Connection, meta: dict):
         req_id = meta.get("req_id", "")
         tenant = str(meta.get("tenant") or DEFAULT_TENANT)
+        tctx = None
         try:
             with trace.root(self.tracer, "query", req_id=req_id,
                             tenant=tenant):
+                tctx = trace.wire_context()
                 results, stats = self.execute_script(
                     meta["script"],
                     func=meta.get("func"),
@@ -1643,14 +1645,16 @@ class Broker:
                 retry_after_s=getattr(e, "retry_after_s", None),
                 retryable=getattr(e, "retryable", None)))
         finally:
-            self._ship_spans()
+            self._ship_spans(tctx)
 
-    def _ship_spans(self) -> None:
+    def _ship_spans(self, tctx: Optional[dict] = None) -> None:
         """Persist this broker's finished spans AND flight-recorder rows
         (query profiles, op stats, sampled metrics, SLO alerts) into the
         data plane: everything goes to one live agent's self_telemetry
         tables through the normal write path, so PxL scripts and standing
         matviews see it without the broker holding a scanned store.
+        `tctx` is the wire context of the query whose end ships them: the
+        agent records what each write cost as a span of that query.
 
         Runs in query finally-blocks: telemetry failure (agent churn racing
         the conn map, dead sockets) must never replace a query's outcome, so
@@ -1672,7 +1676,7 @@ class Broker:
 
             def send(rows):
                 if not send_to_agent(wire.encode_json(
-                        {"msg": "spans", "spans": rows})):
+                        {"msg": "spans", "spans": rows, "trace": tctx})):
                     _metrics.counter_inc(
                         "px_broker_trace_spans_unshipped_total",
                         float(len(rows)),
@@ -1682,7 +1686,7 @@ class Broker:
             for table, rows in self._telemetry.drain().items():
                 if not send_to_agent(wire.encode_json(
                         {"msg": "telemetry_rows", "table": table,
-                         "rows": rows})):
+                         "rows": rows, "trace": tctx})):
                     _metrics.counter_inc(
                         "px_broker_telemetry_rows_unshipped_total",
                         float(len(rows)),
@@ -2167,6 +2171,7 @@ class Broker:
         shed = False
         ok_query = False
         qid = None
+        tctx = None  # the root's wire context, shipped with its telemetry
         cls = None  # rate-model plan class, set once admission classifies
         wait_ns = 0
         try:
@@ -2175,6 +2180,7 @@ class Broker:
                 # below runs AFTER the cm unwinds, and an error profile
                 # must still join this query's spans on query_id==trace_id
                 qid = self._query_trace_id() if prof_on else None
+                tctx = trace.wire_context() if owns_root else None
                 ticket, cls = self._admit(script, func, func_args,
                                           default_limit, tenant)
                 wait_ns = ticket.wait_ns if ticket is not None else 0
@@ -2240,7 +2246,7 @@ class Broker:
                 self._telemetry.add(_observe.ALERTS_TABLE,
                                     mon.drain_alerts())
             if owns_root:
-                self._ship_spans()
+                self._ship_spans(tctx)
 
     def _query_trace_id(self) -> str:
         """Query id for profile rows: the active trace root's trace_id (so

@@ -14,7 +14,22 @@ timeline.  This module closes that loop with the system's own machinery:
     the same path user data takes — so PxL queries them like any table
     (the bundled `px/self_query_latency` script), and a span→HostBatch
     adapter feeds the existing engine/otel.py resourceSpans encoder so
-    traces ship to any OTLP collector.
+    traces ship to any OTLP collector;
+  * every finished span also stays in one process-wide bounded ring
+    (`recent()`, `ring_dropped()`), flushed or not, for readers that run in
+    the process after the store is gone (the benchmark's per-layer
+    metrics).
+
+Names a reader can rely on (README "Where the agent's time goes"): the
+agent's `exec` root; one chain span per executor chain with `engine` and the
+routing decision (`arm`, `source`, `size_bucket`, `decision_n`, `rows`);
+`feed`; `readback_wave` (in an accelerator process: a pull from the
+accelerator) and `cpu_chain_wait` (there: the wait for a chain pinned to
+XLA-CPU); `jax_compile` (`kind`, `cache_hit`), whose seconds are also
+`exec_stats["compile_s"]`: jax trace + lower + backend compile of this query
+in the agent, not the broker's `phases.compile_ns`, which is the PxL compile;
+`plan_decode`, `result_send`; `telemetry_flush` and `telemetry_write`, the
+cost of the self-telemetry writes themselves.
 
 Tracing is on by default and disabled via PL_TRACING_ENABLED=0; the disabled
 fast path is a single ContextVar read per instrumentation site (no span is
@@ -27,7 +42,9 @@ plugin OTLP export path, exec/otel_export_sink_node.*).
 """
 from __future__ import annotations
 
+import collections
 import contextvars
+import itertools
 import json
 import secrets
 import threading
@@ -103,6 +120,35 @@ class Span:
         }
 
 
+#: how many finished spans the process keeps for in-process readers
+#: (`recent`): over twice a 50 s window of the densest benchmark cell
+#: (~354 queries of ~45 spans, broker and agent together)
+RING_SPANS = 1 << 16
+#: every tracer's finished spans, oldest first, each with its number in the
+#: order of finishing.  Flushing does not empty it: a per-layer reader runs
+#: after the store the spans were flushed to is gone.
+_RING: "collections.deque[tuple[int, Span]]" = collections.deque(
+    maxlen=RING_SPANS)
+_RING_SEQ = itertools.count()
+
+
+def recent(since_unix_ns: int = 0) -> "list[Span]":
+    """Finished spans of every tracer in this process that ended at or
+    after `since_unix_ns`, oldest first, whether or not they were flushed
+    since.  The ring holds the last RING_SPANS; `ring_dropped()` says how
+    many older ones it has let go."""
+    return [sp for _n, sp in list(_RING) if sp.end_ns >= since_unix_ns]
+
+
+def ring_dropped() -> int:
+    """Spans the ring has let go since the process started (the number of
+    the oldest span it still holds)."""
+    try:
+        return _RING[0][0]
+    except IndexError:
+        return 0
+
+
 #: live tracers for the span-buffer health gauges (weak: a stopped service's
 #: tracer must not be pinned by the metrics registry)
 _LIVE: "weakref.WeakSet[Tracer]" = weakref.WeakSet()
@@ -152,6 +198,8 @@ class Tracer:
         span.end_ns = end_ns if end_ns is not None else time.time_ns()
         with self._lock:
             self.finished += 1
+            if enabled():
+                _RING.append((next(_RING_SEQ), span))
             if len(self._finished) >= self.max_spans:
                 self.dropped += 1
             else:
@@ -243,6 +291,16 @@ def start_child(name: str, **attributes) -> Optional[Span]:
                              attributes=attributes or None)
 
 
+def _finished_span(tracer: "Tracer", trace_id, parent_span_id: str, name: str,
+                   start_unix_ns: int, duration_ns: int,
+                   attributes: dict) -> None:
+    sp = tracer.start_span(name, trace_id=trace_id,
+                           parent_span_id=parent_span_id,
+                           attributes=attributes or None,
+                           start_ns=start_unix_ns)
+    tracer.finish(sp, end_ns=start_unix_ns + max(0, int(duration_ns)))
+
+
 def event_span(name: str, start_unix_ns: int, duration_ns: int,
                **attributes) -> None:
     """Record an already-measured interval as a finished child span (the
@@ -251,11 +309,20 @@ def event_span(name: str, start_unix_ns: int, duration_ns: int,
     if c is None:
         return
     tracer, parent = c
-    sp = tracer.start_span(name, trace_id=parent.trace_id,
-                           parent_span_id=parent.span_id,
-                           attributes=attributes or None,
-                           start_ns=start_unix_ns)
-    tracer.finish(sp, end_ns=start_unix_ns + max(0, int(duration_ns)))
+    _finished_span(tracer, parent.trace_id, parent.span_id, name,
+                   start_unix_ns, duration_ns, attributes)
+
+
+def remote_event_span(tracer: Tracer, ctx: Optional[dict], name: str,
+                      start_unix_ns: int, duration_ns: int,
+                      **attributes) -> None:
+    """`event_span` for a thread with no active trace: an already-measured
+    interval, finished on `tracer` as a child of the wire context `ctx`
+    (`wire_context()` of the query it belongs to).  A no-op without a
+    context or with tracing off."""
+    if ctx and enabled():
+        _finished_span(tracer, ctx.get("trace_id"), ctx.get("span_id") or "",
+                       name, start_unix_ns, duration_ns, attributes)
 
 
 class _SpanCm:
