@@ -8,7 +8,8 @@ all string work at compile time against dictionary snapshots:
 
   * numeric ops → jnp ops on column tensors (device, fused by XLA);
   * string scalar UDFs → host evaluation over dictionary values producing LUT
-    arrays, applied on device with one gather;
+    arrays, applied on device with one gather, or a compare-select for small
+    tables on the TPU (`_lookup`);
   * string equality / select → dictionary code translation at compile time,
     integer compare / where on device.
 
@@ -24,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from pixie_tpu.ops import groupby as _groupby
 from pixie_tpu.plan.plan import Call, Column, Expr, Literal
 from pixie_tpu.status import CompilerError
 from pixie_tpu.table.dictionary import Dictionary
@@ -54,15 +56,76 @@ class SVal:
     origin: Optional[tuple] = None
 
 
+#: A LUT of at most this many entries, of values at most 32 bits wide, is
+#: applied in a program traced for the TPU as a chain of compare-selects
+#: instead of a gather (`_lookup`).  Read on one TPU v5e chip over the served
+#: path's 8,388,608-row bucket (PR 28; ms, gather against one chain of
+#: selects, int32): K=110 67.4 / 0.96, 1,024 45.6 / 7.88, 4,096 60.8 / 28.0,
+#: 16,384 60.7 / (cut into runs of 32 under a `fori_loop`) 55.5.  In time
+#: the select crosses the gather above 8,000 entries; what binds sooner is
+#: the compile, 16.5 s for a chain of 1,024 and 66 s for 4,096, and inside
+#: the by-status program (where XLA copies the chain into each consumer)
+#: already 4.8 s more a program at 110.  Runs under a loop compile in under
+#: a second at any K, but their carried buffer slowed the two scatters of
+#: the windowed chain by 0.27 s a query: not used.  64-bit values keep the
+#: gather (128.3 ms at K=110): a chain of them runs in 3.5 ms but XLA hands
+#: its compares between the value's 32-bit halves as [rows] predicates, 483
+#: MB of temporaries at K=110.
+LUT_SELECT_MAX = 128
+
+
+def lut_selects(k: int, itemsize: int) -> bool:
+    """Whether a program traced right now applies a LUT of `k` entries of
+    `itemsize` bytes as a compare-select: decided by what the trace can
+    observe, the dispatch platform and the table's static length and value
+    width (as `ops.groupby._use_matmul`)."""
+    return (0 < k <= LUT_SELECT_MAX and itemsize <= 4
+            and _groupby.dispatch_backend() == "tpu")
+
+
+def _lookup(lut: jax.Array, idx: jax.Array) -> jax.Array:
+    """lut[idx], for idx already clipped into [0, len(lut)).
+
+    One algorithm, two formulations.  XLA's TPU gather is serial per row
+    whatever the table's size (8 ns a row for 440 bytes of table), while
+    `where(idx == k, lut[k], acc)` over the entries is elementwise VPU work
+    that fuses into its consumers: no [rows, K] intermediate exists, the
+    accumulator is the one live value a row has.  It is a select in the
+    LUT's own dtype, so the result is bit-equal to the gather's.  Elsewhere
+    (XLA-CPU gathers from a table in L1), and for longer or wider tables,
+    the gather stays."""
+    if not lut_selects(lut.shape[0], lut.dtype.itemsize):
+        return jnp.take(lut, idx)
+    return _select_chain(lut, idx)
+
+
+def _select_chain(lut: jax.Array, idx: jax.Array) -> jax.Array:
+    """`_lookup`'s compare-select, for any dtype (the tests hold it to the
+    gather bit for bit)."""
+    acc = jnp.broadcast_to(lut[0], jnp.shape(idx))
+    for i in range(1, lut.shape[0]):
+        acc = jnp.where(idx == i, lut[i], acc)
+    return acc
+
+
 @jax.named_scope("px.md_lookup")
 def apply_lut(lut: jax.Array, codes: jax.Array, fill):
-    """Safe LUT gather: codes may be -1 (null / no-translation) → fill.
+    """Safe LUT application (a gather, or a compare-select for small tables
+    on the TPU: `_lookup`): codes may be -1 (null / no-translation) → fill.
     An EMPTY lut (no dictionary values yet — empty table) yields all-fill."""
     if lut.shape[0] == 0:
         return jnp.full(jnp.shape(codes), fill, dtype=jnp.asarray(lut).dtype)
-    safe = jnp.clip(codes, 0, lut.shape[0] - 1)
-    out = jnp.take(lut, safe)
+    out = _lookup(lut, jnp.clip(codes, 0, lut.shape[0] - 1))
     return jnp.where(codes >= 0, out, jnp.asarray(fill, dtype=out.dtype))
+
+
+@jax.named_scope("px.md_lookup")
+def apply_int_domain_lut(lut: jax.Array, x: jax.Array, lo: int, oob):
+    """LUT over the integer domain [lo, lo + len(lut)): x outside it → oob."""
+    hi = lo + lut.shape[0] - 1
+    idx = jnp.clip(x - lo, 0, hi - lo).astype(jnp.int32)
+    return jnp.where((x >= lo) & (x <= hi), _lookup(lut, idx),
+                     jnp.asarray(oob, dtype=lut.dtype))
 
 
 def apply_lut_np(lut: np.ndarray, codes: np.ndarray, fill=-1) -> np.ndarray:
@@ -89,6 +152,9 @@ class ExprCompiler:
         self.col_dicts = col_dicts
         self.registry = registry
         self.luts: dict[str, np.ndarray] = {}
+        #: (entries, bytes a value) of the table of every LUT application
+        #: compiled into the chain's expressions (see `lut_forms`)
+        self.lut_sizes: list[tuple[int, int]] = []
         self._n = 0
         # Memo holds (expr, SVal): the strong ref to expr is REQUIRED — keying
         # by id() of a dead object would let a newly allocated Expr reuse the
@@ -101,6 +167,24 @@ class ExprCompiler:
         self._n += 1
         self.luts[name] = arr
         return name
+
+    def _add_applied_lut(self, arr: np.ndarray) -> str:
+        """`_add_lut` for a table the chain's program looks codes up in."""
+        self.lut_sizes.append((len(arr), arr.dtype.itemsize))
+        return self._add_lut(arr)
+
+    def _lut_build(self, arr: np.ndarray, codes_build: Callable, fill) -> Callable:
+        """Register `arr` and return the builder that applies it to the codes
+        `codes_build(env)` gives."""
+        name = self._add_applied_lut(arr)
+        return lambda env: apply_lut(env["luts"][name], codes_build(env), fill)
+
+    def lut_forms(self) -> dict:
+        """How a program traced right now applies the chain's LUTs: the count
+        of compare-selects and of gathers (an empty table is neither)."""
+        sel = sum(lut_selects(k, size) for k, size in self.lut_sizes)
+        return {"lut_select": sel,
+                "lut_gather": sum(k > 0 for k, _ in self.lut_sizes) - sel}
 
     def _cast(self, v: SVal, target: DT) -> SVal:
         if v.dtype == target:
@@ -306,22 +390,12 @@ class ExprCompiler:
         if udf.out_type == DT.STRING:
             out_dict = Dictionary()
             lut = s.dictionary.lut(lambda v: out_dict.code(call_fn(v)), np.int32, size=size)
-            name = self._add_lut(lut)
-            return SVal(
-                DT.STRING,
-                lambda env, name=name, b=b: apply_lut(env["luts"][name], b(env), -1),
-                out_dict,
-                origin=origin,
-            )
+            return SVal(DT.STRING, self._lut_build(lut, b, -1), out_dict,
+                        origin=origin)
         np_out = STORAGE_DTYPE[udf.out_type]
         lut = s.dictionary.lut(call_fn, np_out, size=size)
-        name = self._add_lut(lut)
         fill = False if udf.out_type == DT.BOOLEAN else 0
-        return SVal(
-            udf.out_type,
-            lambda env, name=name, b=b, fill=fill: apply_lut(env["luts"][name], b(env), fill),
-            origin=origin,
-        )
+        return SVal(udf.out_type, self._lut_build(lut, b, fill), origin=origin)
 
     #: compile-time cap on per-dictionary-value composed evaluation (each
     #: value may run several eager device ops — keep python work bounded)
@@ -342,24 +416,13 @@ class ExprCompiler:
             out_dict = Dictionary()
             lut = root_dict.lut(lambda v: out_dict.code(fn(v)), np.int32,
                                 size=size)
-            name = self._add_lut(lut)
-            return SVal(
-                DT.STRING,
-                lambda env, name=name, b=codes_build: apply_lut(
-                    env["luts"][name], b(env), -1),
-                out_dict,
-                origin=origin,
-            )
+            return SVal(DT.STRING, self._lut_build(lut, codes_build, -1),
+                        out_dict, origin=origin)
         np_out = STORAGE_DTYPE[udf.out_type]
         lut = root_dict.lut(fn, np_out, size=size)
-        name = self._add_lut(lut)
         fill = False if udf.out_type == DT.BOOLEAN else 0
-        return SVal(
-            udf.out_type,
-            lambda env, name=name, b=codes_build, fill=fill: apply_lut(
-                env["luts"][name], b(env), fill),
-            origin=origin,
-        )
+        return SVal(udf.out_type, self._lut_build(lut, codes_build, fill),
+                    origin=origin)
 
     #: cross-product bound for two-dictionary host calls (compile-time python
     #: work + LUT bytes; typical script usage is tiny enum×enum / id×id spaces)
@@ -405,18 +468,16 @@ class ExprCompiler:
             )
             out_dict = None
             fill = False if udf.out_type == DT.BOOLEAN else 0
-        name = self._add_lut(lut)
 
-        def build(env, name=name, ab=ab, bb=bb, nb=nb, fill=fill):
+        def pair(env, ab=ab, bb=bb, nb=nb):
             ca, cb = ab(env), bb(env)
-            pair = jnp.where(
+            return jnp.where(
                 (ca >= 0) & (cb >= 0),
                 ca.astype(jnp.int32) * nb + cb.astype(jnp.int32),
                 -1,
             )
-            return apply_lut(env["luts"][name], pair, fill)
 
-        return SVal(udf.out_type, build, out_dict)
+        return SVal(udf.out_type, self._lut_build(lut, pair, fill), out_dict)
 
     def _int_domain_call(self, call: Call, udf) -> SVal:
         lo, hi = udf.int_domain
@@ -429,36 +490,21 @@ class ExprCompiler:
                 raise CompilerError(f"{udf.name}: trailing arguments must be literals")
             consts.append(a.value)
         vals = [udf.fn(i, *consts) for i in range(lo, hi + 1)]
-        b = v.build
+        oob = udf.fn(lo - 1, *consts)  # out-of-domain value
         if udf.out_type == DT.STRING:
             out_dict = Dictionary()
             lut = np.asarray([out_dict.code(x) for x in vals], dtype=np.int32)
-            oob = out_dict.code(udf.fn(lo - 1, *consts))  # out-of-domain value
-            name = self._add_lut(lut)
+            oob = out_dict.code(oob)
+        else:
+            out_dict = None
+            lut = np.asarray(vals, dtype=STORAGE_DTYPE[udf.out_type])
+        name = self._add_applied_lut(lut)
+        b = v.build
 
-            def build(env, name=name, b=b, lo=lo, hi=hi, oob=oob):
-                x = b(env)
-                with jax.named_scope("px.md_lookup"):
-                    in_dom = (x >= lo) & (x <= hi)
-                    idx = jnp.clip(x - lo, 0, hi - lo).astype(jnp.int32)
-                    return jnp.where(in_dom,
-                                     jnp.take(env["luts"][name], idx), oob)
+        def build(env, name=name, b=b, lo=lo, oob=oob):
+            return apply_int_domain_lut(env["luts"][name], b(env), lo, oob)
 
-            return SVal(DT.STRING, build, out_dict)
-        np_out = STORAGE_DTYPE[udf.out_type]
-        lut = np.asarray(vals, dtype=np_out)
-        oob_v = udf.fn(lo - 1, *consts)
-        name = self._add_lut(lut)
-
-        def build_n(env, name=name, b=b, lo=lo, hi=hi, oob_v=oob_v):
-            x = b(env)
-            with jax.named_scope("px.md_lookup"):
-                in_dom = (x >= lo) & (x <= hi)
-                idx = jnp.clip(x - lo, 0, hi - lo).astype(jnp.int32)
-                return jnp.where(in_dom, jnp.take(env["luts"][name], idx),
-                                 jnp.asarray(oob_v, dtype=lut.dtype))
-
-        return SVal(udf.out_type, build_n)
+        return SVal(udf.out_type, build, out_dict)
 
     def _string_equality(self, call: Call, negate: bool) -> SVal:
         lhs_e, rhs_e = call.args
@@ -488,12 +534,10 @@ class ExprCompiler:
 
             return SVal(DT.BOOLEAN, build_same)
         trans = rv.dictionary.translate_to(lv.dictionary, insert=False)
-        name = self._add_lut(trans)
-        lb, rb = lv.build, rv.build
+        lb, rt = lv.build, self._lut_build(trans, rv.build, -1)
 
-        def build_trans(env, lb=lb, rb=rb, name=name, negate=negate):
-            r = apply_lut(env["luts"][name], rb(env), -1)
-            eq = lb(env) == r
+        def build_trans(env, lb=lb, rt=rt, negate=negate):
+            eq = lb(env) == rt(env)
             return jnp.logical_not(eq) if negate else eq
 
         return SVal(DT.BOOLEAN, build_trans)
@@ -507,11 +551,10 @@ class ExprCompiler:
         # Output dictionary: copy of a's snapshot, then b's values appended.
         out = Dictionary(a.dictionary.values())
         tb = b.dictionary.translate_to(out, insert=True)
-        name = self._add_lut(tb)
-        cb, ab, bb = cond.build, a.build, b.build
+        cb, ab = cond.build, a.build
+        bt = self._lut_build(tb, b.build, -1)
 
-        def build(env, cb=cb, ab=ab, bb=bb, name=name):
-            bc = apply_lut(env["luts"][name], bb(env), -1)
-            return jnp.where(cb(env), ab(env), bc)
+        def build(env, cb=cb, ab=ab, bt=bt):
+            return jnp.where(cb(env), ab(env), bt(env))
 
         return SVal(DT.STRING, build, out)

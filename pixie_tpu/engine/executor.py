@@ -623,6 +623,9 @@ class ChainKernel:
     def luts(self) -> dict[str, np.ndarray]:
         return self.ctx.ec.luts
 
+    def lut_forms(self) -> dict:
+        return self.ctx.ec.lut_forms()
+
     @jax.named_scope("px.time_mask")
     def _base_mask(self, env, n, n_valid, t_lo, t_hi):
         mask = jnp.arange(n) < n_valid
@@ -1273,7 +1276,7 @@ class PlanExecutor:
         return _contextlib.nullcontext()
 
     def _note_engine(self, engine: str, rec: Optional[dict] = None,
-                     src=None) -> None:
+                     src=None, kern: Optional["ChainKernel"] = None) -> None:
         """Record which engine ran one of this query's chains, and the
         platform and device_kind its kernels were dispatched to, in
         stats["device"] — so exec_stats, the flight recorder and EXPLAIN
@@ -1284,9 +1287,14 @@ class PlanExecutor:
         native loops; no kernel is dispatched).  "platform" names the
         accelerator-route device when any chain ran there, else "cpu".
         `rec`, the chain's _timed frame, takes the engine and the routing
-        decision `src` ran under as the attributes of its trace span."""
+        decision `src` ran under as the attributes of its trace span, and,
+        for a jitted chain over `kern`, how its program applies its LUTs
+        (`lut_select`/`lut_gather`: call this inside the chain's device
+        context, where the program is traced)."""
         if rec is not None:
             rec["span"] = {"engine": engine, **self._route_attrs(src)}
+            if kern is not None:
+                rec["span"].update(kern.lut_forms())
         dev = self.stats.setdefault("device", {})
         engines = dev.setdefault("engines", {})
         engines[engine] = engines.get(engine, 0) + 1
@@ -1302,9 +1310,10 @@ class PlanExecutor:
             d = _cpu_device()
             dev["platform"], dev["device_kind"] = d.platform, d.device_kind
 
-    def _note_chain(self, src, rec: Optional[dict] = None) -> None:
+    def _note_chain(self, src, rec: Optional[dict] = None,
+                    kern: Optional["ChainKernel"] = None) -> None:
         self._note_engine("xla_cpu_chain" if self._backend_for(src) == "cpu"
-                          else "device_chain", rec, src)
+                          else "device_chain", rec, src, kern)
 
     def _route_attrs(self, src) -> dict:
         """What the router decided for a chain over `src`: the arm it ran
@@ -1851,7 +1860,7 @@ class PlanExecutor:
             from collections import deque
 
             with self._timed(label, op_ids) as rec, self._device_ctx(src):
-                self._note_chain(src, rec)
+                self._note_chain(src, rec, kern)
                 has_limit = kern.has_limit
                 remaining = kern.init_limits()
                 computing: deque = deque()  # (outs, cnt): compute dispatched
@@ -2432,9 +2441,9 @@ class PlanExecutor:
                     self._note_engine("wholeplan", rec, src)
                 else:
                     if spmd_step is not None:
-                        self._note_engine("device_chain", rec, src)
+                        self._note_engine("device_chain", rec, src, kern)
                     else:
-                        self._note_chain(src, rec)
+                        self._note_chain(src, rec, kern)
                     state_np = self._agg_feed_loop(
                         kern, step, partial_step, merge_fn, spmd_step,
                         init_specs, num_groups,

@@ -189,7 +189,8 @@ class Dictionary:
         """Apply host `fn` to every dictionary value; return an array indexed by code.
 
         This is the engine's scalar-string-UDF evaluation strategy: the device
-        applies the result with `jnp.take(lut, codes)`.
+        applies the result to the codes with a gather, or a compare-select for
+        small tables on the TPU (engine/eval._lookup).
         """
         n = self.size if size is None else size
         out = np.empty(n, dtype=out_dtype)

@@ -8,7 +8,8 @@ re-design:
   * A *device* ScalarUDF is a pure jax function over column tensors — vectorized
     by construction (no per-row Exec loop, no udf_wrapper.h eval loops).
   * A *host* ScalarUDF runs over dictionary values (unique strings) producing a
-    LUT that the evaluator applies with `jnp.take` — O(unique) instead of O(rows).
+    LUT that the evaluator applies with a gather, or a compare-select for small
+    tables on the TPU (engine/eval._lookup) — O(unique) instead of O(rows).
   * A UDA's state is a pytree whose every leaf declares a reduction op
     ("add"|"min"|"max"); Merge — local or across a mesh axis — is that reduction,
     which makes every UDA partial-aggregation-capable by construction

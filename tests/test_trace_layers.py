@@ -10,6 +10,7 @@ values from a hand-made list of spans."""
 from __future__ import annotations
 
 import collections
+import contextlib
 import importlib.util
 import itertools
 import os
@@ -51,12 +52,12 @@ def fresh_ring(monkeypatch):
     monkeypatch.setattr(trace, "_RING_SEQ", itertools.count())
 
 
-@pytest.fixture
-def served(monkeypatch, fresh_ring):
-    """One Broker and one single-device Agent `pem0` over a small
-    http_events: tracing and the router's model on (the model fresh), the
-    native whole-plan loop and standing views off, so that every query
-    runs its jitted chain under a routing decision."""
+@contextlib.contextmanager
+def serving(store, monkeypatch):
+    """One Broker and one single-device Agent `pem0` over `store`: tracing
+    and the router's model on (the model fresh), the native whole-plan loop
+    and standing views off, so that every query runs its jitted chain under
+    a routing decision.  Yields the client."""
     wanted = {"PL_TRACING_ENABLED": True, "PX_AUTOTUNE": True,
               "PX_WHOLEPLAN_NATIVE": False, "PL_MATVIEW_ENABLED": False}
     before = {k: flags.get(k) for k in wanted}
@@ -65,17 +66,26 @@ def served(monkeypatch, fresh_ring):
     monkeypatch.setattr(spmd, "default_mesh", lambda: None)
     autotune.MODEL.reset_for_testing()
     broker = Broker(hb_expiry_s=5.0, query_timeout_s=30.0).start()
-    store = _mkstore(1, time.time_ns())
     agent = Agent("pem0", "127.0.0.1", broker.port, store=store,
                   heartbeat_s=1.0).start()
     client = Client("127.0.0.1", broker.port, timeout_s=30.0)
-    yield client, store
-    client.close()
-    agent.stop()
-    broker.stop()
-    autotune.MODEL.reset_for_testing()
-    for k, v in before.items():
-        flags.set_for_testing(k, v)
+    try:
+        yield client
+    finally:
+        client.close()
+        agent.stop()
+        broker.stop()
+        autotune.MODEL.reset_for_testing()
+        for k, v in before.items():
+            flags.set_for_testing(k, v)
+
+
+@pytest.fixture
+def served(monkeypatch, fresh_ring):
+    """`serving` over a small http_events."""
+    store = _mkstore(1, time.time_ns())
+    with serving(store, monkeypatch) as client:
+        yield client, store
 
 
 def _agent_spans(since_ns: int = 0) -> list:
