@@ -13,6 +13,9 @@ favor, not the one a build-time constant picked):
   * **Per-gate cost models.**  Each gate keeps, per (plan class, size
     bucket) key, one `_Arm` per choice (service-time EWMA + mean-absolute
     deviation + a bounded sample ring — the PR 15 ratemodel estimator).
+    An arm's predicted cost is the MEDIAN of its ring, so one slow sample
+    among its recent ones moves no decision; the EWMA is what persists
+    and what a KV-warmed arm answers with until its ring has samples.
     `decide()` returns the arm with the lowest predicted cost once every
     arm is warm (`PX_AUTOTUNE_MIN_SAMPLES`), else the gate's static
     default — a cold model must never steer dispatch off one noisy sample.
@@ -23,12 +26,15 @@ favor, not the one a build-time constant picked):
     non-static arms probe at a faster fixed cadence so a fresh model warms
     in bounded decisions; a KV-warmed model skips that burst entirely.
   * **Tail guard.**  Whenever the model favors a non-static arm, the
-    favored arm's recent-sample p99 is compared against the static arm's:
-    past `PX_AUTOTUNE_GUARD_FACTOR`× the gate snaps back to its static
-    default for `PX_AUTOTUNE_GUARD_HOLDOFF` decisions, the drifted arm's
-    stats reset, and an `autotune_fallback` event lands in
-    `self_telemetry.autotune` — a drifted model can never hold a tail
-    hostage.
+    favored arm's last `PX_AUTOTUNE_GUARD_WINDOW` samples are held
+    against `PX_AUTOTUNE_GUARD_FACTOR`× the static arm's median: once
+    `GUARD_SHARE` of them lie over it (a regression that lasts, not one
+    outlier) the gate snaps back to its static default for
+    `PX_AUTOTUNE_GUARD_HOLDOFF` decisions, the drifted arm keeps its
+    history less the samples that falsified it (so its return after the
+    hold-off is one comparison, not a cold warm-up), and an
+    `autotune_fallback` event lands in `self_telemetry.autotune` — a
+    drifted model can never hold a tail hostage.
   * **Persistence.**  `save_kv`/`load_kv` round-trip the per-arm (n, ewma,
     dev) triples through the broker KV (`autotune/model`, the PR 15 quota
     pattern) so a restarted broker starts warm; a corrupt record degrades
@@ -43,6 +49,7 @@ original static logic bit-identically, no decision is recorded anywhere.
 """
 from __future__ import annotations
 
+import statistics
 import threading
 import time
 from collections import deque
@@ -68,16 +75,18 @@ flags.define_int(
     "may override the static default")
 flags.define_int(
     "PX_AUTOTUNE_GUARD_WINDOW", 8,
-    "recent samples per arm the p99 tail guard needs before it compares a "
-    "model-favored arm against the static arm")
+    "the tail guard's window: the model-favored arm's most recent samples "
+    "it holds against the static arm (and the fewest samples either arm "
+    "needs before it compares at all)")
 flags.define_float(
     "PX_AUTOTUNE_GUARD_FACTOR", 2.0,
-    "tail-guard trip ratio: a model-favored arm whose recent p99 exceeds "
-    "factor * the static arm's p99 reverts the gate to its static default")
+    "tail-guard trip ratio: a model-favored arm with half its guard window "
+    "over factor * the static arm's median sample reverts the gate to its "
+    "static default")
 flags.define_int(
     "PX_AUTOTUNE_GUARD_HOLDOFF", 256,
     "decisions a tripped gate key stays pinned to its static default "
-    "before the (reset) model may re-learn the non-favored arm")
+    "before the model may favor the tripped arm again")
 
 #: the gates this module models (mq_fusion is record-only: its decision is
 #: baked into compiled kernels at trace time, so flipping it per query
@@ -90,8 +99,18 @@ GATE_HEDGE = "hedge"
 GATE_BATCH_WINDOW = "batch_window"
 GATE_MQ_FUSION = "mq_fusion"
 
-#: recent service samples kept per arm (tail-guard p99 readback)
+#: recent service samples kept per arm (the arm's median and the tail
+#: guard's window are read from them)
 RING = 64
+
+#: ring samples an arm recalled from the KV (n restored, ring empty) needs
+#: before its median replaces the persisted EWMA as its predicted cost
+MEDIAN_MIN = 8
+
+#: share of the favored arm's guard window that must lie over the trip
+#: level: one outlier in a window of 8 is an eighth and trips nothing, a
+#: regression that lasts reaches half within one window
+GUARD_SHARE = 0.5
 
 #: cold non-static arms probe every Nth decision until warm — bounded
 #: warmup without randomness (a KV-warmed model never enters this phase)
@@ -162,17 +181,21 @@ class _Arm:
         self.n += 1
         self.ring.append(secs)
 
-    def ring_q(self, q: float) -> Optional[float]:
-        if not self.ring:
-            return None
-        xs = sorted(self.ring)
-        return xs[min(len(xs) - 1, int(q * len(xs)))]
+    def cost(self) -> float:
+        """The arm's predicted cost: the median of its recent samples,
+        which one outlier among them cannot move; the EWMA while the ring
+        holds fewer than the arm has seen and fewer than MEDIAN_MIN (an
+        arm recalled from the KV starts with an empty ring)."""
+        if len(self.ring) < max(1, min(self.n, MEDIAN_MIN)):
+            return self.ewma
+        return statistics.median(self.ring)
 
 
 class _GateState:
     """One gate's model: per-key arms + decision pacing + guard holdoff."""
 
-    __slots__ = ("arms", "count", "holdoff", "last_arm", "fallbacks")
+    __slots__ = ("arms", "count", "holdoff", "last_arm", "trips",
+                 "fallbacks")
 
     def __init__(self):
         #: key -> {arm_name: _Arm}
@@ -184,6 +207,8 @@ class _GateState:
         #: key -> arm of the most recent decision (observation routing for
         #: call sites whose completion callback has no decision handle)
         self.last_arm: dict[str, str] = {}
+        #: key -> guard trips so far (every decision carries its key's)
+        self.trips: dict[str, int] = {}
         self.fallbacks = 0
 
 
@@ -226,6 +251,7 @@ class AutotuneModel:
                     g.count.pop(lru, None)
                     g.holdoff.pop(lru, None)
                     g.last_arm.pop(lru, None)
+                    g.trips.pop(lru, None)
             arms = g.arms[key] = {}
         a = arms.get(arm)
         if a is None:
@@ -240,8 +266,8 @@ class AutotuneModel:
 
     def _guard_locked(self, gate: str, g: _GateState, key: str,
                       favored: str, static_arm: str) -> bool:
-        """p99 tail guard: True = trip (revert to static, reset the
-        drifted arm, record the fallback event)."""
+        """Tail guard: True = trip (revert to static, take the falsifying
+        samples out of the drifted arm, record the fallback event)."""
         window = int(flags.get("PX_AUTOTUNE_GUARD_WINDOW"))
         factor = float(flags.get("PX_AUTOTUNE_GUARD_FACTOR"))
         arms = g.arms.get(key) or {}
@@ -250,23 +276,34 @@ class AutotuneModel:
             return False
         if len(fav.ring) < window or len(sta.ring) < window:
             return False
-        fp99, sp99 = fav.ring_q(0.99), sta.ring_q(0.99)
-        if fp99 is None or sp99 is None or fp99 <= factor * max(sp99, 1e-9):
+        static_med = statistics.median(sta.ring)
+        level = factor * max(static_med, 1e-9)
+        recent = list(fav.ring)[-window:]
+        over = sum(s > level for s in recent)
+        if over < GUARD_SHARE * window:
             return False
         g.holdoff[key] = int(flags.get("PX_AUTOTUNE_GUARD_HOLDOFF"))
+        g.trips[key] = g.trips.get(key, 0) + 1
         g.fallbacks += 1
-        # the drifted arm re-learns from scratch: its history is exactly
-        # what the guard just falsified
-        arms[favored] = _Arm()
+        # the drifted arm keeps what the guard did not falsify: after the
+        # hold-off it is compared as it stood before it drifted, and trips
+        # again within one window if the drift has lasted
+        kept = [s for s in fav.ring if s <= level]
+        if kept:
+            fav.ring = deque(kept, maxlen=RING)
+            fav.ewma = statistics.median(kept)
+        else:
+            arms[favored] = _Arm()
         cls, _, bucket = key.partition("|")
+        recent_med = statistics.median(recent)
         self._event_locked({
             "time_": time.time_ns(), "query_id": "", "gate": gate,
             "plan_class": cls, "size_bucket": bucket, "arm": static_arm,
             "static_arm": static_arm, "source": "fallback",
-            "model_ms": round(fp99 * 1e3, 3),
-            "static_ms": round(sp99 * 1e3, 3), "observed_ms": 0.0,
-            "reason": f"autotune_fallback p99 {fp99 * 1e3:.1f}ms > "
-                      f"{factor:g}x {sp99 * 1e3:.1f}ms"})
+            "model_ms": round(recent_med * 1e3, 3),
+            "static_ms": round(static_med * 1e3, 3), "observed_ms": 0.0,
+            "reason": f"autotune_fallback {over}/{window} recent samples > "
+                      f"{factor:g}x median {static_med * 1e3:.1f}ms"})
         return True
 
     # ------------------------------------------------------------- decisions
@@ -302,14 +339,15 @@ class AutotuneModel:
         count = g.count.get(key, 0)
         g.count[key] = count + 1
         hold = g.holdoff.get(key, 0)
-        static_ms = (round(states[static_arm].ewma * 1e3, 3)
+        static_ms = (round(states[static_arm].cost() * 1e3, 3)
                      if static_arm in states and states[static_arm].n
                      else None)
 
         def _dec(arm, source, model_ms=None):
             g.last_arm[key] = arm
             return {"arm": arm, "source": source, "model_ms": model_ms,
-                    "static_ms": static_ms, "n": count + 1}
+                    "static_ms": static_ms, "n": count + 1,
+                    "guard_trips": g.trips.get(key, 0)}
 
         if hold > 0:
             g.holdoff[key] = hold - 1
@@ -325,8 +363,9 @@ class AutotuneModel:
                 probe = min(cold, key=lambda a: states[a].n)
                 return _dec(probe, "explore")
             return _dec(static_arm, "cold")
-        favored = min(arms, key=lambda a: states[a].ewma)
-        model_ms = round(states[favored].ewma * 1e3, 3)
+        costs = {a: states[a].cost() for a in arms}
+        favored = min(arms, key=costs.get)
+        model_ms = round(costs[favored] * 1e3, 3)
         if favored != static_arm and self._guard_locked(
                 gate, g, key, favored, static_arm):
             return _dec(static_arm, "fallback", model_ms)

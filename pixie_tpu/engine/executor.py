@@ -1318,8 +1318,9 @@ class PlanExecutor:
     def _route_attrs(self, src) -> dict:
         """What the router decided for a chain over `src`: the arm it ran
         on and, where the adaptive model took the decision, its source
-        (`model`/`static`/`cold`/`explore`/`fallback`), size bucket and
-        number.  A chain span with source=explore is a router probe."""
+        (`model`/`static`/`cold`/`explore`/`fallback`), size bucket,
+        number and how often the key's tail guard has tripped so far.  A
+        chain span with source=explore is a router probe."""
         n = _src_rows(src) if src is not None else None
         if n is None:
             return {}
@@ -1327,7 +1328,7 @@ class PlanExecutor:
         dec = self._at_route.get(_autotune.size_bucket(n * self.route_scale))
         if dec is not None:
             attrs.update(source=dec["source"], size_bucket=dec["size_bucket"],
-                         decision_n=dec["n"])
+                         decision_n=dec["n"], guard_trips=dec["guard_trips"])
         return attrs
 
     # -------------------------------------------------------------- exec stats
@@ -1709,7 +1710,7 @@ class PlanExecutor:
             if isinstance(op, AggOp):
                 out = self._run_agg(op)
             elif isinstance(op, JoinOp):
-                out = self._run_join(op)
+                out = self._run_join(op, rec)
             elif isinstance(op, UnionOp):
                 out = self._run_union(op)
             elif isinstance(op, MemorySourceOp):
@@ -2458,12 +2459,15 @@ class PlanExecutor:
                 dec = self._at_route.get(
                     _autotune.size_bucket(n * self.route_scale))
                 if dec is not None:
+                    # the sample is the chain's wall less what jax compiled
+                    # in it (recorded beside it): a program's first run
+                    # must not price its arm.  jax reports nested traces
+                    # inside their outer one too, so compile_s can pass the
+                    # wall by a few percent and that one sample reads 0
+                    compile_s = self.stats["compile_s"] - compile_s0
                     _autotune.MODEL.observe_decision(
-                        dec, rec["wall_ns"] / 1e9)
-                    # how much of observed_ms was jax compiling (recorded
-                    # beside it; the model does not read it)
-                    dec["compile_ms"] = round(
-                        (self.stats["compile_s"] - compile_s0) * 1e3, 3)
+                        dec, max(rec["wall_ns"] / 1e9 - compile_s, 0.0))
+                    dec["compile_ms"] = round(compile_s * 1e3, 3)
         return keys, udas, state_np, seen_name, in_types, val_dicts
 
     def _wholeplan_program(self, sig, kern, chain, op, keys, init_specs,
@@ -3346,8 +3350,11 @@ class PlanExecutor:
         return HostBatch(dtypes, dicts, cols)
 
     # -------------------------------------------------------------------- join
-    def _run_join(self, op: JoinOp) -> HostBatch:
+    def _run_join(self, op: JoinOp, rec: dict) -> HostBatch:
         """Equijoin with full many-to-many expansion, inner/left/right/outer.
+        `rec`, the join's _timed frame, takes both sides' rows, the rows
+        out and the match kernel (`cross`, `host_sort`, `native`,
+        `device_radix`) as the attributes of its trace span.
 
         Reference: exec/equijoin_node.h + planpb JoinOperator
         (plan.proto:301-316).  Redesigned as a sort/searchsorted join over
@@ -3383,6 +3390,8 @@ class PlanExecutor:
             elif nl == 0 and op.how in ("right", "outer"):
                 ridx = np.arange(nr, dtype=np.int64)
                 lidx = np.full(nr, -1, dtype=np.int64)
+            rec["span"] = {"rows_left": nl, "rows_right": nr,
+                           "kernel": "cross"}
             return self._join_output(op, left, right, lidx, ridx)
 
         # Factorize each key pair into a shared integer code space; nulls
@@ -3431,6 +3440,10 @@ class PlanExecutor:
             gate = {"enabled": False}
         use_device = (at_dec["arm"] == "device" if at_dec is not None
                       else gate["enabled"])
+        rec["span"] = {
+            "rows_left": nl, "rows_right": nr,
+            "kernel": "host_sort" if not use_device else
+            "native" if _jd.join_path() == "native_cpu" else "device_radix"}
         t_match0 = _time.perf_counter_ns()
         if use_device:
             # device radix-bucketed match phase (ops/join_device.py):

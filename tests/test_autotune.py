@@ -1,5 +1,5 @@
 """Profile-fed adaptive gates (ISSUE 17): online per-gate cost models,
-deterministic guarded exploration, the p99 tail guard with its
+deterministic guarded exploration, the tail guard with its
 `autotune_fallback` telemetry row, KV persistence across broker restarts
 (warm first decision, corrupt record degrades), the bit-identity of
 `PX_AUTOTUNE=0`, and the probe staleness horizon on the memoized
@@ -98,32 +98,96 @@ def test_warm_model_epsilon_probes_deterministically():
     assert srcs[15] == "explore" and srcs[31] == "explore"
 
 
-def test_tail_guard_trips_resets_arm_and_emits_fallback_row():
-    """A model-favored arm whose recent p99 drifts past the guard factor
-    snaps the gate back to static, resets the drifted arm's stats, and
-    lands an autotune_fallback event row."""
-    m = AutotuneModel()
-    window = int(flags.get("PX_AUTOTUNE_GUARD_WINDOW"))
-    _warm(m, GATE_CPU_CROSSOVER, {"device": 50.0, "cpu": 2.0},
-          n=max(window, int(flags.get("PX_AUTOTUNE_MIN_SAMPLES"))))
-    # the favored cpu arm grows a TAIL the mean hides: one 500 ms spike
-    # then fast samples keep the EWMA below device's 50 ms (the model
-    # still favors cpu) while the recent-ring p99 is 10x past the guard
-    m.observe(GATE_CPU_CROSSOVER, "agg", "4^8", "cpu", 500.0 / 1e3)
-    for _ in range(window):
-        m.observe(GATE_CPU_CROSSOVER, "agg", "4^8", "cpu", 2.0 / 1e3)
-    dec = m.decide(GATE_CPU_CROSSOVER, "agg", "4^8", "device",
-                   ("device", "cpu"))
-    assert dec["arm"] == "device" and dec["source"] == "fallback"
-    # held off: the next decisions stay pinned static
-    dec2 = m.decide(GATE_CPU_CROSSOVER, "agg", "4^8", "device",
+def _decide(m):
+    return m.decide(GATE_CPU_CROSSOVER, "agg", "4^8", "device",
                     ("device", "cpu"))
+
+
+def _warm_for_guard(m, device_ms=50.0, cpu_ms=2.0):
+    """Both arms warm and past the guard's window; cpu (not the static
+    arm) is the favorite."""
+    window = int(flags.get("PX_AUTOTUNE_GUARD_WINDOW"))
+    _warm(m, GATE_CPU_CROSSOVER, {"device": device_ms, "cpu": cpu_ms},
+          n=max(window, int(flags.get("PX_AUTOTUNE_MIN_SAMPLES"))))
+    return window
+
+
+def test_tail_guard_trips_resets_arm_and_emits_fallback_row():
+    """A model-favored arm of whose guard window half lies past the guard
+    factor times the static arm's median snaps the gate back to static,
+    keeps the arm's history less the samples that falsified it, and lands
+    an autotune_fallback event row."""
+    m = AutotuneModel()
+    window = _warm_for_guard(m)
+    # the favored cpu arm drifts: half a window of 500 ms samples, five
+    # times the guard level (2 x the static arm's 50 ms)
+    for _ in range(window // 2):
+        m.observe(GATE_CPU_CROSSOVER, "agg", "4^8", "cpu", 500.0 / 1e3)
+    dec = _decide(m)
+    assert dec["arm"] == "device" and dec["source"] == "fallback"
+    assert dec["guard_trips"] == 1
+    # held off: the next decisions stay pinned static
+    dec2 = _decide(m)
     assert dec2["source"] == "fallback" and dec2["arm"] == "device"
     assert m.snapshot()[GATE_CPU_CROSSOVER]["fallbacks"] == 1
+    # the drifted arm keeps what was not falsified, and stays warm
+    arm = m._gates[GATE_CPU_CROSSOVER].arms["agg|4^8"]["cpu"]
+    assert list(arm.ring) == [2.0 / 1e3] * window
+    assert arm.n >= int(flags.get("PX_AUTOTUNE_MIN_SAMPLES"))
+    assert arm.cost() == pytest.approx(2.0 / 1e3)
     rows = m.drain_rows()
     assert len(rows) == 1 and rows[0]["source"] == "fallback"
     assert "autotune_fallback" in rows[0]["reason"]
     assert m.drain_rows() == []  # drained once
+
+
+def test_one_outlier_neither_trips_guard_nor_changes_favorite():
+    """One slow sample on the favored arm, however slow, is one of a guard
+    window and one of a ring: the guard holds, the favorite stays, and the
+    arm's predicted cost does not move."""
+    m = AutotuneModel()
+    window = _warm_for_guard(m, device_ms=42.0, cpu_ms=23.0)
+    m.observe(GATE_CPU_CROSSOVER, "agg", "4^8", "cpu", 3.4)  # 3.4 s
+    for i in range(2 * window):
+        dec = _decide(m)
+        assert dec["source"] in ("model", "explore"), (i, dec)
+        assert dec["guard_trips"] == 0
+        if dec["source"] == "model":
+            assert dec["arm"] == "cpu"
+            assert dec["model_ms"] == pytest.approx(23.0)
+        m.observe_decision(dec, {"cpu": 23.0, "device": 42.0}[dec["arm"]]
+                           / 1e3)
+    assert m.snapshot()[GATE_CPU_CROSSOVER]["fallbacks"] == 0
+    assert m.drain_rows() == []
+
+
+def test_one_outlier_on_static_favorite_keeps_it():
+    """Over the crossover the static arm is the favorite and no guard
+    runs: a slow sample there must not hand the window to the other arm
+    (the status cell's one-slow-sample flip)."""
+    m = AutotuneModel()
+    _warm(m, GATE_CPU_CROSSOVER, {"device": 118.0, "cpu": 200.0})
+    m.observe(GATE_CPU_CROSSOVER, "agg", "4^8", "device", 0.9)
+    dec = _decide(m)
+    assert dec["arm"] == "device" and dec["source"] == "static"
+
+
+def test_lasting_regression_trips_within_one_guard_window():
+    """A regression of the favored arm that lasts trips the guard before
+    a whole guard window of slow samples has been served."""
+    m = AutotuneModel()
+    window = _warm_for_guard(m)
+    served = 0
+    for _ in range(window):
+        dec = _decide(m)
+        if dec["source"] == "fallback":
+            break
+        if dec["arm"] == "cpu":
+            served += 1
+        m.observe_decision(dec, {"cpu": 500.0, "device": 50.0}[dec["arm"]]
+                           / 1e3)
+    assert dec["source"] == "fallback" and dec["arm"] == "device"
+    assert served <= window // 2
 
 
 def test_fallback_row_lands_in_self_telemetry_table():
@@ -151,23 +215,103 @@ def test_fallback_row_lands_in_self_telemetry_table():
     assert srcs == ["fallback"]
 
 
-def test_guard_holdoff_expires_and_model_relearns():
+def test_guard_holdoff_expires_and_arm_returns_without_cold_warmup():
     flags.set_for_testing("PX_AUTOTUNE_GUARD_HOLDOFF", 3)
     m = AutotuneModel()
-    window = int(flags.get("PX_AUTOTUNE_GUARD_WINDOW"))
-    _warm(m, GATE_CPU_CROSSOVER, {"device": 50.0, "cpu": 2.0},
-          n=max(window, int(flags.get("PX_AUTOTUNE_MIN_SAMPLES"))))
-    m.observe(GATE_CPU_CROSSOVER, "agg", "4^8", "cpu", 500.0 / 1e3)
-    for _ in range(window):
-        m.observe(GATE_CPU_CROSSOVER, "agg", "4^8", "cpu", 2.0 / 1e3)
+    window = _warm_for_guard(m)
+    for _ in range(window // 2):
+        m.observe(GATE_CPU_CROSSOVER, "agg", "4^8", "cpu", 500.0 / 1e3)
 
-    def srcs(k):
-        return [m.decide(GATE_CPU_CROSSOVER, "agg", "4^8", "device",
-                         ("device", "cpu"))["source"] for _ in range(k)]
+    def srcs(k, cpu_ms):
+        out = []
+        for _ in range(k):
+            dec = _decide(m)
+            out.append((dec["arm"], dec["source"]))
+            if dec["source"] != "fallback":
+                m.observe_decision(
+                    dec, {"cpu": cpu_ms, "device": 50.0}[dec["arm"]] / 1e3)
+        return out
 
-    assert srcs(4) == ["fallback"] * 4  # trip + 3 held-off decisions
-    # past the holdoff the reset arm re-learns through the cold path
-    assert set(srcs(8)) <= {"cold", "explore"}
+    # trip + 3 held-off decisions
+    assert srcs(4, 2.0) == [("device", "fallback")] * 4
+    # past the hold-off the arm is compared as it stood before it drifted:
+    # the very next decision favors it again, and none is a cold warm-up
+    back = srcs(8, 2.0)
+    assert back[0] == ("cpu", "model")
+    assert {s for _a, s in back} <= {"model", "explore"}
+    assert m.snapshot()[GATE_CPU_CROSSOVER]["fallbacks"] == 1
+    # a drift that has outlasted the hold-off trips again within a window
+    again = srcs(window, 500.0)
+    assert ("device", "fallback") in again
+    assert _decide(m)["guard_trips"] == 2
+
+
+def test_pacing_of_a_fresh_key_is_unchanged():
+    """The sources of a fresh key's first 64 decisions under constant
+    samples (static arm cpu 42 ms, device 23 ms: a table under the
+    crossover): a cold probe every COLD_PROBE_PERIODth until the device arm
+    has PX_AUTOTUNE_MIN_SAMPLES, then the model with an explore every
+    16th.  The benchmark's warm-ups (32 queries) are sized to this."""
+    m = AutotuneModel()
+    got = []
+    for _ in range(64):
+        dec = m.decide(GATE_CPU_CROSSOVER, "agg", "4^10", "cpu",
+                       ("cpu", "device"))
+        got.append((dec["arm"], dec["source"]))
+        m.observe_decision(dec, {"cpu": 42.0, "device": 23.0}[dec["arm"]]
+                           / 1e3)
+    want = [("device", "explore") if i % 4 == 3 else ("cpu", "cold")
+            for i in range(32)]
+    want += [("cpu", "explore") if i % 16 == 15 else ("device", "model")
+             for i in range(32, 64)]
+    assert got == want
+
+
+def test_sample_excludes_compile_seconds():
+    """Through the executor: the sample folded into the routing model is
+    the chain's wall less what jax compiled in it, and the compile time is
+    recorded beside it."""
+    from pixie_tpu.compiler import compile_pxl
+    from pixie_tpu.engine.executor import PlanExecutor
+    from pixie_tpu.table import TableStore
+    from pixie_tpu.types import DataType as DT, Relation
+
+    flags.set_for_testing("PX_AUTOTUNE", True)
+    flags.set_for_testing("PX_CPU_CROSSOVER_ROWS", 64)  # jitted chain
+    store = TableStore()
+    t = store.create("t", Relation.of(("time_", DT.TIME64NS),
+                                      ("k", DT.INT64), ("v", DT.INT64)))
+    n = 5000
+    t.write({"time_": np.arange(n, dtype=np.int64),
+             "k": np.arange(n, dtype=np.int64) % 7,
+             "v": np.arange(n, dtype=np.int64)})
+    # a constant no other test uses: this chain's kernel is traced here
+    src = ("df = px.DataFrame(table='t')\n"
+           "df.w = df.v + 29029\n"
+           "df = df.groupby('k').agg(s=('w', px.sum), c=('v', px.count))\n"
+           "px.display(df, 'out')\n")
+    plan = compile_pxl(src, store.schemas()).plan
+
+    def run():
+        ex = PlanExecutor(plan, store)
+        ex.run()
+        dec = next(d for d in ex.stats["autotune"]
+                   if d["gate"] == GATE_CPU_CROSSOVER)
+        chain = next(r for r in ex.op_stats
+                     if r["label"].endswith("partial_agg"))
+        return ex, dec, chain["wall_ns"] / 1e6
+
+    ex, dec, wall_ms = run()
+    assert ex.stats["compile_s"] > 0 and dec["compile_ms"] > 0
+    assert dec["observed_ms"] == pytest.approx(
+        max(wall_ms - dec["compile_ms"], 0.0), abs=0.01)
+    arm = autotune.MODEL._gates[GATE_CPU_CROSSOVER].arms[
+        f"agg|{dec['size_bucket']}"][dec["arm"]]
+    assert arm.ring[-1] == pytest.approx(dec["observed_ms"] / 1e3, abs=1e-5)
+    # a warm run compiles nothing and its sample is its whole wall
+    ex2, dec2, wall2_ms = run()
+    assert dec2["compile_ms"] == 0.0
+    assert dec2["observed_ms"] == pytest.approx(wall2_ms, abs=0.01)
 
 
 def test_hedge_floor_only_lowers_the_static_floor():

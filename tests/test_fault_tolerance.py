@@ -265,8 +265,10 @@ def test_straggler_hedge_first_answer_wins_duplicates_discarded():
                          store=stores["pem2"], heartbeat_s=0.2).start()
     client = Client("127.0.0.1", broker.port, timeout_s=30.0)
     try:
-        # warm the service-time model past HEDGE_MIN_SAMPLES
-        for _ in range(9):
+        # warm the service-time model past HEDGE_MIN_SAMPLES, and until
+        # the first query's compile has left its deadline (ewma + 4 dev):
+        # after 9 a first query of over 1.8 s kept it past the 2.5 s stall
+        for _ in range(20):
             client.execute_script(AGG_SCRIPT)
         baseline = _canon(client.execute_script(AGG_SCRIPT))
         h0 = metrics.counter_value("px_hedged_dispatches_total")

@@ -147,6 +147,7 @@ def test_served_query_spans(served, monkeypatch, accelerator_process):
     a = chain.attributes
     assert a["engine"] == "xla_cpu_chain" and a["arm"] == "cpu"
     assert a["source"] == "cold" and a["decision_n"] == 1
+    assert a["guard_trips"] == 0
     assert a["size_bucket"] == autotune.size_bucket(3000)
     assert a["rows"] == 3000
     (feed,) = [s for s in spans if s.name == "feed"]
@@ -181,10 +182,34 @@ def test_forced_explore_is_a_chain_span(served):
     assert probe["decision_n"] == autotune.COLD_PROBE_PERIOD
 
 
+JOIN_QUERY = """
+import px
+a = px.DataFrame(table='http_events')
+a = a.groupby('service').agg(cnt=('latency', px.count))
+b = px.DataFrame(table='http_events')
+b = b.groupby('service').agg(mx=('latency', px.max))
+df = a.merge(b, how='inner', left_on='service', right_on='service',
+             suffixes=['', '_r'])
+px.display(df, 'out')
+"""
+
+
+def test_join_span_says_rows_and_kernel(served):
+    """The `join` frame's span carries both sides' rows, the rows out and
+    the match kernel; with one agent the join is in the broker's half."""
+    client, _store = served
+    out = client.execute_script(JOIN_QUERY)["out"].to_pandas()
+    (join,) = [s for s in trace.recent() if s.name == "join"]
+    assert join.service == "broker"
+    a = join.attributes
+    assert a["rows_left"] == a["rows_right"] == a["rows_out"] == len(out) > 0
+    assert a["kernel"] == "host_sort"
+
+
 def test_compile_time_is_in_exec_stats_and_spans(served):
     """A cold query's jax trace + lower + backend-compile seconds are in
-    its exec_stats and as `jax_compile` spans that sum to them; a warm
-    repeat has neither."""
+    its exec_stats and as `jax_compile` spans that sum to them, and out of
+    the sample its routing decision observed; a warm repeat has neither."""
     client, _store = served
     q = QUERY.format(floor=7)  # a chain no other test compiles
     cold = client.execute_script(q)["out"].exec_stats["agents"]["pem0"]
@@ -196,8 +221,13 @@ def test_compile_time_is_in_exec_stats_and_spans(served):
                if s.attributes["kind"] == "backend_compile")
     assert sum(s.duration_ns for s in spans) / 1e9 == pytest.approx(
         cold["compile_s"], rel=0.05)
+    # the router's sample is the chain's wall less the compile beside it
     (dec,) = cold["autotune"]
-    assert 0 < dec["compile_ms"] <= dec["observed_ms"]
+    (chain,) = _chains(_agent_spans())
+    assert dec["compile_ms"] > 0
+    assert dec["observed_ms"] == pytest.approx(
+        max(chain.duration_ns / 1e6 - dec["compile_ms"], 0.0), abs=0.01)
+    assert dec["observed_ms"] < 0.2 * chain.duration_ns / 1e6
     t_warm = time.time_ns()
     warm = client.execute_script(q)["out"].exec_stats["agents"]["pem0"]
     assert warm["compile_s"] == 0 and warm["compiles"] == 0
@@ -352,7 +382,8 @@ def _window(tr: trace.Tracer) -> dict:
     def query(t0_ms, exec_ms, chain_ms, engine, source, wait, wait_ms):
         root = span("exec", t0_ms, exec_ms)
         span("scan(t)->partial_agg", t0_ms + 2, chain_ms, root.trace_id,
-             root.span_id, engine=engine, source=source)
+             root.span_id, engine=engine, source=source, size_bucket="4^10",
+             arm="cpu" if engine == "xla_cpu_chain" else "device")
         span(wait, t0_ms + 3, wait_ms, root.trace_id, root.span_id)
         span("telemetry_flush", t0_ms + exec_ms, 1.5, root.trace_id,
              root.span_id)
@@ -370,6 +401,16 @@ def _window(tr: trace.Tracer) -> dict:
     query(1700, 118, 110, "xla_cpu_chain", "static", "cpu_chain_wait", 105)
     span("jax_compile", 1705, 4, kind="trace")
     span("jax_compile", 1709, 6, kind="backend_compile")
+    # the router changes its mind twice in the large bucket (the second
+    # time under the guard's hold-off); the small bucket's arm is its own
+    for t0, arm, source, bucket in ((1900, "device", "model", "4^10"),
+                                    (1940, "device", "static", "4^4"),
+                                    (1950, "cpu", "fallback", "4^10")):
+        span("scan(u)->partial_agg", t0, 5, engine="np_partial", arm=arm,
+             source=source, size_bucket=bucket)
+    for t0, dur in ((1100, 0.4), (1300, 0.6), (1500, 0.5)):
+        span("join", t0, dur, rows_left=110, rows_right=110,
+             kernel="host_sort")
     queries = [{"t0_unix_ns": 1000 * MS, "wall_ms": 140.0},
                {"t0_unix_ns": 1200 * MS, "wall_ms": 120.0},
                {"t0_unix_ns": 1400 * MS, "wall_ms": 600.0}]
@@ -385,6 +426,10 @@ READERS = {
     "router_probe_time_share": 30.0,   # 300 ms of 1000
     "compile_ms_in_window": 10.0,
     "telemetry_ms_per_query": 8.0 / 3,  # four flushes + writes, three queries
+    "router_fallback_share": 100.0 / 7,  # one of the window's seven chains
+    # cpu, cpu, (explore), cpu, device, cpu in 4^10; 4^4 stays on its arm
+    "router_arm_flips": 2.0,
+    "join_ms": 0.5,
 }
 
 
