@@ -13,6 +13,13 @@ favor, not the one a build-time constant picked):
   * **Per-gate cost models.**  Each gate keeps, per (plan class, size
     bucket) key, one `_Arm` per choice (service-time EWMA + mean-absolute
     deviation + a bounded sample ring — the PR 15 ratemodel estimator).
+    The caller names the class.  For the CPU/device crossover it is the
+    chain being routed (`executor._route_class_of`: `agg:<table>:<digest
+    of the chain's ops and its aggregate>`, less everything that changes
+    while the work does not — time bounds, dictionary sizes, the metadata
+    epoch), so two scripts over the same rows price their own arms and one
+    script keeps one key; a chain whose completions nobody observes gets
+    no key and routes by the static crossover alone.
     An arm's predicted cost is the MEDIAN of its ring, so one slow sample
     among its recent ones moves no decision; the EWMA is what persists
     and what a KV-warmed arm answers with until its ring has samples.

@@ -112,6 +112,7 @@ FEED_ROWS = _flags.define_int(
 # origins) is covered by including the table's rows_written in agg signatures.
 import collections as _collections
 import functools as _functools
+import hashlib as _hashlib
 import inspect as _inspect
 import json as _json
 import threading as _threading
@@ -400,6 +401,22 @@ def _route_backend(src, scale: int = 1) -> str:
             n * max(1, scale) <= int(_flags.get("PX_CPU_CROSSOVER_ROWS")):
         return "cpu"
     return "device"
+
+
+def _route_class_of(head, chain, op) -> str:
+    """The class of the router's model key for the chain `head -> chain ->
+    op`: what _chain_cache_sig signs less everything that changes while the
+    work does not.  In: the head's table name (a blocking head's kind), the
+    chain's ops and the blocking op (group keys, UDAs).  Out: the scan's
+    time and row-id bounds, its column list, the table's uid, dictionary
+    identities and sizes, the metadata epoch, the mesh, key-set hashes and
+    rows_written: one script at any start shares one key with itself and
+    with no other script.  No `|` in it (autotune joins its keys on one)."""
+    name = head.table if isinstance(head, MemorySourceOp) else head.kind
+    body = _json.dumps([[_op_sig(o) for o in chain], _op_sig(op)],
+                       sort_keys=True, default=str)
+    digest = _hashlib.blake2s(body.encode(), digest_size=5).hexdigest()
+    return f"agg:{name.replace('|', '_')}:{digest}"
 
 
 def _iter_call_fns(expr):
@@ -1207,9 +1224,14 @@ class PlanExecutor:
         #: CPU/TPU routing multiplies local input sizes by this so a sharded
         #: query routes by its TOTAL size (see _route_backend).
         self.route_scale = max(1, int(route_scale))
-        #: adaptive-routing decisions taken for this query, one per size
-        #: bucket (engine/autotune.py; empty with PX_AUTOTUNE=0)
-        self._at_route: dict[str, dict] = {}
+        #: adaptive-routing decisions taken for this query, one per
+        #: (route class, size bucket) (engine/autotune.py; empty with
+        #: PX_AUTOTUNE=0)
+        self._at_route: dict[tuple, dict] = {}
+        #: id(src) -> (src, model key) of the chains the model routes: those
+        #: whose wall is folded back into the decision that routed them
+        #: (_name_route); the src is held so that its id is not reused
+        self._route_keys: dict[int, tuple] = {}
         #: pin the dispatch backend regardless of input size.  The streaming
         #: executor pins "cpu": every poll delta would re-UPLOAD its rows to
         #: the device (hot data is host-resident), so size-based routing is
@@ -1247,26 +1269,48 @@ class PlanExecutor:
             self.stats.setdefault("device", {})["collective_gate"] = gate
 
     # ------------------------------------------------------------- routing
+    def _name_route(self, src, head, chain, op) -> None:
+        """Give the chain `head -> chain -> op` over `src` its model key,
+        (route class, size bucket): its caller folds the chain's wall into
+        the decision that routes it.  No key with the model off, the
+        backend forced or an input of unknown size; and none for a chain
+        nobody names here, which the static crossover routes alone (a key
+        whose completions nothing folds back would stay cold and probe
+        for ever)."""
+        n = _src_rows(src)
+        if (self.force_backend is None and n is not None
+                and _autotune.enabled()):
+            self._route_keys[id(src)] = (src, (
+                _route_class_of(head, chain, op),
+                _autotune.size_bucket(n * self.route_scale)))
+
+    def _route_key(self, src) -> Optional[tuple]:
+        return self._route_keys.get(id(src), (None, None))[1]
+
+    def _route_decision(self, src) -> Optional[dict]:
+        """The model's decision this query took for the chain over `src`,
+        if it took one."""
+        return self._at_route.get(self._route_key(src))
+
     def _backend_for(self, src) -> str:
         if self.force_backend is not None:
             return self.force_backend
         static = _route_backend(src, self.route_scale)
-        if not _autotune.enabled():
+        key = self._route_key(src)
+        if key is None:
             return static
-        n = _src_rows(src)
-        if n is None:
-            return static
-        # one decision per size bucket per executor: every _backend_for
-        # call for this query's inputs routes consistently (fast paths ask
-        # repeatedly), and stats["autotune"] carries exactly the decisions
-        # this query ran under
-        bucket = _autotune.size_bucket(n * self.route_scale)
-        dec = self._at_route.get(bucket)
+        # the model's key is (gate, route class, size bucket): the class
+        # names the chain being routed (_route_class_of), so two scripts
+        # over the same rows price their own arms.  One decision per key
+        # per executor: every _backend_for call for this chain routes
+        # consistently (fast paths ask repeatedly), and stats["autotune"]
+        # carries exactly the decisions this query ran under
+        dec = self._at_route.get(key)
         if dec is None:
             dec = _autotune.MODEL.decide(
-                _autotune.GATE_CPU_CROSSOVER, "agg", bucket,
+                _autotune.GATE_CPU_CROSSOVER, key[0], key[1],
                 "cpu" if static == "cpu" else "device", ("cpu", "device"))
-            self._at_route[bucket] = dec
+            self._at_route[key] = dec
             self.stats.setdefault("autotune", []).append(dec)
         return "cpu" if dec["arm"] == "cpu" else "device"
 
@@ -1318,17 +1362,19 @@ class PlanExecutor:
     def _route_attrs(self, src) -> dict:
         """What the router decided for a chain over `src`: the arm it ran
         on and, where the adaptive model took the decision, its source
-        (`model`/`static`/`cold`/`explore`/`fallback`), size bucket,
-        number and how often the key's tail guard has tripped so far.  A
-        chain span with source=explore is a router probe."""
+        (`model`/`static`/`cold`/`explore`/`fallback`), the key's route
+        class and size bucket, the decision's number and how often the
+        key's tail guard has tripped so far.  A chain span with
+        source=explore is a router probe."""
         n = _src_rows(src) if src is not None else None
         if n is None:
             return {}
         attrs = {"arm": self._backend_for(src), "rows": n}
-        dec = self._at_route.get(_autotune.size_bucket(n * self.route_scale))
+        dec = self._route_decision(src)
         if dec is not None:
-            attrs.update(source=dec["source"], size_bucket=dec["size_bucket"],
-                         decision_n=dec["n"], guard_trips=dec["guard_trips"])
+            attrs.update(source=dec["source"], plan_class=dec["plan_class"],
+                         size_bucket=dec["size_bucket"], decision_n=dec["n"],
+                         guard_trips=dec["guard_trips"])
         return attrs
 
     # -------------------------------------------------------------- exec stats
@@ -2258,6 +2304,7 @@ class PlanExecutor:
         dtypes, dicts, names, visible, chain = _prune_to_needed(
             head, chain, dtypes, dicts, names, visible, time_col, needed,
         )
+        self._name_route(src, head, chain, op)  # _agg_state observes it
 
         # Agg kernels bake data-dependent key sets (intdevice uniques, window
         # origins) unless every group key is dictionary-backed; cover that with
@@ -2451,23 +2498,19 @@ class PlanExecutor:
                         src, names, cap, t_lo, t_hi, luts, fuse_key=sig,
                     )
                 self._feed_rec = None
-        if self._at_route and rec.get("wall_ns"):
+        dec = self._route_decision(src)
+        if dec is not None and rec.get("wall_ns"):
             # fold the measured chain wall into the routing decision that
-            # picked this backend (per-arm cost model, engine/autotune.py)
-            n = _src_rows(src)
-            if n is not None:
-                dec = self._at_route.get(
-                    _autotune.size_bucket(n * self.route_scale))
-                if dec is not None:
-                    # the sample is the chain's wall less what jax compiled
-                    # in it (recorded beside it): a program's first run
-                    # must not price its arm.  jax reports nested traces
-                    # inside their outer one too, so compile_s can pass the
-                    # wall by a few percent and that one sample reads 0
-                    compile_s = self.stats["compile_s"] - compile_s0
-                    _autotune.MODEL.observe_decision(
-                        dec, max(rec["wall_ns"] / 1e9 - compile_s, 0.0))
-                    dec["compile_ms"] = round(compile_s * 1e3, 3)
+            # picked this backend (per-arm cost model, engine/autotune.py).
+            # The sample is the chain's wall less what jax compiled in it
+            # (recorded beside it): a program's first run must not price
+            # its arm.  jax reports nested traces inside their outer one
+            # too, so compile_s can pass the wall by a few percent and that
+            # one sample reads 0
+            compile_s = self.stats["compile_s"] - compile_s0
+            _autotune.MODEL.observe_decision(
+                dec, max(rec["wall_ns"] / 1e9 - compile_s, 0.0))
+            dec["compile_ms"] = round(compile_s * 1e3, 3)
         return keys, udas, state_np, seen_name, in_types, val_dicts
 
     def _wholeplan_program(self, sig, kern, chain, op, keys, init_specs,

@@ -306,7 +306,7 @@ def test_sample_excludes_compile_seconds():
     assert dec["observed_ms"] == pytest.approx(
         max(wall_ms - dec["compile_ms"], 0.0), abs=0.01)
     arm = autotune.MODEL._gates[GATE_CPU_CROSSOVER].arms[
-        f"agg|{dec['size_bucket']}"][dec["arm"]]
+        f"{dec['plan_class']}|{dec['size_bucket']}"][dec["arm"]]
     assert arm.ring[-1] == pytest.approx(dec["observed_ms"] / 1e3, abs=1e-5)
     # a warm run compiles nothing and its sample is its whole wall
     ex2, dec2, wall2_ms = run()
