@@ -526,7 +526,8 @@ class AutotuneModel:
         sketch dispatch happens at kernel-trace time and is baked into the
         compiled program, so this gate is model-only — no per-query
         exploration (probing would churn the jit cache), the fit comes from
-        the explicit crossover probe the bench runs each round."""
+        an explicit run of `ops/sketch.measure_update_crossover`, which no
+        caller makes today (ROADMAP D7)."""
         min_n = int(flags.get("PX_AUTOTUNE_MIN_SAMPLES"))
         fitted = None
         with self._lock:
@@ -593,7 +594,7 @@ class AutotuneModel:
         return out
 
     def snapshot(self) -> dict:
-        """Per-gate model state for bench reports and ops surfaces."""
+        """Per-gate model state for tests and ops surfaces."""
         out = {}
         with self._lock:
             for gate, g in self._gates.items():

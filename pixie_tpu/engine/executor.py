@@ -395,7 +395,7 @@ def _route_backend(src, scale: int = 1) -> str:
     """
     n = _src_rows(src)
     # read through the flag registry (not the import-time constant) so the
-    # crossover is live-tunable — the static arm the autotune A/B bench and
+    # crossover is live-tunable — the static arm the autotune tests and
     # the tail-guard fallback both pin against
     if n is not None and \
             n * max(1, scale) <= int(_flags.get("PX_CPU_CROSSOVER_ROWS")):

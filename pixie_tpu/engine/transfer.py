@@ -49,7 +49,7 @@ _flags.define_float(
 #: measured-probe memo: the RTT floor and H2D bandwidth are environmental
 #: constants of the process (link + runtime), so each (probe, shape,
 #: device) pair measures ONCE per probe epoch — call sites used to
-#: re-measure independently (bench, the device-join gate), each paying
+#: re-measure independently (chip_smoke, the device-join gate), each paying
 #: ~100+ ms of timed transfers.  Entries carry their measurement time and
 #: expire past PX_PROBE_MAX_AGE_S (a link's bandwidth need NOT be a
 #: constant of the process lifetime);

@@ -126,7 +126,8 @@ def env_exports() -> dict[str, str]:
     """Declared flags as a child-process environment fragment: every flag
     whose effective value differs from its default (env override or
     set_for_testing), stringified for re-parse by the child's registry.
-    Subprocess harnesses (parallel/shard_bench workers) use this instead of
+    Whatever spawns a worker (ProcLauncher's agents, the sharded-parity
+    fixture's jax.distributed workers) uses this instead of
     forwarding raw os.environ reads — the flag registry stays the single
     config surface on both sides of the fork."""
     out: dict[str, str] = {}

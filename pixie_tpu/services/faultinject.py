@@ -258,7 +258,7 @@ def active() -> Optional[FaultInjector]:
 
 
 # arm from the environment at import: a process started with PL_FAULT_PLAN
-# set (the chaos bench's subprocesses, an operator reproducing a failure)
+# set (a test's agent subprocesses, an operator reproducing a failure)
 # injects without any code calling install()
 if str(flags.get("PL_FAULT_PLAN")).strip():  # pragma: no cover — env-driven
     install()

@@ -15,10 +15,6 @@ users:
                   compile), degradation state (readyz flip, cold-query
                   shedding, stale matview serving, narrowed chunk ack
                   windows)
-  load_bench.py — closed-loop load harness: hundreds of concurrent
-                  mixed-tenant clients against a real broker+agents
-                  deployment, reporting p50/p99, goodput, shed rate and
-                  per-tenant fairness (the `serving_load` bench config)
   ratemodel.py  — measured per-(tenant, plan-class) service-rate model:
                   replaces the static warm/cold DRR costs and heuristic
                   retry-after with measured rates, and supplies the
@@ -26,9 +22,6 @@ users:
   elastic.py    — AgentSupervisor: broker-driven agent autoscaling with
                   hysteresis/cooldowns/bounds, loss-safe retires, and
                   orphan-proof launchers (PL_AUTOSCALE)
-  elastic_bench.py — diurnal-ramp elasticity proof (the `elastic_ramp`
-                  bench config: scale both ways under injected
-                  preemption, bit-equal throughout)
 
 Live quotas: `ServingFront.set_quota` applies control-plane records
 (`admission.normalize_quota`) ahead of the PL_TENANT_* env specs; the

@@ -29,12 +29,12 @@ Control loop (one tick per ``PL_AUTOSCALE_PERIOD_S``):
     ``faultinject kill:`` rule) is reaped once past the rejoin grace and,
     under sustained pressure, replaced by the normal scale-up path.
 
-Launchers: ``ThreadLauncher`` runs agents in-process (the same harness
-``services/chaos_bench.py`` restarts kills with — benches and tests);
+Launchers: ``ThreadLauncher`` runs agents in-process over the real
+framed-TCP transport (what the tests use);
 ``ProcLauncher`` spawns real ``python -m pixie_tpu.services.agent``
 subprocesses with orphan-proof cleanup (``PR_SET_PDEATHSIG`` so a
-SIGKILLed harness takes its children with it, plus an atexit sweep for
-clean exits) — a crashed bench can never leave agents squatting on ports.
+SIGKILLed parent takes its children with it, plus an atexit sweep for
+clean exits) — a crashed parent can never leave agents squatting on ports.
 
 Every decision lands in ``self_telemetry.scale_events`` with the smoothed
 pressure that drove it.  ``PL_AUTOSCALE=0`` (the default) never starts the
@@ -239,7 +239,7 @@ class ProcLauncher:
 
         env = dict(os.environ)
         # the flag registry is the single config surface on both sides of
-        # the fork (parallel/shard_bench precedent)
+        # the fork
         env.update(flags.env_exports())
         with self._spawn_lock:
             device_env, chip = self._device_env()
@@ -277,11 +277,11 @@ class ProcLauncher:
 
 
 class ThreadLauncher:
-    """In-process agents over the real framed-TCP transport — the same
-    harness shape chaos_bench restarts kills with.  `store_factory(name)`
-    supplies each spawned agent's TableStore (default: empty) — benches
-    pass a factory that pre-creates the serving tables' SCHEMAS (empty) so
-    the new shard joins every plan without perturbing results."""
+    """In-process agents over the real framed-TCP transport.
+    `store_factory(name)` supplies each spawned agent's TableStore
+    (default: empty) — tests pass a factory that pre-creates the serving
+    tables' SCHEMAS (empty) so the new shard joins every plan without
+    perturbing results."""
 
     def __init__(self, broker_host: str, broker_port: int,
                  store_factory: Optional[Callable] = None,

@@ -1,35 +1,32 @@
-"""Real-size sharded execution bench — the MULTICHIP round's non-dryrun run.
+"""Fixture of tests/test_sharded_parity.py: one workload, and three ways to
+run it sharded that each compare the result bit for bit with the
+single-device one.
 
-This module is what graduated the 2-process `jax.distributed` multihost
-SMOKE test (tests/test_multihost_mp.py) into a BENCHED configuration
-(`sharded_agg_64m` in bench.py): filter→map→partial-agg runs shard-local
-over a device mesh with ONE in-program collective merge (psum/pmin/pmax) at
-the blocking boundary, at real sizes (default 64M rows across 8 devices),
-and reports rows/s + p50 with bit-equality against the single-device
-kernel verified on every run.
-
-Three runners, sharing one workload (`build_store` / chain shape):
+filter→map→partial-agg runs shard-local over a device mesh with ONE
+in-program collective merge (psum/pmin/pmax) at the blocking boundary.
+Three runners share the workload (`build_store` / chain shape):
 
   * `run_local(...)` — the ENGINE path: a real TableStore + PlanExecutor
-    over an n-device mesh, so the measured run exercises the sharded
-    GSPMD feed layout (NamedSharding placement + the sharded-resident
-    tier), per-shard transfer accounting, and the SPMD partial step —
-    compared bit-for-bit against `PlanExecutor(mesh=None)`.
+    over an n-device mesh, so the run exercises the sharded GSPMD feed
+    layout (NamedSharding placement + the sharded-resident tier), per-shard
+    transfer accounting, and the SPMD partial step — compared bit-for-bit
+    against `PlanExecutor(mesh=None)`.
   * `run_shuffled_join(...)` — the pod-scale shuffle join: one agent's
     8-device mesh, the planner widening the repartition to mesh size, both
     sides exchanged with ONE `lax.all_to_all` each, per-partition joins
     riding the radix device join — compared against the single-device join.
   * `run_multihost(...)` (via `main --worker`; `run_subprocess` drives it
-    over virtual CPU devices and says `platform=cpu`) — the 2-process
-    `jax.distributed` job: each process feeds ONLY its host-local shards
+    over virtual CPU devices) — the 2-process `jax.distributed` job: each
+    process feeds ONLY its host-local shards
     (`jax.make_array_from_process_local_data`) and the jitted collective
-    merge spans processes (ICI within a host, DCN across) — the scaling
-    recipe of SNIPPETS [1]-[3]'s pjit/mesh API surface at real sizes.
+    merge spans processes — the scaling recipe of SNIPPETS [1]-[3]'s
+    pjit/mesh API surface.
 
 Every aggregate in the workload is ORDER-INDEPENDENT at the bit level
 (count/sum/mean over ints, min/max, log-histogram p50 whose counts are
 integer-valued), so "bit-equal to the single-device result" is a checked
-invariant, not an rtol claim — see `assert_bitequal`.
+invariant, not an rtol claim — see `assert_bitequal`.  Nothing here times
+anything: a speed is measured on the chip, by `benchmarks/`.
 """
 from __future__ import annotations
 
@@ -38,11 +35,9 @@ import os
 import socket
 import subprocess
 import sys
-import time
 
 import numpy as np
 
-SEC = 1_000_000_000
 N_SERVICES = 16
 STATUSES = (200, 404, 500)
 
@@ -52,11 +47,10 @@ def shard_cols(rows: int, shard: int, n_shards: int) -> dict:
     """Generate ONE row-block shard of the workload, seeded by shard index —
     any process can build exactly its shards (multihost host-local feeds)
     while the oracle rebuilds the full table from the same seeds."""
-    per = rows // n_shards
+    n = rows // n_shards
     rng = np.random.default_rng(1234 + shard)
-    n = per
     return {
-        "time_": (shard * per + np.arange(n, dtype=np.int64)) * 1000,
+        "time_": (shard * n + np.arange(n, dtype=np.int64)) * 1000,
         "service": rng.integers(0, N_SERVICES, n).astype(np.int32),
         "status": rng.choice(np.asarray(STATUSES, dtype=np.int64), n),
         "bytes": rng.integers(0, 1 << 20, n).astype(np.int64),
@@ -161,15 +155,12 @@ def _result_cols(res) -> dict:
     return {k: np.asarray(v) for k, v in res.cols.items()}
 
 
-def _p50(xs):
-    return sorted(xs)[len(xs) // 2]
-
-
 # ------------------------------------------------------- engine-path runner
-def run_local(rows: int, repeats: int = 3, n_devices: int = 8) -> dict:
-    """The engine-path sharded run: PlanExecutor over an n-device mesh vs
-    the single-device executor, bit-equal, with warm-feed transfer and
-    skew accounting.  Returns the result dict (see keys below)."""
+def run_local(rows: int, n_devices: int = 8) -> dict:
+    """The engine-path sharded run: PlanExecutor over an n-device mesh, one
+    cold run (compiles, admits the sharded tier) and one warm, the warm one
+    compared bit for bit with the single-device executor.  Returns the warm
+    run's feed and skew accounting."""
     import jax
 
     from pixie_tpu.engine.executor import PlanExecutor
@@ -181,35 +172,18 @@ def run_local(rows: int, repeats: int = 3, n_devices: int = 8) -> dict:
     ts = build_store(rows)
     plan = agg_plan()
     mesh = make_mesh(n_devices)
-
-    def run_sharded():
+    for _ in range(2):
         ex = PlanExecutor(plan, ts, mesh=mesh, force_backend="device")
-        return ex.run()["output"], ex
-
-    out, ex = run_sharded()  # cold: compiles + admits the sharded tier
-    times = []
-    for _ in range(max(repeats, 2)):
-        t0 = time.perf_counter()
-        out, ex = run_sharded()
-        times.append(time.perf_counter() - t0)
+        out = ex.run()["output"]
     single = PlanExecutor(plan, ts, mesh=None, force_backend="device")
-    sres = single.run()["output"]
-    assert_bitequal(out, sres)
-    p50 = _p50(times)
+    assert_bitequal(out, single.run()["output"])
     stats = ex.stats
     return {
         "rows": rows,
-        "platform": mesh.devices.flat[0].platform,
         "n_devices": n_devices,
-        "rows_per_sec": round(rows / p50),
-        "p50_ms": round(p50 * 1000, 1),
         "bit_equal": True,
         "spmd_feeds": int(stats.get("spmd_feeds", 0)),
-        "resident_feeds": int(stats.get("resident_feeds", 0)),
-        "warm_h2d_bytes": int(stats.get("h2d_bytes", 0)),
         "shard_skew_frac": stats.get("shard_skew_frac"),
-        "collective_gate": (stats.get("device") or {}).get(
-            "collective_gate", {}).get("reason"),
     }
 
 
@@ -269,9 +243,7 @@ def run_shuffled_join(rows_per_side: int, n_devices: int = 8) -> dict:
         raise RuntimeError(
             f"planner did not widen the shuffle to the mesh: "
             f"{[s.n_parts for s in dp.join_stages]}")
-    t0 = time.perf_counter()
     res = cluster.execute(plan)["out"]
-    secs = time.perf_counter() - t0
     agents = res.exec_stats["agents"]
     shuffles = sum(s.get("mesh_shuffles", 0) for s in agents.values())
     if shuffles < 2:
@@ -281,7 +253,6 @@ def run_shuffled_join(rows_per_side: int, n_devices: int = 8) -> dict:
     return {
         "rows": 2 * rows_per_side,
         "n_parts": dp.join_stages[0].n_parts,
-        "rows_per_sec": round(2 * rows_per_side / secs),
         "all_to_all_exchanges": int(shuffles),
         "bit_equal": True,
         "join_rows": int(np.asarray(res.decoded("n"))[0]),
@@ -290,7 +261,7 @@ def run_shuffled_join(rows_per_side: int, n_devices: int = 8) -> dict:
 
 # ------------------------------------------------------- multihost runner
 def _chain_kernel():
-    """The multihost bench's fragment kernel: the same
+    """The multihost run's fragment kernel: the same
     filter→map→partial-agg chain, at the ChainKernel level (the multihost
     data plane feeds the kernel directly — each process owns only its
     host-local shards, so the TableStore/executor layer stays per-process)."""
@@ -341,8 +312,8 @@ def _chain_kernel():
     return kern, udas, init_specs, num_groups
 
 
-def run_multihost(rows: int, repeats: int, mesh) -> dict:
-    """One process's share of the benched multihost sharded agg: feed ONLY
+def run_multihost(rows: int, mesh) -> dict:
+    """One process's share of the multihost sharded agg: feed ONLY
     host-local shards, run the lifted partial step (shard-local chain + one
     in-program collective merge) over the GLOBAL mesh, verify bit-equality
     vs the single-device kernel on process 0."""
@@ -387,25 +358,12 @@ def run_multihost(rows: int, repeats: int, mesh) -> dict:
                              mesh)
     t_lo, t_hi = np.int64(INT64_MIN), np.int64(INT64_MAX)
 
-    def run_once():
-        t0 = time.perf_counter()
-        out = step(gcols, gnv, t_lo, t_hi, kern.luts)
-        jax.block_until_ready(out)
-        return time.perf_counter() - t0, out
-
-    run_once()  # compile + warm
-    times, out = [], None
-    for _ in range(max(repeats, 2)):
-        dt, out = run_once()
-        times.append(dt)
+    out = step(gcols, gnv, t_lo, t_hi, kern.luts)
     state = jax.tree.map(np.asarray, out)
     result = {
         "rows": rows,
-        "platform": mesh.devices.flat[0].platform,
         "n_devices": n_dev,
         "processes": int(jax.process_count()),
-        "rows_per_sec": round(rows / _p50(times)),
-        "p50_ms": round(_p50(times) * 1000, 1),
     }
     if jax.process_index() == 0:
         # single-device oracle over the FULL regenerated data — bit-equal
@@ -453,10 +411,9 @@ def _free_port() -> int:
         return s.getsockname()[1]
 
 
-def run_subprocess(rows: int, repeats: int = 3, processes: int = 2,
-                   devices_per_proc: int = 4,
+def run_subprocess(rows: int, processes: int = 2, devices_per_proc: int = 4,
                    timeout: float = 1200.0) -> dict:
-    """The CPU multi-process form, for tests and CPU boxes: `processes` ×
+    """The CPU multi-process form: `processes` ×
     `devices_per_proc` VIRTUAL CPU devices joined through a
     jax.distributed coordinator (`_worker_env` names the CPU platform
     explicitly, and the result says `"platform": "cpu"`).  On a host with
@@ -465,8 +422,8 @@ def run_subprocess(rows: int, repeats: int = 3, processes: int = 2,
     onto them."""
     coord = f"127.0.0.1:{_free_port()}"
     env = _worker_env(devices_per_proc)
-    base = [sys.executable, "-m", "pixie_tpu.parallel.shard_bench",
-            "--worker", "--rows", str(rows), "--repeats", str(repeats)]
+    base = [sys.executable, "-m", "pixie_tpu.testing.sharded_parity",
+            "--worker", "--rows", str(rows)]
     procs = [
         subprocess.Popen(
             base + ["--coordinator", coord, "--processes", str(processes),
@@ -481,15 +438,13 @@ def run_subprocess(rows: int, repeats: int = 3, processes: int = 2,
             out, err = p.communicate(timeout=timeout)
             if p.returncode != 0:
                 raise RuntimeError(
-                    f"sharded bench worker failed: {err[-2000:]!r}")
+                    f"sharded worker failed: {err[-2000:]!r}")
             outs.append(out)
     finally:
         for q in procs:  # peers block on a dead coordinator otherwise
             if q.poll() is None:
                 q.kill()
-    doc = json.loads(outs[0].strip().splitlines()[-1])
-    doc["mode"] = "multihost"
-    return doc
+    return json.loads(outs[0].strip().splitlines()[-1])
 
 
 def main(argv=None) -> int:
@@ -497,8 +452,7 @@ def main(argv=None) -> int:
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--worker", action="store_true")
-    ap.add_argument("--rows", type=int, default=64_000_000)
-    ap.add_argument("--repeats", type=int, default=3)
+    ap.add_argument("--rows", type=int, required=True)
     ap.add_argument("--coordinator", type=str, default="")
     ap.add_argument("--processes", type=int, default=1)
     ap.add_argument("--process-id", type=int, default=0)
@@ -519,7 +473,7 @@ def main(argv=None) -> int:
 
         mesh = make_mesh(len(jax.devices()))
     assert mesh is not None, "no multi-device mesh available"
-    out = run_multihost(args.rows, args.repeats, mesh)
+    out = run_multihost(args.rows, mesh)
     if jax.process_index() == 0:
         print(json.dumps(out), flush=True)
     return 0

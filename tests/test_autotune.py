@@ -17,10 +17,8 @@ from pixie_tpu.engine.autotune import (
 )
 from pixie_tpu.parallel.cluster import LocalCluster
 from pixie_tpu.services.broker import Broker
-from pixie_tpu.services.chaos_bench import (
-    SCRIPTS, _mkstore, canonical_bytes,
-)
 from pixie_tpu.services.kvstore import KVStore
+from pixie_tpu.testing.fixtures import SCRIPTS, canonical_bytes, mkstore
 
 import pixie_tpu.matview  # noqa: F401 — defines PL_MATVIEW_ENABLED
 
@@ -417,7 +415,7 @@ def test_model_persists_across_broker_restart(tmp_path):
 def test_autotune_off_is_bit_identical_and_silent():
     """PX_AUTOTUNE=0 removes every model read AND write; with the flag on,
     decisions appear in stats and the answers stay BIT-equal."""
-    stores = {f"pem{i}": _mkstore(i, 8_000) for i in range(2)}
+    stores = {f"pem{i}": mkstore(i, 8_000) for i in range(2)}
     cluster = LocalCluster(stores)
     # standing matviews would serve every repeat from cached fragments
     # and the routing gate would never run — the gate is what's under test

@@ -23,10 +23,10 @@ from pixie_tpu import flags, metrics
 from pixie_tpu.services import faultinject, replication
 from pixie_tpu.services.agent import Agent
 from pixie_tpu.services.broker import Broker
-from pixie_tpu.services.chaos_bench import canonical_bytes
 from pixie_tpu.services.client import Client
 from pixie_tpu.services.kvstore import KVStore
 from pixie_tpu.table import TableStore, journal
+from pixie_tpu.testing.fixtures import canonical_bytes
 from pixie_tpu.types import DataType as DT, Relation
 
 REL = Relation.of(

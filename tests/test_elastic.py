@@ -28,9 +28,11 @@ from pixie_tpu.serving.elastic import AgentSupervisor, ProcLauncher, ThreadLaunc
 from pixie_tpu.serving.ratemodel import ServiceRateModel
 from pixie_tpu.services.agent import Agent
 from pixie_tpu.services.broker import Broker
-from pixie_tpu.services.chaos_bench import SCRIPTS, _mkstore, canonical_bytes
 from pixie_tpu.services.client import Client, QueryError
 from pixie_tpu.status import InvalidArgument
+from pixie_tpu.testing.fixtures import (
+    HARD_BATCH_ROWS, SCRIPTS, canonical_bytes, mkdata, mkstore,
+)
 
 ELASTIC_FLAGS = (
     "PL_SERVING_ENABLED", "PL_SERVING_MAX_INFLIGHT",
@@ -274,7 +276,7 @@ def test_quota_set_over_wire_persists_across_restart(tmp_path):
     the KV, and malformed specs are rejected with a clean error."""
     db = str(tmp_path / "control.db")
     broker = Broker(datastore_path=db, hb_expiry_s=5.0).start()
-    st = _mkstore(1, 20_000)
+    st = mkstore(1, 20_000)
     agent = Agent("pem0", "127.0.0.1", broker.port, store=st,
                   heartbeat_s=0.5).start()
     client = Client("127.0.0.1", broker.port, timeout_s=30.0)
@@ -325,13 +327,13 @@ def _broker_with_seed(rows=20_000, **broker_kw):
     broker = Broker(hb_expiry_s=5.0, **broker_kw)
     broker.supervisor = AgentSupervisor(
         broker, ThreadLauncher("127.0.0.1", broker.port,
-                               store_factory=lambda _n: _mkstore(0, 0),
+                               store_factory=lambda _n: mkstore(0, 0),
                                heartbeat_s=0.5))
     # NOT started: tests drive tick() deterministically
     broker._server.start()
     broker._expiry_thread.start()
     seed = Agent("pem0", "127.0.0.1", broker.port,
-                 store=_mkstore(1, rows), heartbeat_s=0.5).start()
+                 store=mkstore(1, rows), heartbeat_s=0.5).start()
     return broker, seed
 
 
@@ -432,8 +434,8 @@ def test_retire_refuses_last_live_holder_without_replication():
     lose rows — the audit refuses the data-holding agent and its rows stay
     queryable."""
     broker = Broker(hb_expiry_s=5.0).start()
-    agents = {n: Agent(n, "127.0.0.1", broker.port, store=_mkstore(i + 1,
-                                                                   30_000),
+    agents = {n: Agent(n, "127.0.0.1", broker.port,
+                       store=mkstore(i + 1, 30_000),
                        heartbeat_s=0.5).start()
               for i, n in enumerate(["pem0", "pem1"])}
     client = Client("127.0.0.1", broker.port, timeout_s=30.0)
@@ -459,22 +461,18 @@ def test_retire_hands_off_to_synced_replica_without_row_loss():
     """With PL_REPLICATION=2 a data-holding agent retires through the
     PR 12 hand-off: its record stays, its shard serves from the replicated
     sealed batches via failover, and answers stay bit-equal."""
-    from pixie_tpu.services.chaos_bench import HARD_BATCH_ROWS
-
     _set(pl_replication=2, pl_rejoin_grace_s=0.2, pl_query_retries=4,
          pl_client_retries=4)
     broker = Broker(hb_expiry_s=5.0, query_timeout_s=30.0).start()
     agents = {}
     for i in range(3):
         n = f"pem{i}"
-        ts = _mkstore(i + 1, 0, batch_rows=HARD_BATCH_ROWS)
+        ts = mkstore(i + 1, 0, batch_rows=HARD_BATCH_ROWS)
         agents[n] = Agent(n, "127.0.0.1", broker.port, store=ts,
                           heartbeat_s=0.4).start()
-    from pixie_tpu.services.chaos_bench import _mkdata
-
     for i, n in enumerate(sorted(agents)):
         agents[n].store.table("http_events").write(
-            _mkdata(i + 1, HARD_BATCH_ROWS))
+            mkdata(i + 1, HARD_BATCH_ROWS))
     for a in agents.values():
         assert a.replication is not None
         assert a.replication.wait_synced(30.0)
@@ -654,7 +652,7 @@ def test_autoscale_off_no_quota_writes_bit_identical():
     model reads are enabled or not (it only reprices scheduling)."""
     broker = Broker(hb_expiry_s=5.0).start()
     assert broker.supervisor is None
-    st = _mkstore(1, 30_000)
+    st = mkstore(1, 30_000)
     agent = Agent("pem0", "127.0.0.1", broker.port, store=st,
                   heartbeat_s=0.5).start()
     client = Client("127.0.0.1", broker.port, timeout_s=30.0)

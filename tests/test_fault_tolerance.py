@@ -18,10 +18,10 @@ from pixie_tpu.plan.plan import Plan
 from pixie_tpu.services import faultinject, wire
 from pixie_tpu.services.agent import Agent
 from pixie_tpu.services.broker import Broker
-from pixie_tpu.services.chaos_bench import canonical_bytes
 from pixie_tpu.services.client import Client, QueryError
 from pixie_tpu.status import InvalidArgument
 from pixie_tpu.table import TableStore
+from pixie_tpu.testing.fixtures import canonical_bytes
 from pixie_tpu.types import DataType as DT, Relation
 
 AGG_SCRIPT = """

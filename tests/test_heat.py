@@ -21,9 +21,9 @@ from pixie_tpu import flags, metrics, observe
 from pixie_tpu.parallel.cluster import LocalCluster
 from pixie_tpu.services.agent import Agent
 from pixie_tpu.services.broker import Broker
-from pixie_tpu.services.chaos_bench import canonical_bytes
 from pixie_tpu.services.client import Client
 from pixie_tpu.table import TableStore, heat, journal
+from pixie_tpu.testing.fixtures import canonical_bytes
 from pixie_tpu.types import DataType as DT, Relation
 
 HEAT_FLAGS = ("PL_TRACING_ENABLED", "PL_HEAT_HALF_LIFE_S",

@@ -26,10 +26,10 @@ import pytest
 from pixie_tpu import flags, metrics
 from pixie_tpu.services.agent import Agent
 from pixie_tpu.services.broker import Broker
-from pixie_tpu.services.chaos_bench import canonical_bytes
 from pixie_tpu.services.client import Client
 from pixie_tpu.services.rebalance import RebalanceController
 from pixie_tpu.table import TableStore, journal, lifecycle
+from pixie_tpu.testing.fixtures import canonical_bytes
 from pixie_tpu.types import DataType as DT, Relation
 
 REL = Relation.of(

@@ -18,10 +18,10 @@ from pixie_tpu.parallel.cluster import LocalCluster
 from pixie_tpu.scripts import REPO_BUNDLE
 from pixie_tpu.services.agent import Agent
 from pixie_tpu.services.broker import Broker
-from pixie_tpu.services.chaos_bench import canonical_bytes
 from pixie_tpu.services.client import Client
 from pixie_tpu.serving import slo
 from pixie_tpu.table import TableStore
+from pixie_tpu.testing.fixtures import canonical_bytes
 from pixie_tpu.types import DataType as DT, Relation
 
 import pixie_tpu.engine.plancache  # noqa: F401 — defines PL_QUERY_FASTPATH

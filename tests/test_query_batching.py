@@ -27,9 +27,9 @@ from pixie_tpu.parallel.cluster import LocalCluster
 from pixie_tpu.serving import batching
 from pixie_tpu.services.agent import Agent
 from pixie_tpu.services.broker import Broker
-from pixie_tpu.services.chaos_bench import canonical_bytes
 from pixie_tpu.services.client import Client
 from pixie_tpu.table import TableStore
+from pixie_tpu.testing.fixtures import canonical_bytes
 from pixie_tpu.types import DataType as DT, Relation
 
 import pixie_tpu.matview  # noqa: F401 — defines PL_MATVIEW_ENABLED

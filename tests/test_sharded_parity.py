@@ -1,9 +1,9 @@
 """Sharded-by-design execution parity (ISSUE 7).
 
 Bit-equality of the sharded path against the single-device executor — not
-rtol closeness: the bench workload's aggregates are order-independent at
+rtol closeness: the fixture workload's aggregates are order-independent at
 the bit level (count/sum/mean over ints, min/max, integer-count p50
-sketch), so `shard_bench.assert_bitequal` is exact.  Covers uneven shard
+sketch), so `sharded_parity.assert_bitequal` is exact.  Covers uneven shard
 tails (row counts not divisible by the mesh width), dictionary-encoded
 keys (group keys and join keys), the sharded-resident tier's zero-H2D warm
 feeds + shard-local delta folds, per-shard transfer accounting, and the
@@ -15,8 +15,8 @@ import pytest
 from pixie_tpu import flags
 from pixie_tpu.engine import resident
 from pixie_tpu.engine.executor import PlanExecutor
-from pixie_tpu.parallel import shard_bench
 from pixie_tpu.parallel.spmd import collective_gate, make_mesh
+from pixie_tpu.testing import sharded_parity
 
 N_DEV = 8
 
@@ -35,7 +35,7 @@ def test_sharded_agg_bitequal_vs_single_device(rows):
     bit for bit — including the uneven tail (99_997 % 8 != 0 leaves a
     short final shard AND a hot unsealed remainder that merges through the
     host path)."""
-    out = shard_bench.run_local(rows, repeats=2, n_devices=N_DEV)
+    out = sharded_parity.run_local(rows, n_devices=N_DEV)
     assert out["bit_equal"] is True
     assert out["spmd_feeds"] >= 1
     assert out["shard_skew_frac"] >= 1.0
@@ -45,15 +45,15 @@ def test_sharded_agg_includes_dict_group_key():
     """The workload groups by a dictionary-encoded service column; decoded
     group values must round-trip identically through the sharded path
     (run_local compares decoded VALUES, not private codes)."""
-    ts = shard_bench.build_store(64_000)
-    plan = shard_bench.agg_plan()
+    ts = sharded_parity.build_store(64_000)
+    plan = sharded_parity.agg_plan()
     mesh = make_mesh(N_DEV)
     sharded = PlanExecutor(plan, ts, mesh=mesh,
                            force_backend="device").run()["output"]
     single = PlanExecutor(plan, ts, mesh=None,
                           force_backend="device").run()["output"]
     assert "service" in sharded.dictionaries
-    shard_bench.assert_bitequal(sharded, single)
+    sharded_parity.assert_bitequal(sharded, single)
 
 
 # ----------------------------------------------------- resident sharded tier
@@ -63,8 +63,8 @@ def test_sharded_resident_warm_zero_h2d_and_delta_fold():
     shard-local, and results stay bit-equal throughout."""
     batch = 8192
     rows = 3 * batch
-    ts = shard_bench.build_store(rows, batch_rows=batch)
-    plan = shard_bench.agg_plan()
+    ts = sharded_parity.build_store(rows, batch_rows=batch)
+    plan = sharded_parity.agg_plan()
     mesh = make_mesh(N_DEV)
 
     cold = PlanExecutor(plan, ts, mesh=mesh, force_backend="device")
@@ -81,8 +81,9 @@ def test_sharded_resident_warm_zero_h2d_and_delta_fold():
     # ingest delta: exactly one more sealed batch → the next feed folds
     # only the delta bytes (4+8+8+8+8 = 36 B/row), not the whole table
     t = ts.table("http_events")
-    services = np.array([f"svc-{i}" for i in range(shard_bench.N_SERVICES)])
-    cols = shard_bench.shard_cols(batch, 0, 1)
+    services = np.array(
+        [f"svc-{i}" for i in range(sharded_parity.N_SERVICES)])
+    cols = sharded_parity.shard_cols(batch, 0, 1)
     t.write({"time_": cols["time_"] + rows * 1000,
              "service": services[cols["service"]],
              "status": cols["status"], "bytes": cols["bytes"],
@@ -96,7 +97,7 @@ def test_sharded_resident_warm_zero_h2d_and_delta_fold():
     assert resident.stats["folds"] >= 1
     single = PlanExecutor(plan, ts, mesh=None,
                           force_backend="device").run()["output"]
-    shard_bench.assert_bitequal(fout, single)
+    sharded_parity.assert_bitequal(fout, single)
     assert wout.num_rows <= fout.num_rows  # sanity: delta visible
 
 
@@ -105,8 +106,8 @@ def test_sharded_and_single_device_entries_coexist():
     the mesh width) — a single-device query after a sharded one must not
     consume the sharded handle."""
     batch = 4096
-    ts = shard_bench.build_store(2 * batch, batch_rows=batch)
-    plan = shard_bench.agg_plan()
+    ts = sharded_parity.build_store(2 * batch, batch_rows=batch)
+    plan = sharded_parity.agg_plan()
     mesh = make_mesh(N_DEV)
     PlanExecutor(plan, ts, mesh=mesh, force_backend="device").run()
     PlanExecutor(plan, ts, mesh=None, force_backend="device").run()
@@ -117,7 +118,7 @@ def test_sharded_and_single_device_entries_coexist():
 
 # ------------------------------------------------------------ join parity
 def test_shuffled_join_bitequal_int_keys():
-    out = shard_bench.run_shuffled_join(60_000, n_devices=N_DEV)
+    out = sharded_parity.run_shuffled_join(60_000, n_devices=N_DEV)
     assert out["bit_equal"] is True
     assert out["n_parts"] == N_DEV
     assert out["all_to_all_exchanges"] >= 2
@@ -160,7 +161,7 @@ def test_shuffled_join_dict_keys_matches_single_device():
     agents = res.exec_stats["agents"]
     assert sum(s.get("mesh_shuffles", 0) for s in agents.values()) >= 2
     single = PlanExecutor(p, ts, mesh=None).run()["out"]
-    shard_bench.assert_bitequal(res, single, keys=("k", "lv", "rv"))
+    sharded_parity.assert_bitequal(res, single, keys=("k", "lv", "rv"))
 
 
 def test_planner_keeps_agent_count_without_explicit_mesh():
@@ -176,7 +177,7 @@ def test_planner_keeps_agent_count_without_explicit_mesh():
         t = ts.create(name, Relation.of(("k", DT.INT64), (col, DT.INT64)))
         t.write({"k": np.arange(100), col: np.arange(100)})
     cluster = LocalCluster({"pem0": ts})  # auto mesh, planner sees None
-    dp = cluster.planner.plan(shard_bench.join_plan())
+    dp = cluster.planner.plan(sharded_parity.join_plan())
     assert not dp.join_stages
 
 
@@ -265,21 +266,19 @@ def test_collective_serialize_gate_auto_and_forced():
         flags.set_for_testing("PX_SERIALIZE_CPU_COLLECTIVES", -1)
         collective_gate(mesh, refresh=True)
 
-    ts = shard_bench.build_store(4096, batch_rows=1024)
-    ex = PlanExecutor(shard_bench.agg_plan(), ts, mesh=make_mesh(N_DEV))
+    ts = sharded_parity.build_store(4096, batch_rows=1024)
+    ex = PlanExecutor(sharded_parity.agg_plan(), ts, mesh=make_mesh(N_DEV))
     rec = ex.stats["device"]["collective_gate"]
     assert rec["reason"] == "xla_cpu_shared_pool" and "_key" not in rec
 
 
-# --------------------------------------------------- promoted bench (slow)
-@pytest.mark.slow  # subprocess pod-scale harness: bench-lane only
-def test_sharded_agg_bench_harness_small():
-    """The promoted `sharded_agg_64m` harness end to end at a small size:
-    numbers + bit-equality come back whichever mode (2-process multihost
-    or single-host fallback) this jaxlib supports."""
-    out = shard_bench.run_subprocess(200_000, repeats=2)
+# ------------------------------------------------------- multihost (slow)
+@pytest.mark.slow  # two jax.distributed worker processes
+def test_sharded_agg_two_process_multihost_small():
+    """The 2-process `jax.distributed` run end to end at a small size: each
+    worker feeds its host-local shards, the collective merge spans both,
+    and process 0 finds the state bit-equal to the single-device kernel's."""
+    out = sharded_parity.run_subprocess(200_000)
     assert out["rows"] == 200_000
-    assert out["rows_per_sec"] > 0 and out["p50_ms"] > 0
-    assert out["n_devices"] == 8
-    assert out["mode"] in ("multihost", "local")
+    assert out["n_devices"] == 8 and out["processes"] == 2
     assert out.get("bit_equal") is True
