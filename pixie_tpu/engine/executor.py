@@ -1336,9 +1336,10 @@ class PlanExecutor:
         (`lut_select`/`lut_gather`: call this inside the chain's device
         context, where the program is traced)."""
         if rec is not None:
-            rec["span"] = {"engine": engine, **self._route_attrs(src)}
+            span = rec.setdefault("span", {})  # _feed may have come first
+            span.update(engine=engine, **self._route_attrs(src))
             if kern is not None:
-                rec["span"].update(kern.lut_forms())
+                span.update(kern.lut_forms())
         dev = self.stats.setdefault("device", {})
         engines = dev.setdefault("engines", {})
         engines[engine] = engines.get(engine, 0) + 1
@@ -1561,30 +1562,36 @@ class PlanExecutor:
 
     def _feed(self, src, names, cap, spmd: bool = False,
               backend: str = "device"):
-        """`_feed_batches`, and under an active trace one `feed` span a
-        chain: from the first batch asked for to the last feed yielded,
-        with how many feeds there were, how many of them the resident tier
-        served and the bytes that crossed host->device."""
+        """`_feed_batches`, with each feed's bucketed row count added to
+        `feed_rows` on the span of the `_timed` frame that consumes it (the
+        chain's: beside `rows` it says what share of the bucket is live,
+        which is the share of chunks the kernels' loops visit), and under
+        an active trace one `feed` span a chain: from the first batch asked
+        for to the last feed yielded, with how many feeds there were, how
+        many of them the resident tier served and the bytes that crossed
+        host->device."""
         from pixie_tpu import trace
 
-        inner = self._feed_batches(src, names, cap, spmd, backend)
-        if trace.current() is None:
-            yield from inner
-            return
+        span = (self._stat_stack[-1].setdefault("span", {})
+                if self._stat_stack else {})
+        traced = trace.current() is not None
         t0 = t_last = _time.time_ns()
         feeds = 0
         resident0 = self.stats.get("resident_feeds", 0)
         h2d0 = self.stats.get("h2d_bytes", 0)
         try:
-            for item in inner:
+            for item in self._feed_batches(src, names, cap, spmd, backend):
                 feeds += 1
+                span["feed_rows"] = span.get("feed_rows", 0) + _first_len(
+                    item[0])
                 t_last = _time.time_ns()
                 yield item
         finally:
-            trace.event_span(
-                "feed", t0, t_last - t0, feeds=feeds,
-                resident=self.stats.get("resident_feeds", 0) - resident0,
-                h2d_bytes=self.stats.get("h2d_bytes", 0) - h2d0)
+            if traced:
+                trace.event_span(
+                    "feed", t0, t_last - t0, feeds=feeds,
+                    resident=self.stats.get("resident_feeds", 0) - resident0,
+                    h2d_bytes=self.stats.get("h2d_bytes", 0) - h2d0)
 
     def _feed_batches(self, src, names, cap, spmd: bool = False,
                       backend: str = "device"):
@@ -2213,9 +2220,11 @@ class PlanExecutor:
             # accumulators live on the dispatch device (CPU for small batches)
             state = {name: uda.init(Gb, in_dt)
                      for name, uda, in_dt in init_pairs}
+            fed = 0
             for off in range(0, n, SORT_AGG_CHUNK):
                 end = min(off + SORT_AGG_CHUNK, n)
                 bucket = max(next_pow2(end - off), MIN_BUCKET)
+                fed += bucket
                 gid_c = _pad(gid_np[off:end], bucket)
                 mask_c = np.zeros(bucket, dtype=bool)
                 mask_c[: end - off] = valid[off:end]
@@ -2223,6 +2232,7 @@ class PlanExecutor:
                 state = upd(state, gid_c, mask_c, vals_c)
                 if self.analyze:
                     jax.block_until_ready(state)
+            rec["span"]["feed_rows"] = fed
             state_np = transfer.pull(state)
         return (group_cols, out_dtypes, out_dicts, udas, in_types, state_np, G,
                 val_dicts)

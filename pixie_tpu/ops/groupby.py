@@ -96,7 +96,7 @@ def _use_matmul(n: int, num_groups: int) -> bool:
 
 
 def _chunked_onehot_sum(v32: jax.Array, gid: jax.Array, num_groups: int,
-                        precision=None) -> jax.Array:
+                        mask: jax.Array, precision=None) -> jax.Array:
     """sum per group of float32 contributions via MXU: for each chunk,
     v[1,CH] @ one_hot[CH,G], accumulated across chunks in float64.
 
@@ -106,20 +106,41 @@ def _chunked_onehot_sum(v32: jax.Array, gid: jax.Array, num_groups: int,
     f32 accumulation exact for bounded-magnitude contributions.
     """
     return _chunked_onehot_multi_sum(
-        lambda vv: vv[None, :], v32, gid, num_groups, precision)[0]
+        lambda vv: vv[None, :], v32, gid, num_groups, mask, precision)[0]
 
 
-def scan_sum(fn, xs):
-    """sum over i of fn(xs[i]) along the leading axis, in order, as one
-    `lax.scan`.  The carry starts at fn(xs[0]) instead of a fresh zeros
-    array: under `jax.shard_map` a scan's carry must have the same
-    varying-axes type going in as coming out, and zeros made inside the body
-    are replicated while fn's output varies over the mesh axis.  (0 + x is x,
-    so the sum is bit-identical to the zero-seeded one.)"""
-    first = jax.tree.map(lambda a: a[0], xs)
-    rest = jax.tree.map(lambda a: a[1:], xs)
-    out, _ = jax.lax.scan(lambda acc, x: (acc + fn(x), None), fn(first), rest)
-    return out
+def live_chunks(mask: jax.Array, ch: int) -> tuple[jax.Array, jax.Array]:
+    """(lo, hi): the chunks [lo, hi) of `ch` rows that hold every live row
+    of `mask`, from the first chunk with one to the last, on the device.  An
+    all-masked input gives one chunk (the last), so a loop over the range
+    still runs once and adds zeros."""
+    live = mask.reshape(-1, ch).any(axis=1)
+    c = live.shape[0]
+    at = jnp.arange(c, dtype=jnp.int32)
+    lo = jnp.min(jnp.where(live, at, c - 1))
+    hi = jnp.max(jnp.where(live, at + 1, lo + 1))
+    return lo, hi
+
+
+def scan_sum(fn, xs, lo, hi):
+    """sum over i in [lo, hi) of fn(xs[i]) along the leading axis, in
+    order, as one `lax.fori_loop` whose bounds are traced: the callers pad
+    a feed to a pow2 bucket and mask the padding, so they pass the chunk
+    range that holds a live row (`live_chunks`) and the loop visits no
+    other.  A chunk outside it holds masked rows only: fn of it is exact
+    zeros (+0.0 to a float carry, 0 to a count), so leaving it out is
+    bit-identical to the loop over every chunk.  The carry starts at
+    fn(xs[lo]) instead of a fresh zeros array: under `jax.shard_map` a
+    loop's carry must have the same varying-axes type going in as coming
+    out, and zeros made inside the body are replicated while fn's output
+    varies over the mesh axis (as lo and hi do: each shard walks its own
+    range).  (0 + x is x, so the sum is bit-identical to the zero-seeded
+    one.)"""
+    def at(i):
+        return fn(jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False), xs))
+
+    return jax.lax.fori_loop(lo + 1, hi, lambda i, acc: acc + at(i), at(lo))
 
 
 #: Float lanes accumulate on the MXU in runs of this many rows (see
@@ -149,9 +170,10 @@ def _lanes_gemm(lanes: jax.Array, oh: jax.Array, precision) -> jax.Array:
     return runs.astype(jnp.float64).sum(axis=0)
 
 
-def _chunked_onehot_multi_sum(lanes_fn, v, gid: jax.Array,
-                              num_groups: int, precision=None) -> jax.Array:
-    """[L, G] f64 per-group sums where lanes_fn(chunk) -> [L, CH] f32 lanes.
+def _chunked_onehot_multi_sum(lanes_fn, v, gid: jax.Array, num_groups: int,
+                              mask: jax.Array, precision=None) -> jax.Array:
+    """[L, G] f64 per-group sums where lanes_fn(chunk) -> [L, CH] f32 lanes
+    and `v` is zero wherever `mask` is False.
 
     The one-hot is the expensive part (CH x G f32 written/read from HBM per
     chunk); stacking all L lanes into ONE [L,CH] @ [CH,G] GEMM builds it
@@ -159,6 +181,12 @@ def _chunked_onehot_multi_sum(lanes_fn, v, gid: jax.Array,
     HBM-bound on exactly this (8 one-hot rebuilds per column per chunk).
     `precision`: None for bf16-exact lanes, Precision.HIGHEST for float
     lanes (_lanes_gemm).
+
+    The loop visits the chunks from the first that holds a live row of
+    `mask` to the last (scan_sum, live_chunks), not the whole bucket: a
+    chunk of masked rows has all-zero lanes, its GEMM is exact zeros in
+    every lane, and the limb and run exactness arguments are per chunk, so
+    the sums are the all-chunks loop's bit for bit.
     """
     n = v.shape[0]
     ch = min(n, CHUNK_ROWS)
@@ -169,7 +197,8 @@ def _chunked_onehot_multi_sum(lanes_fn, v, gid: jax.Array,
         oh = jax.nn.one_hot(gg, num_groups, dtype=jnp.float32)
         return _lanes_gemm(lanes_fn(vv), oh, precision)
 
-    return scan_sum(chunk, (v.reshape(c, ch), gid.reshape(c, ch)))
+    return scan_sum(chunk, (v.reshape(c, ch), gid.reshape(c, ch)),
+                    *live_chunks(mask, ch))
 
 
 @jax.named_scope("px.groupby_sum")
@@ -180,7 +209,8 @@ def masked_segment_sum(values: jax.Array, gid: jax.Array, num_groups: int, mask:
     gid = gid.astype(jnp.int32)
     d = jnp.dtype(v.dtype)
     if d == jnp.bool_:
-        return _chunked_onehot_sum(v.astype(jnp.float32), gid, num_groups).astype(jnp.int64)
+        return _chunked_onehot_sum(
+            v.astype(jnp.float32), gid, num_groups, mask).astype(jnp.int64)
     if d in (jnp.dtype(jnp.int64), jnp.dtype(jnp.uint64), jnp.dtype(jnp.int32)):
         # EXACT 64-bit sums on the MXU: split the two's-complement bit pattern
         # into 8-bit limbs; each limb's chunk sum ≤ 2^24 is exact in f32, the
@@ -195,7 +225,7 @@ def masked_segment_sum(values: jax.Array, gid: jax.Array, num_groups: int, mask:
             return ((uu[None, :] >> shifts[:, None])
                     & jnp.uint64(0xFF)).astype(jnp.float32)
 
-        s = _chunked_onehot_multi_sum(limbs, u, gid, num_groups)  # [8, G]
+        s = _chunked_onehot_multi_sum(limbs, u, gid, num_groups, mask)  # [8, G]
         with jax.named_scope("px.int_limbs"):
             total = jnp.zeros((num_groups,), dtype=jnp.uint64)
             for k in range(8):
@@ -209,12 +239,12 @@ def masked_segment_sum(values: jax.Array, gid: jax.Array, num_groups: int, mask:
             lo = (vv - hi.astype(jnp.float64)).astype(jnp.float32)
             return jnp.stack([hi, lo])
 
-        s = _chunked_onehot_multi_sum(hilo, v, gid, num_groups,
+        s = _chunked_onehot_multi_sum(hilo, v, gid, num_groups, mask,
                                       precision=jax.lax.Precision.HIGHEST)
         return s[0] + s[1]
     if d in (jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16)):
         return _chunked_onehot_sum(
-            v.astype(jnp.float32), gid, num_groups,
+            v.astype(jnp.float32), gid, num_groups, mask,
             precision=jax.lax.Precision.HIGHEST).astype(d)
     return jax.ops.segment_sum(v, gid, num_segments=num_groups)
 
@@ -225,7 +255,8 @@ def masked_segment_count(gid: jax.Array, num_groups: int, mask: jax.Array) -> ja
     (per-chunk counts ≤ CHUNK_ROWS are exact in f32), scatter elsewhere."""
     n = gid.shape[0]
     if _use_matmul(n, num_groups):
-        c = _chunked_onehot_sum(mask.astype(jnp.float32), gid.astype(jnp.int32), num_groups)
+        c = _chunked_onehot_sum(mask.astype(jnp.float32),
+                                gid.astype(jnp.int32), num_groups, mask)
         return c.astype(jnp.int64)
     ones = jnp.where(mask, 1, 0).astype(jnp.int64)
     return jax.ops.segment_sum(ones, gid, num_segments=num_groups)

@@ -181,7 +181,13 @@ class LogHistogram:
         lane is one-hot, the digit is the VALUE (1 or DIGIT) — one narrow
         [G,CH]@[CH,LANES] MXU GEMM per chunk, then an exact divmod unpack
         into the histogram halves.  Half the MXU FLOPs of the full-width
-        one-hot (LANES = width/2) at bit-equal counts."""
+        one-hot (LANES = width/2) at bit-equal counts.
+
+        The chunk loop visits the chunks from the first that holds a live
+        row of `mask` to the last (groupby.scan_sum, live_chunks), not the
+        whole pow2 bucket: a chunk of masked rows has an all-zero group
+        one-hot, so its packed GEMM and both unpacked digits are exact
+        zeros and the histogram is the all-chunks loop's bit for bit."""
         n = gid.shape[0]
         ch = min(n, self.CHUNK)
         if n % ch:
@@ -217,12 +223,13 @@ class LogHistogram:
             c_lo = packed - c_hi * digit
             return jnp.concatenate([c_lo, c_hi], axis=1)[:, :self.width]
 
-        from pixie_tpu.ops.groupby import scan_sum
+        from pixie_tpu.ops.groupby import live_chunks, scan_sum
 
         mb = jnp.where(mask, 1.0, 0.0).astype(jnp.bfloat16)
         return hist + scan_sum(
             lambda xs: gemm(*xs).astype(hist.dtype),
-            (g32.reshape(c, ch), bins.reshape(c, ch), mb.reshape(c, ch)))
+            (g32.reshape(c, ch), bins.reshape(c, ch), mb.reshape(c, ch)),
+            *live_chunks(mask, ch))
 
     def init(self, num_groups: int, dtype=jnp.float32) -> jax.Array:
         return jnp.zeros((num_groups, self.width), dtype=dtype)

@@ -11,6 +11,8 @@ import sys
 import numpy as np
 import pytest
 
+from pixie_tpu.testing.live_chunks import LIVE_RANGES
+
 REPO = pathlib.Path(__file__).resolve().parent.parent
 
 
@@ -317,6 +319,152 @@ def test_tpu_groupby_formulations_trace_and_agree(monkeypatch):
             np.testing.assert_array_equal(np.asarray(got), want[k])
         else:
             np.testing.assert_allclose(np.asarray(got), want[k], rtol=1e-6)
+
+
+def _count(v, g, m, groups):
+    from pixie_tpu.ops import groupby as gb
+
+    return gb.masked_segment_count(g, groups, m)
+
+
+def _sum(v, g, m, groups):
+    from pixie_tpu.ops import groupby as gb
+
+    return gb.masked_segment_sum(v, g, groups, m)
+
+
+#: the chunk-loop kernels of ops/groupby.py: (values from a seed, kernel)
+LOOP_KERNELS = {
+    "count": (lambda rng, n: np.zeros(n), _count),
+    "i64": (lambda rng, n: rng.integers(-(1 << 40), 1 << 40, n), _sum),
+    "f64": (lambda rng, n: rng.exponential(50.0, n), _sum),
+    "f32": (lambda rng, n: rng.exponential(50.0, n).astype(np.float32), _sum),
+    "bool": (lambda rng, n: rng.random(n) < 0.3, _sum),
+}
+
+
+def test_live_chunks_is_the_range_of_chunks_with_a_live_row():
+    """`live_chunks` gives the chunks from the first with a live row to the
+    last, one chunk for an all-masked feed; `scan_sum` sums that range and
+    nothing outside it."""
+    import jax
+    import jax.numpy as jnp
+
+    from pixie_tpu.ops import groupby as gb
+    from pixie_tpu.testing.live_chunks import live_mask
+
+    c, ch = 8, 64
+    for name, rows in LIVE_RANGES.items():
+        a, b = rows(c, ch)
+        lo, hi = map(int, jax.jit(lambda m: gb.live_chunks(m, ch))(
+            live_mask(name, c, ch)))
+        if a == b:
+            assert hi - lo == 1, name
+        else:
+            assert (lo, hi) == (a // ch, -(-b // ch)), name
+    xs = jnp.arange(c * ch, dtype=jnp.int64).reshape(c, ch)
+    got = jax.jit(lambda x, lo, hi: gb.scan_sum(jnp.sum, x, lo, hi))(xs, 2, 5)
+    assert int(got) == int(xs[2:5].sum())
+
+
+@pytest.mark.parametrize("kernel", list(LOOP_KERNELS))
+@pytest.mark.parametrize("live", list(LIVE_RANGES))
+def test_chunk_loop_over_live_chunks_is_the_loop_over_all(
+        monkeypatch, kernel, live):
+    """The one-hot GEMM group-by visits the chunks that hold a live row and
+    no other.  Wherever the live rows sit in the pow2 bucket, its answer is
+    the answer of the loop over every chunk bit for bit, and the scatter
+    formulation's (exactly for counts and integers, to F64_SUM_RTOL for
+    float sums)."""
+    import jax
+
+    from pixie_tpu.ops import groupby as gb
+    from pixie_tpu.testing.live_chunks import live_mask, scan_every_chunk
+
+    c, groups = 8, 48
+    n = c * gb.CHUNK_ROWS
+    rng = np.random.default_rng(7)
+    gid = rng.integers(0, groups, n).astype(np.int32)
+    values, kern = LOOP_KERNELS[kernel]
+    v = values(rng, n)
+
+    def fn(v, g, m):
+        return kern(v, g, m, groups)
+
+    mask = live_mask(live, c, gb.CHUNK_ROWS)
+    # (the scatter takes no bool: the planner hands it the widened column)
+    want = np.asarray(fn(v.astype(np.int64) if kernel == "bool" else v,
+                         gid, mask))
+    monkeypatch.setattr(gb, "dispatch_backend", lambda: "tpu")
+    assert gb._use_matmul(n, groups)
+    got = np.asarray(jax.jit(fn)(v, gid, mask))
+    monkeypatch.setattr(gb, "scan_sum", scan_every_chunk)
+    # (a new callable: jit would hand back fn's program, traced before)
+    every = np.asarray(jax.jit(lambda *a: fn(*a))(v, gid, mask))
+    assert got.dtype == every.dtype == want.dtype
+    assert got.tobytes() == every.tobytes()
+    if kernel in ("f64", "f32"):
+        exact = np.zeros(groups)
+        np.add.at(exact, gid[mask], v[mask].astype(np.float64))
+        np.testing.assert_allclose(got, exact, rtol=gb.F64_SUM_RTOL)
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-2)
+    else:
+        np.testing.assert_array_equal(got, want)
+
+
+def test_chunk_loops_walk_each_shards_own_range_under_shard_map(monkeypatch):
+    """Under `jax.shard_map` every shard derives its own live range: the
+    loop's bounds vary over the mesh axis as its carry does.  Eight shards,
+    one live range each, every chunk-loop kernel: each shard's answer is
+    the single-device scatter formulation's of its rows."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    from pixie_tpu.ops import groupby as gb
+    from pixie_tpu.ops.sketch import LogHistogram
+    from pixie_tpu.parallel.spmd import make_mesh, shard_map
+    from pixie_tpu.testing.live_chunks import live_mask
+
+    lh = LogHistogram()
+    c, groups = 8, 16
+    ch = lh.CHUNK
+    per = c * ch
+    names = list(LIVE_RANGES)
+    mesh = make_mesh(len(names))
+    rng = np.random.default_rng(11)
+    gid = rng.integers(0, groups, (len(names), per)).astype(np.int32)
+    mask = np.stack([live_mask(name, c, ch, seed=i)
+                     for i, name in enumerate(names)])
+    kernels = dict(LOOP_KERNELS, sketch=(
+        lambda rng, n: rng.exponential(50.0, n),
+        lambda v, g, m, groups: lh._update_gemm(
+            lh.init(groups), g, lh.bin_index(v), m, groups)))
+    scatter = dict(kernels, sketch=(
+        None, lambda v, g, m, groups: lh._update_segment(
+            lh.init(groups), g, lh.bin_index(v), m, groups)))
+    vals = {k: values(rng, len(names) * per).reshape(len(names), per)
+            for k, (values, _fn) in kernels.items()}
+    want = {k: [np.asarray(fn(
+        vals[k][i].astype(np.int64) if k == "bool" else vals[k][i],
+        gid[i], mask[i], groups)) for i in range(len(names))]
+        for k, (_values, fn) in scatter.items()}
+    monkeypatch.setattr(gb, "dispatch_backend", lambda: "tpu")
+    monkeypatch.setattr(gb, "CHUNK_ROWS", ch)
+    assert gb._use_matmul(per, groups)
+    for k, (_values, fn) in kernels.items():
+        f = jax.jit(shard_map(
+            lambda v, g, m: fn(v[0], g[0], m[0], groups)[None], mesh=mesh,
+            in_specs=(P("agents"),) * 3, out_specs=P("agents")))
+        got = np.asarray(f(jnp.asarray(vals[k]), gid, mask))
+        for i, name in enumerate(names):
+            if k in ("f64", "f32"):
+                np.testing.assert_allclose(
+                    got[i], want[k][i], rtol=1e-4, atol=1e-2,
+                    err_msg=f"{k} {name}")
+            else:
+                np.testing.assert_array_equal(
+                    got[i], want[k][i], err_msg=f"{k} {name}")
 
 
 def test_tpu_formulations_trace_under_shard_map(monkeypatch):

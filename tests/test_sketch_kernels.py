@@ -17,6 +17,7 @@ import jax.numpy as jnp
 
 from pixie_tpu import flags
 from pixie_tpu.ops.sketch import LogHistogram, _sort_min_groups
+from pixie_tpu.testing.live_chunks import LIVE_RANGES, live_mask, scan_every_chunk
 
 
 @pytest.fixture(scope="module")
@@ -108,6 +109,32 @@ class TestBitEquality:
         want = np.asarray(
             lh._update_segment(lh.init(G), gid, lh.bin_index(vals), mask, G))
         np.testing.assert_array_equal(want, got)
+
+
+@pytest.mark.parametrize("live", list(LIVE_RANGES))
+def test_gemm_over_live_chunks_is_the_gemm_over_all(lh, monkeypatch, live):
+    """The GEMM's chunk loop visits the chunks that hold a live row and no
+    other: wherever the live rows sit in the pow2 bucket its histogram is
+    the scatter's, and the loop over every chunk's, bit for bit."""
+    from pixie_tpu.ops import groupby as gb
+
+    c, G = 8, 16
+    n = c * lh.CHUNK
+    rng = np.random.default_rng(6)
+    gid = rng.integers(0, G, n).astype(np.int32)
+    bins = lh.bin_index(jnp.asarray(rng.exponential(50.0, n)))
+    mask = live_mask(live, c, lh.CHUNK)
+
+    def gemm(g, b, m):
+        return lh._update_gemm(lh.init(G), g, b, m, G)
+
+    want = np.asarray(lh._update_segment(lh.init(G), gid, bins, mask, G))
+    got = np.asarray(jax.jit(gemm)(gid, bins, mask))
+    monkeypatch.setattr(gb, "scan_sum", scan_every_chunk)
+    # (a new callable: jit would hand back gemm's program, traced before)
+    every = np.asarray(jax.jit(lambda *a: gemm(*a))(gid, bins, mask))
+    assert got.tobytes() == every.tobytes() == want.tobytes()
+    assert got.sum() == mask.sum()
 
 
 class TestDigitPacking:

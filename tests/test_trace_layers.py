@@ -182,6 +182,23 @@ def test_forced_explore_is_a_chain_span(served):
     assert probe["decision_n"] == autotune.COLD_PROBE_PERIOD
 
 
+def test_chain_span_says_the_bucket_its_kernels_were_handed(served):
+    """Beside `rows` a chain span carries `feed_rows`, the pow2-bucketed
+    row count of the feeds its kernels were handed, on either arm: `rows /
+    feed_rows` is the share of chunks the device kernels' loops visit.  The
+    store's 3,000 rows are five sealed batches of 512, one feed in a
+    4,096-row bucket, and a hot remainder of 440 in the smallest bucket."""
+    client, _store = served
+    for _ in range(autotune.COLD_PROBE_PERIOD):
+        client.execute_script(QUERY.format(floor=5))
+    chains = _chains(_agent_spans())
+    assert [c.attributes["engine"] for c in chains] == [
+        "xla_cpu_chain"] * 3 + ["device_chain"]
+    for c in chains:
+        assert c.attributes["rows"] == 3000
+        assert c.attributes["feed_rows"] == 4096 + 1024
+
+
 JOIN_QUERY = """
 import px
 a = px.DataFrame(table='http_events')
