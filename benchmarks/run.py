@@ -152,7 +152,9 @@ def run_cell(bench, cell, cfg_entry, seed, seconds, traced, out_dir, phases,
 
     broker = Broker(hb_expiry_s=120.0, query_timeout_s=300.0).start()
     agent = Agent(AGENT, "127.0.0.1", broker.port, store=store,
-                  heartbeat_s=2.0).start()
+                  heartbeat_s=2.0,
+                  n_devices=len(devices) if len(devices) > 1 else None
+                  ).start()
     client = Client("127.0.0.1", broker.port, timeout_s=300.0)
     host_spans: list = []
     tracer = None
@@ -208,7 +210,7 @@ def run_cell(bench, cell, cfg_entry, seed, seconds, traced, out_dir, phases,
     metrics = {}
     breakdown = None
     if traced:
-        run["trace"] = tracer.reduce(host_spans, recs)
+        run["trace"] = tracer.reduce(host_spans, recs, len(devices))
         device["busy_s"] = run["trace"]["busy_s"]
         device["window_s"] = run["trace"]["window_s"]
         breakdown = {"device_ops": run["trace"]["device_ops"],
@@ -351,7 +353,16 @@ class TailTrace:
         if self.started:
             self.jax.profiler.stop_trace()
 
-    def reduce(self, host_spans: list, recs: list) -> dict:
+    def reduce(self, host_spans: list, recs: list, n_devices: int) -> dict:
+        """The trace's reduction over the traced span, for a cell of
+        `n_devices`.  A device that ran nothing while the profiler was on
+        has no plane in the trace: a window the program served off the chip
+        reads busy 0 and is a reading like any other.  Only where a query
+        that began and ended inside the span says that it ran a chain on
+        the device (`recs` carry their digests) is a trace that shows no
+        device operation there a broken one, whether it lacks the plane or
+        holds one without an operation.  (A query that only reaches into
+        the span may have had its device work outside it.)"""
         import tracered
 
         if not self.started:
@@ -368,7 +379,18 @@ class TailTrace:
         spans += [(r["t0_unix_ns"] + shift,
                    r["t0_unix_ns"] + shift + int(r["wall_ms"] * 1e6),
                    "client.execute_script") for r in recs]
-        out = tracered.reduce_trace(planes, lo, hi, spans)
+        out = tracered.reduce_trace(planes, lo, hi, spans, n_devices)
+        routed = sum(
+            1 for r in recs
+            if r.get("digest") and r["digest"]["engine"] == "device"
+            and r["t0_unix_ns"] >= self.lo_unix_ns
+            and r["t0_unix_ns"] + r["wall_ms"] * 1e6 <= self.hi_unix_ns)
+        if routed and out["busy_s"] == 0:
+            raise RuntimeError(
+                f"the trace shows no operation on a device in the traced "
+                f"span (device planes: {sorted(planes['devices'])}), yet "
+                f"{routed} queries inside it ran a chain on the device: the "
+                "trace is broken, not a window without device work")
         out["clock_shift_ns"] = shift
         out["lo_unix_ns"], out["hi_unix_ns"] = self.lo_unix_ns, self.hi_unix_ns
         out["layout"] = planes["layout"]

@@ -3,6 +3,7 @@
 
     python benchmarks/selfcheck.py            # files and the recorded trace
     JAX_PLATFORMS=cpu python benchmarks/selfcheck.py --rehearse <workload>
+        [--trace 1] [--chips N]
 
 The first loads every JSON under configs/, traffic/, scripts/ and
 BENCHMARK.json, holds names and units to the allowed characters and lengths,
@@ -25,8 +26,11 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
-#: the plane and line a host trace has in the device's place (rehearsals only)
-HOST_PLANE = ("/host:CPU", "python")
+#: the plane a host trace has in the device's place (rehearsals only); the
+#: lines read there are this process's Python threads, which the profiler
+#: names as the kernel does (/proc/self/comm): their JAX calls stand in for
+#: the device's operations
+HOST_PLANE = "/host:CPU"
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 
 
@@ -148,10 +152,30 @@ def check_trace() -> list:
                                       ["total:a.exec", 20e-9]]
             or r["device_ops"][0] != ["while.1", 30e-9]):
         errs.append(f"hand-made trace reduced to {r}")
+    # a span in which the device ran nothing is a reading, not an error:
+    # no plane at all, or a plane without an operation.  Busy 0, no ops,
+    # and the span as one gap: named by the stretch its middle lies in,
+    # its totals split over the host's spans
+    hosts = [(0, 100, "a.query"), (38, 62, "a.exec")]
+    idle = {"busy_s": 0.0, "window_s": 100e-9, "device_ops": [],
+            "idle_gaps": [["a.exec", 100e-9], ["total:a.query", 76e-9],
+                          ["total:a.exec", 24e-9]]}
+    for devices in ({}, {"/device:TPU:0": []}):
+        r = tracered.reduce_trace({"devices": devices, "marks": []}, 0, 100,
+                                  hosts)
+        if r != idle:
+            errs.append(f"device-less trace {devices} reduced to {r}")
+    # a cell of four chips with planes for two: the others were idle and
+    # count 0 in the mean
+    hand["devices"]["/device:TPU:2"] = [(0, 20, "fusion.2")]
+    r = tracered.reduce_trace(hand, 0, 100, hosts, n_devices=4)
+    if abs(r["busy_s"] - (40e-9 + 20e-9) / 4) > 1e-15:
+        errs.append(f"four devices, two planes: busy_s {r['busy_s']!r}")
     return errs
 
 
-def rehearse(workload: str, rows: int, seconds: float, traced: bool) -> int:
+def rehearse(workload: str, rows: int, seconds: float, traced: bool,
+             chips: int = 1) -> int:
     sys.path.insert(0, HERE)
     import run
     import tracered
@@ -164,12 +188,19 @@ def rehearse(workload: str, rows: int, seconds: float, traced: bool) -> int:
     config["rows"] = rows
     for t in config["tables"]:
         t.pop("max_bytes", None)
-    dev = jax.devices()[0]
+    devices = jax.devices()[:chips]
+    if len(devices) < chips:
+        raise SystemExit(f"selfcheck: --chips {chips}, and JAX has "
+                         f"{len(devices)} device(s) (on the CPU: XLA_FLAGS="
+                         "--xla_force_host_platform_device_count=8)")
+    dev = devices[0]
     if dev.platform != "tpu":  # a host trace has no device plane to reduce
-        tracered.DEVICE_PREFIX, tracered.OPS_LINE = HOST_PLANE
+        tracered.DEVICE_PREFIX = HOST_PLANE
+        with open("/proc/self/comm") as f:
+            tracered.OPS_LINE = f.read().strip()
     result = run.run_cell(
         bench, cell, cfg, 2147483659, seconds, traced,
-        os.path.join(HERE, "out", "rehearsal"), run.Phases(), jax, [dev],
+        os.path.join(HERE, "out", "rehearsal"), run.Phases(), jax, devices,
         config=config,
         peaks={dev.device_kind: {"hbm_bytes_per_s": 1e11}})
     print(f"REHEARSAL on platform={dev.platform} at rows={rows}: not a "
@@ -184,10 +215,13 @@ def main() -> int:
     ap.add_argument("--rows", type=int, default=200_000)
     ap.add_argument("--seconds", type=float, default=3.0)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--chips", type=int, default=1,
+                    help="rehearse on the first N devices, whatever the "
+                         "cell's `chips` (the Agent gets n_devices=N)")
     args = ap.parse_args()
     if args.rehearse:
         return rehearse(args.rehearse, args.rows, args.seconds,
-                        bool(args.trace))
+                        bool(args.trace), args.chips)
     errs = check_files() + check_trace()
     for e in errs:
         print("selfcheck:", e, file=sys.stderr)

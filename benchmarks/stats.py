@@ -10,7 +10,8 @@ import math
 def digest(stats: dict) -> dict:
     """One answer's exec_stats, flat.  `engine` is "device" where a chain
     ran on the accelerator route, else "cpu"; `arm`/`source` are the
-    router's decision for the largest input (engine/autotune.py)."""
+    router's decision for the largest input, `plan_class` and
+    `size_bucket` the model key it was taken under (engine/autotune.py)."""
     agents = stats.get("agents") or {}
     agent = next(iter(agents.values()), {}) if agents else {}
     mv = agent.get("matview") or {}
@@ -30,10 +31,11 @@ def digest(stats: dict) -> dict:
         "device_kind": dev.get("device_kind"),
         "arm": big["arm"] if big else None,
         "source": big["source"] if big else None,
+        "plan_class": big.get("plan_class") if big else None,
         "size_bucket": big["size_bucket"] if big else None,
         "decision_n": big.get("n") if big else None,
         "decisions": [[d.get("arm"), d.get("source"), d.get("size_bucket"),
-                       d.get("n")] for d in decisions],
+                       d.get("n"), d.get("plan_class")] for d in decisions],
         "rows_scanned": int(agent.get("rows_scanned", 0)),
         "matview_hit": bool(mv.get("hit")),
         "plan_cache_hit": bool((stats.get("fastpath") or {})
@@ -58,19 +60,22 @@ def mark_probes(recs: list) -> None:
     duplicate, which takes the router's next decision and answers first;
     the explore's decision is discarded with the loser's stats, and the
     router's counter shows it only as a step of two from the query before
-    in the same size bucket (`hedged` in the digest counts the duplicate)."""
+    under the same model key, (`plan_class`, `size_bucket`): the router
+    counts each key's decisions apart (`hedged` in the digest counts the
+    duplicate)."""
     last: dict = {}
     for r in recs:
         d = r.get("digest")
         if not d:
             continue
         probe = d["source"] == "explore"
-        mine = [x for x in d["decisions"] if x[2] == d["size_bucket"]]
+        key = (d.get("plan_class"), d["size_bucket"])
+        mine = [x for x in d["decisions"] if (x[4], x[2]) == key]
         if mine and mine[0][3] is not None:
-            prev = last.get(d["size_bucket"])
+            prev = last.get(key)
             if prev is not None and mine[0][3] - prev >= 2:
                 probe = True
-            last[d["size_bucket"]] = mine[-1][3]
+            last[key] = mine[-1][3]
         d["probe"] = probe
 
 

@@ -84,13 +84,18 @@ def clip(intervals: list, lo: int, hi: int) -> list:
 
 
 def reduce_trace(planes: dict, lo_ns: int, hi_ns: int,
-                 host_spans: list | None = None) -> dict:
+                 host_spans: list | None = None, n_devices: int = 1) -> dict:
     """Busy seconds (mean over devices), top ops and idle gaps inside
     [lo_ns, hi_ns) on the trace's clock.  `host_spans` are (start_ns,
     end_ns, name) on the same clock; a gap takes the name of the shortest
-    span that covers its middle."""
-    if not planes["devices"]:
-        raise ValueError("the trace holds no device plane")
+    span that covers its middle.
+
+    The profiler writes a device's plane only if an operation ran on it
+    while it traced.  Of the cell's `n_devices`, one without a plane was
+    idle for the whole span and counts 0 in the mean; with no plane at all
+    busy is 0, `device_ops` is empty and the span is one gap.  Whether that
+    is a window served off the chip or a broken trace, the caller knows
+    (run.TailTrace.reduce): this function only reads what is there."""
     busy_s, ops, merged_all = [], {}, []
     for evs in planes["devices"].values():
         inside = [(s, e, n) for s, e, n in evs if e > lo_ns and s < hi_ns]
@@ -101,7 +106,7 @@ def reduce_trace(planes: dict, lo_ns: int, hi_ns: int,
             ops[n] = ops.get(n, 0.0) + (min(e, hi_ns) - max(s, lo_ns)) / 1e9
     # idle gaps of the first device (one chip: the only one)
     gaps, t = [], lo_ns
-    for s, e in merged_all[0]:
+    for s, e in merged_all[0] if merged_all else []:
         if s > t:
             gaps.append((t, s))
         t = max(t, e)
@@ -132,7 +137,7 @@ def reduce_trace(planes: dict, lo_ns: int, hi_ns: int,
     longest = sorted(named, key=lambda x: -x[1])[:TOP // 2]
     by_name = sorted(totals.items(), key=lambda x: -x[1])[:TOP - len(longest)]
     return {
-        "busy_s": sum(busy_s) / len(busy_s),
+        "busy_s": sum(busy_s) / max(n_devices, len(busy_s)),
         "window_s": (hi_ns - lo_ns) / 1e9,
         "device_ops": [[n, s] for n, s in
                        sorted(ops.items(), key=lambda x: -x[1])[:TOP]],
