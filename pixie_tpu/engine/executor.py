@@ -14,16 +14,18 @@ re-stream from those.  Joins/unions run host-side in numpy in v1 (they see small
 aggregated inputs in the target workloads); the device hash-join is a perf-phase
 upgrade tracked in SURVEY.md §7.
 
-Group-by strategy (see ops/groupby.py): every key must be reducible to a dense
-code — dictionary columns natively, raw int columns via a query-time dictionary
+Group-by strategy (see ops/groupby.py): a key reducible to a dense code —
+dictionary columns natively, raw int columns via a query-time dictionary
 built in a host pre-scan of the cursor snapshot, and `px.bin(time)`-derived
-window keys via range arithmetic. Anything else is rejected until the sort-based
-fallback lands.
+window keys via range arithmetic — indexes a dense state.  Any other key, and
+a dense space that is past MAX_GROUPS or sparse against its input, takes the
+sorted form on the same routed arm (GroupKeyFallback, _sorted_group_reduce).
 """
 from __future__ import annotations
 
 import contextlib as _contextlib
 import dataclasses
+import math
 import time as _time
 from typing import Callable, Optional
 
@@ -58,13 +60,16 @@ from pixie_tpu.status import CompilerError, Internal, InvalidArgument, Unimpleme
 from pixie_tpu.table.dictionary import Dictionary
 from pixie_tpu.types import STORAGE_DTYPE, ColumnSchema, DataType as DT, Relation
 
+from pixie_tpu.ops import groupby as _gb
 from pixie_tpu.ops.groupby import next_pow2
 
 INT64_MIN = np.iinfo(np.int64).min
 INT64_MAX = np.iinfo(np.int64).max
 MAX_GROUPS = 1 << 22
-#: Sorted-fallback device reduction chunk (rows per update step).
-SORT_AGG_CHUNK = 1 << 20
+#: A dense state of up to this many slots a leaf is cheap to initialise, read
+#: back and search whatever its occupancy (half a megabyte an INT64 leaf):
+#: under it the dense form is kept however few rows the input has.
+SPARSE_MIN_GROUPS = 1 << 16
 #: Minimum window-bin bucket: keeps the compiled group space stable across
 #: streaming polls whose deltas span few windows.
 MIN_WINDOW_BINS = 1 << 6
@@ -83,11 +88,17 @@ def _decode_picker_codes(vals, d: Dictionary) -> np.ndarray:
 
 
 class GroupKeyFallback(Unimplemented):
-    """Raised when group keys are not expressible as bounded dense codes
-    (computed numeric keys, float keys, cardinality beyond MAX_GROUPS).
-    The executor catches it and reruns the aggregate through the sort-based
-    path (SURVEY §7 hard parts; reference capability: exec/agg_node.h's hash
-    map has no cardinality bound)."""
+    """Raised where the dense form of an aggregate (one state slot for every
+    combination of the keys' dense codes) does not serve it: a key has no
+    bounded dense code (a computed numeric key, a float key), the product of
+    the codes' cardinalities passes MAX_GROUPS, or that product is sparse
+    against the input (`_group_space_is_sparse`: more slots than the input
+    has rows, so the state would be mostly identities to initialise, read
+    back and search).  The executor catches it and runs the aggregate
+    through the sorted form on the same routed arm (`_sorted_group_reduce`:
+    the rows sorted by their keys on the device, one run a group, a
+    result of one slot a live group; reference capability: exec/agg_node.h's
+    hash map has no cardinality bound)."""
 MIN_BUCKET = 1 << 10
 from pixie_tpu import flags as _flags
 
@@ -403,6 +414,27 @@ def _route_backend(src, scale: int = 1) -> str:
     return "device"
 
 
+def _group_space_is_sparse(num_groups: int, src) -> bool:
+    """True where a dense state of `num_groups` slots a leaf has more slots
+    than the input over `src` has rows (its pow2 bucket) and more than
+    SPARSE_MIN_GROUPS: most of it would be identities, and what it costs to
+    initialise, read back and search grows with the space while the sorted
+    form's cost and result grow with the rows alone.  Both arms take the
+    answer, so that a script's spellings share one path and one model key;
+    XLA-CPU alone would scatter into such a space faster than it sorts
+    (PERF.md section 7, row 9)."""
+    n = _src_rows(src)
+    return (n is not None and num_groups > SPARSE_MIN_GROUPS
+            and num_groups > next_pow2(max(n, 1)))
+
+
+@_functools.partial(jax.jit, static_argnums=2)
+def _take_front(tree, order, n: int):
+    """Every leaf of `tree` at the first `n` rows of `order`, still on the
+    device."""
+    return _gb.take_rows(tree, order[:n])
+
+
 def _route_class_of(head, chain, op) -> str:
     """The class of the router's model key for the chain `head -> chain ->
     op`: what _chain_cache_sig signs less everything that changes while the
@@ -709,6 +741,55 @@ class ChainKernel:
             return outs, jnp.sum(mask.astype(jnp.int64)), consumed
 
         return jax.jit(step), out_dtypes, out_dicts
+
+    def make_keyed_rows_step(self, key_builders: list, cards: Optional[list],
+                             value_builders: list):
+        """→ jit fn(cols, n_valid, t_lo, t_hi, limit_remaining, luts)
+        → (mask, sort keys, values, consumed): the rows the chain lets
+        through (`mask`, over the whole bucket: nothing is compacted), what
+        to sort them by (ops/groupby.run_sort_keys of their group keys) and
+        the aggregates' value columns, for the sorted aggregate.
+        `key_builders`: (build, kind) per group key, kind "dict" (codes; a
+        null code, -1, takes its row out of `mask`), "float" (a NaN does)
+        or "int".  With `cards` (every key a dictionary code, their pow2
+        cardinalities) the keys are one mixed-radix id, int32 where the
+        product fits and int64 otherwise, and the masked rows take the
+        product itself; without, one array a key behind a live-first key."""
+        packed_dtype = total = None
+        if cards is not None:
+            total = math.prod(cards)
+            packed_dtype = jnp.int32 if total < (1 << 31) else jnp.int64
+
+        def step(cols, n_valid, t_lo, t_hi, limit_remaining, luts):
+            env = {"cols": cols, "luts": luts}
+            n = _first_len(cols)
+            mask = self._base_mask(env, n, n_valid, t_lo, t_hi)
+            mask, consumed = self._apply_steps(env, mask, limit_remaining)
+
+            def rows(v):
+                return jnp.broadcast_to(v, (n,)) if v.ndim == 0 else v
+
+            keys = []
+            for build, kind in key_builders:
+                k = rows(build(env))
+                if kind == "dict":
+                    mask = mask & (k >= 0)
+                elif kind == "float":
+                    mask = mask & ~jnp.isnan(k)
+                elif k.dtype == jnp.bool_:
+                    k = k.astype(jnp.int32)
+                keys.append(k)
+            if packed_dtype is not None:
+                keys = [_gb.combine_codes(keys, cards, packed_dtype)[0]]
+            values = []
+            for build in value_builders:
+                v = rows(build(env))
+                values.append(v.astype(jnp.int32) if v.dtype == jnp.bool_
+                              else v)
+            return (mask, _gb.run_sort_keys(keys, mask, total), values,
+                    consumed)
+
+        return jax.jit(step)
 
     def make_partial_agg_step(self, keys, udas, num_groups: int, init_specs):
         """→ jit fn(cols, n_valid, t_lo, t_hi, luts) → partial state.
@@ -1313,6 +1394,21 @@ class PlanExecutor:
             self._at_route[key] = dec
             self.stats.setdefault("autotune", []).append(dec)
         return "cpu" if dec["arm"] == "cpu" else "device"
+
+    def _observe_route(self, src, rec: dict, compile_s0: float) -> None:
+        """Fold the measured wall of the chain over `src` (its _timed frame
+        `rec`) into the routing decision that picked its backend (per-arm
+        cost model, engine/autotune.py).  The sample is the chain's wall
+        less what jax compiled in it since `compile_s0` (recorded beside
+        it): a program's first run must not price its arm.  jax reports
+        nested traces inside their outer one too, so compile_s can pass the
+        wall by a few percent and that one sample reads 0."""
+        dec = self._route_decision(src)
+        if dec is not None and rec.get("wall_ns"):
+            compile_s = self.stats["compile_s"] - compile_s0
+            _autotune.MODEL.observe_decision(
+                dec, max(rec["wall_ns"] / 1e9 - compile_s, 0.0))
+            dec["compile_ms"] = round(compile_s * 1e3, 3)
 
     def _device_ctx(self, src):
         if self._backend_for(src) == "cpu":
@@ -2094,148 +2190,213 @@ class PlanExecutor:
         return self._finalize_agg(op, keys, udas, state_np, seen_name, in_types,
                                   val_dicts)
 
-    # -------------------------------------------------- sort-based agg fallback
-    def _sorted_group_reduce(self, op: AggOp):
-        """Sort-based groupby for keys with no bounded dense code space.
-
-        Two phases, matching the SURVEY §7 design: (1) the chain's compiled
-        select kernel materializes group-key + value columns (device work);
-        (2) the host sorts/uniques the composite key — the analog of the
-        reference's unbounded hash map (exec/agg_node.h:55-140) — and the
-        per-group reduction goes back to the device as chunked masked segment
-        reductions over the exact group ids.
-
-        Returns (group_cols, dtypes, dicts, udas, in_types, state_np, G,
-        val_dicts) — val_dicts maps dict-valued picker outputs to the
-        dictionary their code-state decodes through.
-        """
-        self.stats["sorted_agg_fallbacks"] = self.stats.get("sorted_agg_fallbacks", 0) + 1
-        parent = self.plan.parents(op)[0]
-        need = list(dict.fromkeys(
-            [*op.groups, *[ae.arg for ae in op.values if ae.arg is not None]]
-        ))
-        hb = self._consume_to_batch(parent, need)
-        cols, out_dtypes, out_dicts = hb.cols, hb.dtypes, hb.dicts
-        n = hb.num_rows
-
-        # ---- composite key factorization (host sort).
-        valid = np.ones(n, dtype=bool)
-        per_inv, per_card = [], []
+    # ------------------------------------------------------- sorted aggregate
+    def _sorted_agg_kernel(self, op: AggOp, sig, dtypes, dicts, chain,
+                           time_col, visible):
+        """Fetch-or-build the sorted aggregate's kernel bundle for `op`:
+        (kern, rows_step, reduce_step, rest_step, key_specs, cards, udas,
+        in_types, val_dicts).  `udas` holds (out_name, uda, index of its
+        value column | None, input dtype); `key_specs` (name, DataType,
+        dictionary | None) per group key."""
+        cached = _cache_get(sig)
+        if cached is not None:
+            return cached
+        kern = ChainKernel(dtypes, dicts, chain, self.registry, time_col,
+                           visible)
+        key_specs, key_builders = [], []
         for g in op.groups:
-            arr = cols[g]
-            if g in out_dicts:
-                valid &= arr >= 0  # null keys drop out (pandas dropna)
-            elif arr.dtype.kind == "f":
-                valid &= ~np.isnan(arr)  # NaN keys drop out (pandas dropna)
-            u, inv = np.unique(arr, return_inverse=True)
-            per_inv.append(inv.astype(np.int64))
-            per_card.append(len(u))
-        total_card = 1
-        for c in per_card:
-            total_card *= max(c, 1)
-        if total_card < (1 << 62):
-            comp = per_inv[0]
-            for inv, card in zip(per_inv[1:], per_card[1:]):
-                comp = comp * card + inv
-        else:
-            # mixed radix would overflow int64: unique over the record rows
-            _u, comp = np.unique(np.rec.fromarrays(per_inv), return_inverse=True)
-            comp = comp.astype(np.int64)
-        vrows = np.nonzero(valid)[0]
-        uniq_comp, first_in_valid = (
-            np.unique(comp[vrows], return_index=True)
-            if len(vrows)
-            else (np.empty(0, dtype=np.int64), np.empty(0, dtype=np.int64))
-        )
-        G = len(uniq_comp)
-        rep_rows = vrows[first_in_valid]  # one representative row per group
-        group_cols = {g: cols[g][rep_rows] for g in op.groups}
-        Gb = max(next_pow2(max(G, 1)), 1)
-        gid_np = np.searchsorted(uniq_comp, comp).clip(0, Gb - 1).astype(np.int32)
-
-        # ---- device reduction over exact gids, chunked.
-        udas, in_types, init_pairs = [], {}, []
+            sv = kern.ctx.sym.get(g)
+            if sv is None:
+                raise CompilerError(f"group key {g!r} not found")
+            if sv.dictionary is not None:
+                kind = "dict"
+            elif sv.dtype == DT.FLOAT64:
+                kind = "float"
+            elif sv.dtype in (DT.INT64, DT.TIME64NS, DT.BOOLEAN):
+                kind = "int"
+            else:
+                raise Unimplemented(
+                    f"group key {g!r} has type {sv.dtype.name} and no "
+                    "dictionary")
+            key_specs.append((g, sv.dtype, sv.dictionary))
+            key_builders.append((sv.build, kind))
+        # every key a dictionary code: one mixed-radix id rides the sorts
+        # where the product of the pow2 cardinalities fits 62 bits
+        cards = None
+        if all(d is not None for _g, _dt, d in key_specs):
+            cards = [next_pow2(max(d.size, 1)) for _g, _dt, d in key_specs]
+            if math.prod(cards) >= (1 << 62):
+                cards = None
+        udas, in_types = [], {}
         val_dicts: dict[str, Dictionary] = {}
-        dict_val_cols: set[str] = set()
+        value_names: list[str] = []
+        value_builders = []
         for ae in op.values:
             uda = self.registry.uda(ae.fn)
-            in_dt = None
+            vi = in_dt = None
             in_types[ae.out_name] = None
             if ae.arg is not None:
-                if ae.arg in out_dicts:
-                    if not uda.dict_ok:
-                        raise Unimplemented(
-                            f"aggregate {ae.fn} over string column {ae.arg!r}"
-                        )
-                    in_types[ae.out_name] = out_dtypes[ae.arg]
-                    in_dt = np.int32
-                    val_dicts[ae.out_name] = out_dicts[ae.arg]
-                    dict_val_cols.add(ae.arg)
-                else:
-                    if getattr(uda, "needs_dict", False):
-                        raise Unimplemented(
-                            f"aggregate {ae.fn} requires a string "
-                            f"(dictionary-encoded) input column, got "
-                            f"{ae.arg!r}"
-                        )
-                    in_types[ae.out_name] = out_dtypes[ae.arg]
-                    in_dt = STORAGE_DTYPE[out_dtypes[ae.arg]]
+                sv = kern.ctx.sym.get(ae.arg)
+                if sv is None:
+                    raise CompilerError(
+                        f"agg input column {ae.arg!r} not found")
+                picker = sv.dictionary is not None
+                if picker and not uda.dict_ok:
+                    raise Unimplemented(
+                        f"aggregate {ae.fn} over string column {ae.arg!r}")
+                if not picker and getattr(uda, "needs_dict", False):
+                    raise Unimplemented(
+                        f"aggregate {ae.fn} requires a string "
+                        f"(dictionary-encoded) input column, got {ae.arg!r}")
+                in_types[ae.out_name] = sv.dtype
+                in_dt = np.int32 if picker else STORAGE_DTYPE[sv.dtype]
+                if picker:
+                    val_dicts[ae.out_name] = sv.dictionary
+                if ae.arg not in value_names:  # one payload a column
+                    value_names.append(ae.arg)
+                    b = sv.build
+                    # null codes must never win the picker's min-reduction
+                    value_builders.append(
+                        (lambda env, b=b: jnp.where(
+                            (v := b(env)) >= 0, v,
+                            jnp.int32(PICKER_NULL_SENTINEL)))
+                        if picker else b)
+                vi = value_names.index(ae.arg)
             elif not uda.nullary:
-                raise CompilerError(f"aggregate {ae.fn} requires an input column")
-            udas.append((ae.out_name, uda, ae.arg))
-            init_pairs.append((ae.out_name, uda, in_dt))
-        val_names = sorted({vn for _o, _u, vn in udas if vn is not None})
-        # null codes must never win the picker's min-reduction
-        for vn in dict_val_cols:
-            c = cols[vn]
-            cols = {**cols,
-                    vn: np.where(c >= 0, c, PICKER_NULL_SENTINEL).astype(np.int32)}
+                raise CompilerError(
+                    f"aggregate {ae.fn} requires an input column")
+            udas.append((ae.out_name, uda, vi, in_dt))
+        rows_step = kern.make_keyed_rows_step(key_builders, cards,
+                                              value_builders)
+        n_keys = 1 if cards is not None else len(key_specs)
+        scans = [u for u in udas if u[1].segment_only]
+        rest = [u for u in udas if not u[1].segment_only]
 
-        # The jitted update closure is cached per (registry, agg spec, Gb):
-        # jax.jit then reuses traces across calls/polls instead of recompiling
-        # the reduction every invocation.
-        upd_key = (
-            "sorted_upd", self.registry.uid,
-            tuple((ae.out_name, ae.fn, ae.arg) for ae in op.values), Gb,
-        )
-        cached_upd = _cache_get(_json.dumps(upd_key))
-        if cached_upd is not None:
-            upd, udas = cached_upd
+        def reduce_step(mask, keys_sorted, order, values):
+            """Over the rows as `sort_order` ordered them: every run of
+            equal keys reduced at its last row.  -> (groups, the key whose
+            `sort_order` brings those rows to the front, {"keys", "state"}
+            with [n] leaves, and what `rest_step` needs)."""
+            n = mask.shape[0]
+            keys_s = list(keys_sorted[-n_keys:])
+            runs, live = _gb.runs_of(keys_s, jnp.sum(mask.astype(jnp.int32)))
+            values_s = _gb.take_rows(values, order)
+            state = {
+                name: uda.update(
+                    uda.init(n, in_dt), runs,
+                    None if vi is None else values_s[vi], live, n)
+                for name, uda, vi, in_dt in scans}
+            groups = jnp.sum(runs.end.astype(jnp.int32))
+            after = ((_gb.run_ids(runs), live, values_s) if rest else None)
+            return (groups, _gb.run_end_key(runs),
+                    {"keys": keys_s, "state": state}, after)
+
+        def rest_step(seg, live, values_s, gb):
+            """The aggregates that do not reduce runs (sketches, code
+            histograms), over the runs' exact ids into `gb` slots."""
+            return {
+                name: uda.update(
+                    uda.init(gb, in_dt), seg,
+                    None if vi is None else values_s[vi], live, gb)
+                for name, uda, vi, in_dt in rest}
+
+        bundle = (kern, rows_step, jax.jit(reduce_step),
+                  jax.jit(rest_step, static_argnums=3) if rest else None,
+                  key_specs, cards, udas, in_types, val_dicts)
+        _cache_put(sig, bundle)
+        return bundle
+
+    def _sorted_group_reduce(self, op: AggOp):
+        """The aggregate for a group space the dense form does not serve
+        (GroupKeyFallback), on whichever arm the router picks for its
+        chain.  The chain's rows, keys and value columns stay where the
+        feed is; there the rows are sorted by their keys, every run of
+        equal keys is reduced by a segmented scan and a second sort finds
+        the runs' results (ops/groupby.sort_order, runs_of, run_end_key:
+        the analog of the reference's unbounded hash map,
+        exec/agg_node.h:55-140, with no scatter and no dense state).  The
+        host reads the number of groups, then their keys and states and
+        nothing else.
+
+        Returns (group_cols, dtypes, dicts, udas, in_types, state_np, G,
+        val_dicts): the leaves of state_np hold at least G slots, in the
+        order of group_cols' rows; val_dicts maps dict-valued picker
+        outputs to the dictionary their code-state decodes through.
+        """
+        self.stats["sorted_agg_fallbacks"] = self.stats.get("sorted_agg_fallbacks", 0) + 1
+        head, chain = self._upstream_chain(self.plan.parents(op)[0])
+        dtypes, dicts, src, names, visible, time_col, cap = self._input_of(head)
+        needed = set(op.groups) | {ae.arg for ae in op.values
+                                   if ae.arg is not None}
+        dtypes, dicts, names, visible, chain = _prune_to_needed(
+            head, chain, dtypes, dicts, names, visible, time_col, needed)
+        self._name_route(src, head, chain, op)
+        sig = self._chain_cache_sig(head, chain, dtypes, dicts,
+                                    ["sorted_agg", _op_sig(op)])
+        (kern, rows_step, reduce_step, rest_step, key_specs, cards, udas,
+         in_types, val_dicts) = self._sorted_agg_kernel(
+            op, sig, dtypes, dicts, chain, time_col, visible)
+        t_lo, t_hi = _time_bounds(head)
+        compile_s0 = self.stats["compile_s"]
+        with self._timed(
+            self._chain_label(head, chain, "sorted_agg"),
+            ([head.id] if head.id >= 0 else []) + [o.id for o in chain]
+            + [op.id],
+        ) as rec, self._device_ctx(src):
+            self._note_chain(src, rec, kern)
+            remaining = kern.init_limits()
+            parts = []
+            for cols, n_valid in self._feed(
+                    src, names, cap, backend=self._backend_for(src)):
+                mask, keys, values, consumed = rows_step(
+                    cols, np.int64(n_valid), t_lo, t_hi, remaining, kern.luts)
+                if kern.has_limit:
+                    remaining = remaining - consumed
+                parts.append((mask, keys, values))
+            groups = 0
+            state, key_cols = {}, []
+            if parts:
+                with self._timed("sort_reduce", [op.id]):
+                    # a table of several feeds sorts them as one input
+                    mask, sort_keys, values = (
+                        parts[0] if len(parts) == 1 else jax.tree.map(
+                            lambda *xs: jnp.concatenate(xs), *parts))
+                    n_groups, end_key, found, after = reduce_step(
+                        mask, *_gb.sort_order(sort_keys), values)
+                    _ends, front = _gb.sort_order((end_key,))
+                    groups = int(n_groups)  # the one sync before the pull
+                with self._timed("compact_readback", [op.id]):
+                    gb = next_pow2(max(groups, 1))
+                    found = _take_front(found, front, gb)
+                    if rest_step is not None:
+                        found["state"].update(rest_step(*after, gb))
+                    pulled = transfer.pull(found)
+                    state, key_cols = pulled["state"], pulled["keys"]
+            else:  # no rows at all: identity states, no group
+                state = transfer.pull({name: uda.init(1, in_dt)
+                                       for name, uda, _vi, in_dt in udas})
+            rec["rows_out"] = groups
+            rec["span"].update(
+                groups_out=groups,
+                d2h_bytes=4 + sum(
+                    x.nbytes for x in jax.tree.leaves((state, key_cols))))
+        self._observe_route(src, rec, compile_s0)
+        if not parts:
+            codes = [np.empty(0, np.int64)] * len(key_specs)
+        elif cards is not None:
+            codes = _gb.split_codes(key_cols[0][:groups], cards)
         else:
-            spec = list(udas)
-
-            def upd(state, gid, mask, vals, spec=spec):
-                new = {}
-                for out_name, uda, vname in spec:
-                    v = vals[vname] if vname is not None else None
-                    new[out_name] = uda.update(state[out_name], gid, v, mask, Gb)
-                return new
-
-            upd = jax.jit(upd, donate_argnums=(0,))
-            _cache_put(_json.dumps(upd_key), (upd, udas))
-        with self._timed(f"sorted_agg(by={op.groups}, G={G})",
-                         [op.id]) as rec, self._device_ctx(hb):
-            self._note_chain(hb, rec)
-            # state init happens inside the device context so the donated
-            # accumulators live on the dispatch device (CPU for small batches)
-            state = {name: uda.init(Gb, in_dt)
-                     for name, uda, in_dt in init_pairs}
-            fed = 0
-            for off in range(0, n, SORT_AGG_CHUNK):
-                end = min(off + SORT_AGG_CHUNK, n)
-                bucket = max(next_pow2(end - off), MIN_BUCKET)
-                fed += bucket
-                gid_c = _pad(gid_np[off:end], bucket)
-                mask_c = np.zeros(bucket, dtype=bool)
-                mask_c[: end - off] = valid[off:end]
-                vals_c = {vn: _pad(cols[vn][off:end], bucket) for vn in val_names}
-                state = upd(state, gid_c, mask_c, vals_c)
-                if self.analyze:
-                    jax.block_until_ready(state)
-            rec["span"]["feed_rows"] = fed
-            state_np = transfer.pull(state)
-        return (group_cols, out_dtypes, out_dicts, udas, in_types, state_np, G,
-                val_dicts)
+            codes = [k[:groups] for k in key_cols]
+        group_cols, out_dtypes, out_dicts = {}, {}, {}
+        for code, (g, dt, d) in zip(codes, key_specs):
+            out_dtypes[g] = dt
+            if d is not None:
+                out_dicts[g] = d
+            group_cols[g] = code.astype(
+                np.int32 if d is not None else STORAGE_DTYPE[dt], copy=False)
+        return (group_cols, out_dtypes, out_dicts,
+                [(name, uda, vi) for name, uda, vi, _dt in udas], in_types,
+                state, groups, val_dicts)
 
     def _run_agg_sorted(self, op: AggOp) -> HostBatch:
         (group_cols, in_dtypes, in_dicts, udas, in_types, state_np, G,
@@ -2285,12 +2446,15 @@ class PlanExecutor:
                 "(the distributed planner cuts them as rows channels)"
             )
         key_cols, key_dtypes = {}, {}
-        for g in op.groups:
-            key_dtypes[g] = in_dtypes[g]
-            if g in in_dicts:
-                key_cols[g] = np.asarray(in_dicts[g].decode(group_cols[g]), dtype=object)
-            else:
-                key_cols[g] = group_cols[g]
+        with self._timed("key_decode", [op.id]) as rec:
+            # ship VALUES: each agent has a private code space
+            rec["rows_out"] = G
+            for g in op.groups:
+                key_dtypes[g] = in_dtypes[g]
+                if g in in_dicts:
+                    key_cols[g] = in_dicts[g].decode_array(group_cols[g])
+                else:
+                    key_cols[g] = group_cols[g]
         states = {
             out_name: jax.tree.map(lambda x: np.asarray(x)[:G], state_np[out_name])
             for out_name, _uda, _vn in udas
@@ -2400,6 +2564,11 @@ class PlanExecutor:
                                      time_col, visible, src, head)
             (kern, keys, udas, in_types, init_specs, num_groups, seen_name,
              step, partial_step, merge_fn, spmd_step, val_dicts) = built
+            if _group_space_is_sparse(num_groups, src):
+                # decided for this input, so not memoized under fb_sig
+                raise GroupKeyFallback(
+                    f"agg {op.id}: {num_groups} dense slots for "
+                    f"{_src_rows(src)} rows")
             ok, keys, lut_over = self._refresh_window_keys(keys, src, head)
             if ok:
                 break
@@ -2508,19 +2677,7 @@ class PlanExecutor:
                         src, names, cap, t_lo, t_hi, luts, fuse_key=sig,
                     )
                 self._feed_rec = None
-        dec = self._route_decision(src)
-        if dec is not None and rec.get("wall_ns"):
-            # fold the measured chain wall into the routing decision that
-            # picked this backend (per-arm cost model, engine/autotune.py).
-            # The sample is the chain's wall less what jax compiled in it
-            # (recorded beside it): a program's first run must not price
-            # its arm.  jax reports nested traces inside their outer one
-            # too, so compile_s can pass the wall by a few percent and that
-            # one sample reads 0
-            compile_s = self.stats["compile_s"] - compile_s0
-            _autotune.MODEL.observe_decision(
-                dec, max(rec["wall_ns"] / 1e9 - compile_s, 0.0))
-            dec["compile_ms"] = round(compile_s * 1e3, 3)
+        self._observe_route(src, rec, compile_s0)
         return keys, udas, state_np, seen_name, in_types, val_dicts
 
     def _wholeplan_program(self, sig, kern, chain, op, keys, init_specs,
@@ -2963,7 +3120,7 @@ class PlanExecutor:
                 col, d = self._decode_key_column(k, kc)
                 if d is not None:
                     # ship VALUES — each agent has a private code space
-                    key_cols[k.name] = np.asarray(d.decode(col), dtype=object)
+                    key_cols[k.name] = d.decode_array(col)
                 else:
                     key_cols[k.name] = col
         states = {}

@@ -1,13 +1,36 @@
-"""Group-by primitives: dense group codes + masked segment reductions.
+"""Group-by primitives: group codes and masked segment reductions.
 
 Replaces the reference's hash group-by (AbslRowTupleHashMap over RowTuples,
-src/carnot/exec/agg_node.h:55-140) with a TPU-native formulation: every group key
-column is a dense int32 code (dictionary code for strings/UPIDs; query-time
-dictionary for raw ints), multi-key groups are mixed-radix combined into a single
-segment id, and aggregation is an XLA segment reduction — which lowers to sorted
-scatter-adds that tile well, instead of pointer-chasing hash probes.
+src/carnot/exec/agg_node.h:55-140).  Every group key column is a dense int32
+code (dictionary code for strings/UPIDs; query-time dictionary for raw
+ints) and multi-key groups are mixed-radix combined into one group id.  What
+reduces the rows of a group depends on the group count and on the platform
+the kernel is traced for (`dispatch_backend`):
+
+  * up to MATMUL_MAX_GROUPS groups on the TPU, sums and counts are one-hot
+    GEMMs on the MXU, chunk by chunk over the live rows (`scan_sum`,
+    `live_chunks`): exact for counts and INT64 (8-bit limbs), F64_SUM_RTOL
+    for f64;
+  * above that, and on every other platform, sums and counts are
+    `jax.ops.segment_sum` and min/max are `jax.ops.segment_min/max` at any
+    group count: scatters into the dense [num_groups] state.  XLA-CPU runs
+    them at memory speed (0.007 us a row a scatter); the TPU serializes
+    them (0.104 us a row on a v5e: PERF.md section 6, PR 36's kernels
+    alone), and the dense state is initialised, read back and searched for
+    its seen groups whatever its occupancy;
+  * where the group space is sparse (more slots than rows, or no dense
+    code at all: float keys, computed keys, a space past the executor's
+    MAX_GROUPS) the rows are sorted by their keys instead (`sort_order`),
+    every group becomes one run of adjacent rows (`runs_of`), the same
+    `masked_segment_*` calls reduce each run with a segmented scan
+    (`SortedRuns` in the place of the group id) and a second `sort_order`
+    (`run_end_key`) says where the runs' results lie: O(rows log rows)
+    whatever the size of the space, and a result of one slot a live
+    group.
 """
 from __future__ import annotations
+
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -18,8 +41,10 @@ def next_pow2(n: int) -> int:
     return 1 << max(0, (int(n) - 1)).bit_length()
 
 
-def combine_codes(codes: list[jax.Array], cards: list[int]) -> tuple[jax.Array, int]:
-    """Mixed-radix combine k dense code columns into one group id.
+def combine_codes(codes: list[jax.Array], cards: list[int],
+                  dtype=jnp.int32) -> tuple[jax.Array, int]:
+    """Mixed-radix combine k dense code columns into one group id of `dtype`
+    (int64 where the product of the cards passes 31 bits).
 
     cards[i] is a static upper bound on codes[i] (dictionary-size snapshot,
     bucketed by the caller to stabilize compiled shapes). Returns (gid, num_groups)
@@ -30,10 +55,10 @@ def combine_codes(codes: list[jax.Array], cards: list[int]) -> tuple[jax.Array, 
     num_groups = 1
     for c in cards:
         num_groups *= int(c)
-    gid = jnp.zeros_like(codes[0], dtype=jnp.int32)
+    gid = jnp.zeros_like(codes[0], dtype=dtype)
     for code, card in zip(codes, cards):
         c = jnp.clip(code.astype(jnp.int32), 0, card - 1)
-        gid = gid * card + c
+        gid = gid * card + c.astype(dtype)
     return gid, num_groups
 
 
@@ -201,9 +226,121 @@ def _chunked_onehot_multi_sum(lanes_fn, v, gid: jax.Array, num_groups: int,
                     *live_chunks(mask, ch))
 
 
+class SortedRuns(NamedTuple):
+    """Rows sorted so that every group is one run of adjacent rows, handed
+    to `masked_segment_*` in the place of the group id: `start[i]` says that
+    row i opens its run, `end[i]` that it closes it; a row past the live
+    ones does neither.  A reduction over runs is aligned with the rows
+    ([n], not [num_groups]) and holds a run's result at the run's last row;
+    `run_end_key` finds those."""
+
+    start: jax.Array
+    end: jax.Array
+
+
+def run_sort_keys(keys: list, mask: jax.Array,
+                  sentinel: Optional[int] = None) -> tuple:
+    """What `sort_order` sorts the rows of a sorted aggregate by: the rows
+    of `mask` first, then `keys` lexicographically.  `sentinel`, for ONE
+    integer key: a value above every live row's key; the masked rows take
+    it and no key of their own orders them last."""
+    if sentinel is not None:
+        (k,) = keys
+        return (jnp.where(mask, k, jnp.asarray(sentinel, k.dtype)),)
+    return (jnp.logical_not(mask).astype(jnp.int32), *keys)
+
+
+@jax.jit
+def sort_order(keys: tuple) -> tuple:
+    """(the keys sorted lexicographically, the row each sorted position
+    came from): one `lax.sort` of the keys and an iota.  Nothing else
+    rides it and it is a program of its own, because the TPU compiler's
+    time for a sort grows with its operands (11 s for a 32-bit key and the
+    iota at 524,288 rows, 38 s with two INT64 columns riding, 89 s with
+    nine 32-bit ones: PERF.md section 6, PR 36's kernels alone): the value
+    columns are gathered by the order instead, and the sort that moves the
+    runs' results to the front (`run_end_key`) is this same program
+    again."""
+    n = keys[0].shape[0]
+    with jax.named_scope("px.sort_runs"):
+        out = jax.lax.sort((*keys, jnp.arange(n, dtype=jnp.int32)),
+                           num_keys=len(keys))
+    return tuple(out[:-1]), out[-1]
+
+
+def runs_of(keys_sorted: list, n_live: jax.Array) -> tuple:
+    """(runs, live) of rows sorted by `keys_sorted` of which the first
+    `n_live` are live: a run is a stretch of live rows whose keys are all
+    equal; `live[i]` says that sorted row i is a live row."""
+    n = keys_sorted[0].shape[0]
+    live = jnp.arange(n, dtype=jnp.int32) < n_live
+    differs = jnp.zeros((n - 1,), dtype=jnp.bool_)
+    for k in keys_sorted:
+        differs = differs | (k[1:] != k[:-1])
+    first = jnp.ones((1,), dtype=jnp.bool_)
+    start = live & jnp.concatenate([first, differs])
+    end = live & jnp.concatenate([start[1:] | ~live[1:], first])
+    return SortedRuns(start, end), live
+
+
+def run_ids(runs: SortedRuns) -> jax.Array:
+    """The number of each row's run, in run order (int32; 0 before the
+    first): exact dense group ids for a reduction that needs them."""
+    return jnp.maximum(jnp.cumsum(runs.start.astype(jnp.int32)) - 1, 0)
+
+
+def run_end_key(runs: SortedRuns) -> jax.Array:
+    """An int32 key under which `sort_order` puts the rows that close a
+    run first, in run order: its order's first sum(runs.end) entries are
+    where the groups' results lie."""
+    n = runs.end.shape[0]
+    at = jnp.arange(n, dtype=jnp.int32)
+    return jnp.where(runs.end, at, at + n)
+
+
+@jax.named_scope("px.compact_runs")
+def take_rows(tree, rows: jax.Array):
+    """Every leaf of `tree` at `rows`: the gather that brings the value
+    columns into sorted order, and the runs' results to the front."""
+    return jax.tree.map(lambda a: a[rows], tree)
+
+
+def _run_reduce(v: jax.Array, runs: SortedRuns, op) -> jax.Array:
+    """Inclusive scan of `v` under `op` that starts anew at every run
+    start; at a run's last row it is the run's reduction.  No scatter on
+    either platform.  On the TPU: log2(n) whole-array passes, each row
+    taking in the row 2^k before it unless a run starts in between
+    (Hillis-Steele; O(n log n) elementwise work, a few MB a pass).
+    Elsewhere `lax.associative_scan`, O(n) work.  Each platform has the
+    form the other cannot afford (one INT64 leaf of 524,288 rows alone,
+    PERF.md section 6, PR 36): the TPU runs the passes in 0.7 ms and
+    builds them in 2.5 s, where the work-efficient scan's strided slices
+    run in 2.4 ms and take its compiler 24 s a leaf; XLA-CPU runs the
+    passes in 97 ms and the work-efficient scan in 2.5."""
+    if dispatch_backend() != "tpu":
+        def combine(a, b):
+            fa, va = a
+            fb, vb = b
+            return fa | fb, jnp.where(fb, vb, op(va, vb))
+
+        return jax.lax.associative_scan(combine, (runs.start, v))[1]
+    n = v.shape[0]
+    started = runs.start  # a run starts in (i - d, i]: nothing to take in
+    d = 1
+    while d < n:
+        before = jnp.concatenate([v[:d], v[:-d]])
+        v = jnp.where(started, v, op(before, v))
+        started = started | jnp.concatenate(
+            [jnp.ones((d,), dtype=jnp.bool_), started[:-d]])
+        d *= 2
+    return v
+
+
 @jax.named_scope("px.groupby_sum")
 def masked_segment_sum(values: jax.Array, gid: jax.Array, num_groups: int, mask: jax.Array):
     v = jnp.where(mask, values, jnp.zeros((), dtype=values.dtype))
+    if isinstance(gid, SortedRuns):
+        return _run_reduce(v, gid, jnp.add)
     if not _use_matmul(v.shape[0], num_groups):
         return jax.ops.segment_sum(v, gid, num_segments=num_groups)
     gid = gid.astype(jnp.int32)
@@ -253,6 +390,8 @@ def masked_segment_sum(values: jax.Array, gid: jax.Array, num_groups: int, mask:
 def masked_segment_count(gid: jax.Array, num_groups: int, mask: jax.Array) -> jax.Array:
     """Rows per group (int64, exact): f32 one-hot matmul of the mask on TPU
     (per-chunk counts ≤ CHUNK_ROWS are exact in f32), scatter elsewhere."""
+    if isinstance(gid, SortedRuns):
+        return _run_reduce(mask.astype(jnp.int64), gid, jnp.add)
     n = gid.shape[0]
     if _use_matmul(n, num_groups):
         c = _chunked_onehot_sum(mask.astype(jnp.float32),
@@ -266,6 +405,8 @@ def masked_segment_count(gid: jax.Array, num_groups: int, mask: jax.Array) -> ja
 def masked_segment_min(values: jax.Array, gid: jax.Array, num_groups: int, mask: jax.Array):
     big = _identity_for(values.dtype, "min")
     v = jnp.where(mask, values, big)
+    if isinstance(gid, SortedRuns):
+        return _run_reduce(v, gid, jnp.minimum)
     return jax.ops.segment_min(v, gid, num_segments=num_groups)
 
 
@@ -273,6 +414,8 @@ def masked_segment_min(values: jax.Array, gid: jax.Array, num_groups: int, mask:
 def masked_segment_max(values: jax.Array, gid: jax.Array, num_groups: int, mask: jax.Array):
     small = _identity_for(values.dtype, "max")
     v = jnp.where(mask, values, small)
+    if isinstance(gid, SortedRuns):
+        return _run_reduce(v, gid, jnp.maximum)
     return jax.ops.segment_max(v, gid, num_segments=num_groups)
 
 

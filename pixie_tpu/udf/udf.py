@@ -97,6 +97,11 @@ class UDA:
     needs_dict: bool = False
     #: fixed output semantic type (e.g. quantiles → ST_QUANTILES), or None
     out_st = None
+    #: True if update() reaches the rows through ops.groupby.masked_segment_*
+    #: alone and combines their results elementwise: such a UDA reduces the
+    #: runs of pre-sorted rows (groupby.SortedRuns in the place of `gid`,
+    #: one state slot a row) exactly as it reduces dense group ids
+    segment_only: bool = False
 
     def out_type(self, in_type: DataType | None) -> DataType:
         raise NotImplementedError
@@ -147,6 +152,7 @@ def _acc_dtype(in_dtype) -> jnp.dtype:
 class CountUDA(UDA):
     name = "count"
     nullary = True
+    segment_only = True
 
     def out_type(self, in_type):
         return DataType.INT64
@@ -169,6 +175,7 @@ class CountUDA(UDA):
 class SumUDA(UDA):
     name = "sum"
     st_preserve = True
+    segment_only = True
 
     def out_type(self, in_type):
         return DataType.FLOAT64 if in_type == DataType.FLOAT64 else DataType.INT64
@@ -191,6 +198,7 @@ class SumUDA(UDA):
 class MeanUDA(UDA):
     name = "mean"
     st_preserve = True
+    segment_only = True
 
     def out_type(self, in_type):
         return DataType.FLOAT64
@@ -221,6 +229,7 @@ class MeanUDA(UDA):
 class MinUDA(UDA):
     name = "min"
     st_preserve = True
+    segment_only = True
 
     def out_type(self, in_type):
         return in_type
@@ -245,6 +254,7 @@ class MinUDA(UDA):
 class MaxUDA(UDA):
     name = "max"
     st_preserve = True
+    segment_only = True
 
     def out_type(self, in_type):
         return in_type
@@ -272,6 +282,7 @@ class VarianceUDA(UDA):
     its states merge two at a time; collectives prefer linear state)."""
 
     name = "variance"
+    segment_only = True
 
     def out_type(self, in_type):
         return DataType.FLOAT64
@@ -322,6 +333,7 @@ class AnyUDA(UDA):
     name = "any"
     st_preserve = True
     dict_ok = True
+    segment_only = True
 
     def out_type(self, in_type):
         return in_type

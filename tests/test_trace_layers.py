@@ -199,6 +199,49 @@ def test_chain_span_says_the_bucket_its_kernels_were_handed(served):
         assert c.attributes["feed_rows"] == 4096 + 1024
 
 
+SORTED_QUERY = """
+import px
+df = px.DataFrame(table='http_events')
+df.k = df.latency % 7
+df = df.groupby(['service', 'k']).agg(mn=('latency', px.min),
+                                      mx=('latency', px.max))
+px.display(df, 'out')
+"""
+
+
+def test_sorted_aggregate_spans_say_its_phases_and_what_came_back(served):
+    """A computed key has no dense code: the aggregate sorts.  Its chain
+    span (`..->sorted_agg`) is routed like any other and says how many
+    groups came out and the bytes read back; `sort_reduce` and
+    `compact_readback` lie inside it, the host's `key_decode` follows it."""
+    client, _store = served
+    for _ in range(autotune.COLD_PROBE_PERIOD):
+        out = client.execute_script(SORTED_QUERY)["out"].to_pandas()
+    assert len(out) == 14
+    spans = _agent_spans()
+    chains = _chains(spans)
+    assert [c.name for c in chains] == ["scan(http_events)->map->sorted_agg"] * 4
+    assert [c.attributes["source"] for c in chains] == [
+        "cold", "cold", "cold", "explore"]
+    assert [c.attributes["engine"] for c in chains] == [
+        "xla_cpu_chain"] * 3 + ["device_chain"]
+    for c in chains:
+        a = c.attributes
+        assert a["groups_out"] == 14
+        assert a["plan_class"].startswith("agg:http_events:")
+        # 16 slots of an int32 code, an int64 key and two int64 states
+        assert a["d2h_bytes"] == 4 + 16 * (4 + 8 + 8 + 8)
+        assert a["rows"] == 3000 and a["feed_rows"] == 4096 + 1024
+        inside = [s for s in spans if s.name in ("sort_reduce",
+                                                 "compact_readback")
+                  and c.start_ns <= s.start_ns and s.end_ns <= c.end_ns]
+        assert sorted(s.name for s in inside) == ["compact_readback",
+                                                  "sort_reduce"]
+    decodes = [s for s in spans if s.name == "key_decode"]
+    assert len(decodes) == 4
+    assert all(s.attributes["rows_out"] == 14 for s in decodes)
+
+
 JOIN_QUERY = """
 import px
 a = px.DataFrame(table='http_events')
@@ -317,6 +360,25 @@ def _lower_sketch(method):
     return lower
 
 
+def _lower_sorted_runs(monkeypatch):
+    """The sorted aggregate's kernel: the sort of the keys, the gather of
+    the values, a run reduction and the gather of the runs' results."""
+    from pixie_tpu.ops import groupby
+
+    def reduce(k, v, m):
+        (_dead, ks), order = groupby.sort_order(
+            groupby.run_sort_keys([k], m))
+        runs, live = groupby.runs_of([ks], m.sum())
+        vs = groupby.take_rows(v, order)
+        mn = groupby.masked_segment_min(vs, runs, k.shape[0], live)
+        _ends, front = groupby.sort_order((groupby.run_end_key(runs),))
+        return groupby.take_rows({"k": ks, "mn": mn}, front)
+
+    n = 2048
+    return jax.jit(reduce).lower(np.zeros(n, np.int32), np.ones(n, np.int64),
+                                 np.ones(n, bool))
+
+
 def _lower_md_lookup(monkeypatch):
     from pixie_tpu.engine.eval import apply_lut
 
@@ -355,6 +417,11 @@ SCOPES = {
     "px.sketch_update_gemm": _lower_sketch("_update_gemm"),
     "px.sketch_update_sorted": _lower_sketch("_update_sorted"),
     "px.sketch_update_segment": _lower_sketch("_update_segment"),
+    # a program of its own (the executor calls it between two others)
+    "px.sort_runs": lambda _mp: __import__(
+        "pixie_tpu.ops.groupby", fromlist=["sort_order"]).sort_order.lower(
+            (np.zeros(2048, np.int32),)),
+    "px.compact_runs": _lower_sorted_runs,
     "px.md_lookup": _lower_md_lookup,
     "px.time_mask": _lower_window_agg,
     "px.window_bin": _lower_window_agg,
@@ -409,13 +476,16 @@ def _window(tr: trace.Tracer) -> dict:
 
     query(500, 400, 390, "device_chain", "explore", "readback_wave", 380)
     span("jax_compile", 600, 50)  # the warm-up's: not the window's
-    query(1000, 130, 120, "xla_cpu_chain", "static", "cpu_chain_wait", 115)
-    query(1200, 108, 100, "xla_cpu_chain", "static", "cpu_chain_wait", 96)
+    first = query(1000, 130, 120, "xla_cpu_chain", "static",
+                  "cpu_chain_wait", 115)
+    second = query(1200, 108, 100, "xla_cpu_chain", "static",
+                   "cpu_chain_wait", 96)
     probe = query(1400, 310, 300, "device_chain", "explore",
                   "readback_wave", 290)
     # two waves under one exec overlap: the union counts, not the sum
     span("readback_wave", 1500, 100, probe.trace_id, probe.span_id)
-    query(1700, 118, 110, "xla_cpu_chain", "static", "cpu_chain_wait", 105)
+    dup = query(1700, 118, 110, "xla_cpu_chain", "static", "cpu_chain_wait",
+                105)
     span("jax_compile", 1705, 4, kind="trace")
     span("jax_compile", 1709, 6, kind="backend_compile")
     # the router changes its mind twice in the large bucket (the second
@@ -428,9 +498,34 @@ def _window(tr: trace.Tracer) -> dict:
     for t0, dur in ((1100, 0.4), (1300, 0.6), (1500, 0.5)):
         span("join", t0, dur, rows_left=110, rows_right=110,
              kernel="host_sort")
-    queries = [{"t0_unix_ns": 1000 * MS, "wall_ms": 140.0},
-               {"t0_unix_ns": 1200 * MS, "wall_ms": 120.0},
-               {"t0_unix_ns": 1400 * MS, "wall_ms": 600.0}]
+    # the third query is the flow graph (the first two the widget): its
+    # probe's sorted aggregates on the device arm (the table's, and the
+    # broker's regroup, which is no scan of the table) and the hedge's
+    # duplicate on the CPU arm (no `engine`: the chain readers above do not
+    # count them); each one's phases lie inside it, the host's key decode
+    # follows it, and every query sends its result
+    span("scan(t)->sorted_agg", 1410, 20, probe.trace_id, probe.span_id,
+         arm="device", groups_out=3, d2h_bytes=4096)
+    span("sort_reduce", 1412, 10, probe.trace_id, probe.span_id)
+    span("compact_readback", 1423, 5, probe.trace_id, probe.span_id)
+    span("remote(ch0)->map->sorted_agg", 1640, 10, probe.trace_id,
+         arm="device", groups_out=2, d2h_bytes=1024)
+    span("sort_reduce", 1641, 4, probe.trace_id)
+    span("scan(t)->sorted_agg", 1710, 30, dup.trace_id, dup.span_id,
+         arm="cpu", groups_out=3, d2h_bytes=4096)
+    span("sort_reduce", 1712, 25, dup.trace_id, dup.span_id)
+    for t0, dur, tid in ((1431, 6.0, probe.trace_id), (1741, 3.0, dup.trace_id)):
+        span("key_decode", t0, dur, tid)
+    for t0, dur, tid in ((1120, 2.0, first.trace_id),
+                         (1300, 9.0, second.trace_id),
+                         (1690, 4.0, probe.trace_id),
+                         (1830, 7.0, dup.trace_id)):
+        span("result_send", t0, dur, tid, chunks=1, bytes=2800000)
+    widget = {"script": "net_flow_by_service", "start_time": 0}
+    queries = [dict(widget, t0_unix_ns=1000 * MS, wall_ms=140.0),
+               dict(widget, t0_unix_ns=1200 * MS, wall_ms=120.0),
+               {"script": "conn_flow_graph", "start_time": 100 * MS,
+                "t0_unix_ns": 1400 * MS, "wall_ms": 600.0}]
     return {"queries": queries, "window_s": 1.0}
 
 
@@ -447,7 +542,18 @@ READERS = {
     # cpu, cpu, (explore), cpu, device, cpu in 4^10; 4^4 stays on its arm
     "router_arm_flips": 2.0,
     "join_ms": 0.5,
+    # of the flow-graph query's two traces (the probe and the hedge's
+    # duplicate) alone: the widget's spans are not read
+    "sorted_agg_host_ms": 9.0,         # two key decodes, one query
+    "d2h_bytes_per_query": 5120.0,     # its two device-arm chains
+    "result_send_ms": 4.0,             # of 4 and 7; the widget sent 2 and 9
+    # the table's and the regroup's on the device arm and the hedge's
+    # duplicate on the CPU arm: 10 + 4 + 25, one query
+    "sort_reduce_ms": 39.0,
 }
+#: the readers of the flow graph's own spans
+FLOW_GRAPH_READERS = ("sorted_agg_host_ms", "d2h_bytes_per_query",
+                      "result_send_ms", "sort_reduce_ms")
 
 
 @pytest.mark.parametrize("name", sorted(READERS))
@@ -464,6 +570,36 @@ def test_reader_returns_none_when_the_ring_wrapped(name, monkeypatch, capsys):
     assert trace.ring_dropped() > 0
     assert _reader(name).read(run) is None
     assert "let go" in capsys.readouterr().err
+
+
+@pytest.mark.parametrize("name", FLOW_GRAPH_READERS)
+def test_flow_graph_reader_reads_nothing_in_a_window_of_widgets(name,
+                                                                fresh_ring):
+    """The same spans under a window none of whose queries is the flow
+    graph (`net_flow_1chip`'s, or the parent's under another traffic): the
+    reader has nothing to read, whatever sorted aggregates, key decodes and
+    sends the window's spans hold."""
+    run = _window(trace.Tracer("pem0"))
+    assert _reader(name).read(run) is not None
+    for q in run["queries"]:
+        q["script"] = "net_flow_by_service"
+    assert _reader(name).read(run) is None
+
+
+def test_flow_graph_readers_need_the_sorted_aggregates_own_spans(fresh_ring):
+    """A program that serves the flow graph with no `groups_out`,
+    `d2h_bytes` or `sort_reduce` (the parent sorts on the host under one
+    unobserved span): three readers are silent, `result_send_ms` reads the
+    flow graph's sends."""
+    tr = trace.Tracer("pem0")
+    run = _window(tr)
+    for s in trace.recent():
+        for k in ("groups_out", "d2h_bytes"):
+            s.attributes.pop(k, None)
+        if s.name == "sort_reduce":
+            s.name = "host_sort"
+    assert [_reader(n).read(run) for n in FLOW_GRAPH_READERS] == [
+        None, None, 4.0, None]
 
 
 def test_readers_read_nothing_where_there_is_nothing(monkeypatch, fresh_ring):
