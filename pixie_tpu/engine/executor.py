@@ -1416,7 +1416,8 @@ class PlanExecutor:
         return _contextlib.nullcontext()
 
     def _note_engine(self, engine: str, rec: Optional[dict] = None,
-                     src=None, kern: Optional["ChainKernel"] = None) -> None:
+                     src=None, kern: Optional["ChainKernel"] = None,
+                     num_groups: Optional[int] = None) -> None:
         """Record which engine ran one of this query's chains, and the
         platform and device_kind its kernels were dispatched to, in
         stats["device"] — so exec_stats, the flight recorder and EXPLAIN
@@ -1430,12 +1431,20 @@ class PlanExecutor:
         decision `src` ran under as the attributes of its trace span, and,
         for a jitted chain over `kern`, how its program applies its LUTs
         (`lut_select`/`lut_gather`: call this inside the chain's device
-        context, where the program is traced)."""
+        context, where the program is traced).  A dense aggregate passes
+        `num_groups`, the span's `groups`; `agg_form` beside it says how
+        its sums and counts reduce them: the host engines scatter, a
+        jitted chain's feeds say theirs as they are dispatched
+        (_agg_feed_loop)."""
         if rec is not None:
             span = rec.setdefault("span", {})  # _feed may have come first
             span.update(engine=engine, **self._route_attrs(src))
             if kern is not None:
                 span.update(kern.lut_forms())
+            if num_groups is not None:
+                span["groups"] = num_groups
+                if engine in ("np_partial", "wholeplan"):
+                    span["agg_form"] = "scatter"
         dev = self.stats.setdefault("device", {})
         engines = dev.setdefault("engines", {})
         engines[engine] = engines.get(engine, 0) + 1
@@ -1452,9 +1461,10 @@ class PlanExecutor:
             dev["platform"], dev["device_kind"] = d.platform, d.device_kind
 
     def _note_chain(self, src, rec: Optional[dict] = None,
-                    kern: Optional["ChainKernel"] = None) -> None:
+                    kern: Optional["ChainKernel"] = None,
+                    num_groups: Optional[int] = None) -> None:
         self._note_engine("xla_cpu_chain" if self._backend_for(src) == "cpu"
-                          else "device_chain", rec, src, kern)
+                          else "device_chain", rec, src, kern, num_groups)
 
     def _route_attrs(self, src) -> dict:
         """What the router decided for a chain over `src`: the arm it ran
@@ -2377,7 +2387,7 @@ class PlanExecutor:
                                        for name, uda, _vi, in_dt in udas})
             rec["rows_out"] = groups
             rec["span"].update(
-                groups_out=groups,
+                agg_form="sorted", groups_out=groups,
                 d2h_bytes=4 + sum(
                     x.nbytes for x in jax.tree.leaves((state, key_cols))))
         self._observe_route(src, rec, compile_s0)
@@ -2650,7 +2660,8 @@ class PlanExecutor:
                         np_partial.value_args(kern, op))
                     self.stats["np_fast_polls"] = self.stats.get(
                         "np_fast_polls", 0) + 1
-                    self._note_engine("np_partial", rec, src)
+                    self._note_engine("np_partial", rec, src,
+                                      num_groups=num_groups)
                 elif (prog := self._wholeplan_program(
                         sig, kern, chain, op, keys, init_specs, dtypes,
                         dicts, names, time_col, src, val_dicts,
@@ -2665,12 +2676,14 @@ class PlanExecutor:
                                             init_specs, t_lo, t_hi, luts)
                     self.stats["wholeplan_native"] = self.stats.get(
                         "wholeplan_native", 0) + 1
-                    self._note_engine("wholeplan", rec, src)
+                    self._note_engine("wholeplan", rec, src,
+                                      num_groups=num_groups)
                 else:
                     if spmd_step is not None:
-                        self._note_engine("device_chain", rec, src, kern)
+                        self._note_engine("device_chain", rec, src, kern,
+                                          num_groups)
                     else:
-                        self._note_chain(src, rec, kern)
+                        self._note_chain(src, rec, kern, num_groups)
                     state_np = self._agg_feed_loop(
                         kern, step, partial_step, merge_fn, spmd_step,
                         init_specs, num_groups,
@@ -2833,6 +2846,23 @@ class PlanExecutor:
         no-feed fallback) materializes identities here.
         """
         state = None
+        span = (self._stat_stack[-1].setdefault("span", {})
+                if self._stat_stack else {})  # the chain's, as in _feed
+        forms: dict = {}  # form -> its largest feed
+
+        def note_form(rows: int) -> None:
+            # A feed of `rows` rows (one shard's, under a mesh) is about to
+            # be dispatched: its sums and counts take the form
+            # ops/groupby.agg_form says, which is what the kernels dispatch
+            # on (call this inside the context the step is traced in).  The
+            # chain's span names the forms of its feeds as `agg_form`,
+            # largest feed first, joined by `+`: a 1,024-row hot remainder
+            # scatters beside a bucket that takes a GEMM.
+            form = _gb.agg_form(rows, num_groups)
+            forms[form] = max(forms.get(form, 0), rows)
+            span["agg_form"] = "+".join(
+                sorted(forms, key=lambda f: -forms[f]))
+
         if kern.has_limit:
             # Limit queries must thread the budgets, so the feed steps chain;
             # the budgets stay a device vector (no per-feed host sync).
@@ -2841,6 +2871,7 @@ class PlanExecutor:
             remaining = kern.init_limits()
             for cols, n_valid in self._feed(
                     src, names, cap, backend=self._backend_for(src)):
+                note_form(_first_len(cols))
                 state, cnt, consumed = step(
                     cols, np.int64(n_valid), t_lo, t_hi, remaining, luts, state
                 )
@@ -2906,6 +2937,7 @@ class PlanExecutor:
                 ctx = (jax.default_device(_cpu_device()) if small_np
                        else _contextlib.nullcontext())
                 with ctx:
+                    note_form(bucket)
                     p = partial_step(cols, np.int64(n_valid), t_lo,
                                      t_hi, luts)
                     if not small_np and backend == "device" \
@@ -2937,6 +2969,7 @@ class PlanExecutor:
                     from pixie_tpu.parallel.spmd import per_shard_valid
 
                     nv = per_shard_valid(n_valid, bucket, n_dev)
+                    note_form(bucket // n_dev)
                     partials.append(spmd_step(cols, nv, t_lo, t_hi, luts))
                     self.stats["spmd_feeds"] = self.stats.get("spmd_feeds", 0) + 1
                     self._note_shard_rows(nv)
@@ -2960,6 +2993,7 @@ class PlanExecutor:
                     fuse_key,
                     {name: uda for name, uda, _dt in init_specs},
                     partial_step)
+                note_form(_first_len(held[0]))
                 finals, rest = fn(held[0], np.int64(held[1]), t_lo, t_hi,
                                   luts)
                 finals_np, rest_np = transfer.pull((finals, rest))

@@ -7,17 +7,22 @@ ints) and multi-key groups are mixed-radix combined into one group id.  What
 reduces the rows of a group depends on the group count and on the platform
 the kernel is traced for (`dispatch_backend`):
 
-  * up to MATMUL_MAX_GROUPS groups on the TPU, sums and counts are one-hot
-    GEMMs on the MXU, chunk by chunk over the live rows (`scan_sum`,
-    `live_chunks`): exact for counts and INT64 (8-bit limbs), F64_SUM_RTOL
-    for f64;
-  * above that, and on every other platform, sums and counts are
-    `jax.ops.segment_sum` and min/max are `jax.ops.segment_min/max` at any
-    group count: scatters into the dense [num_groups] state.  XLA-CPU runs
-    them at memory speed (0.007 us a row a scatter); the TPU serializes
-    them (0.104 us a row on a v5e: PERF.md section 6, PR 36's kernels
-    alone), and the dense state is initialised, read back and searched for
-    its seen groups whatever its occupancy;
+  * on the TPU, sums and counts over a dense space are one-hot GEMMs on
+    the MXU, chunk by chunk over the live rows (`scan_sum`, `live_chunks`):
+    exact for counts and INT64 (8-bit limbs), F64_SUM_RTOL for f64.  Up to
+    MATMUL_MAX_GROUPS slots the one-hot is flat (a chunk's rows x slots);
+    from there to ONEHOT2_MAX_GROUPS it is factored by the id's own two
+    digits (`_onehot2_gemm`: both operands narrow, the same sums cell for
+    cell); `agg_form` says which, from the slot count, the rows and the
+    platform alone;
+  * past ONEHOT2_MAX_GROUPS, and on every other platform at any group
+    count, sums and counts are `jax.ops.segment_sum`; min/max are
+    `jax.ops.segment_min/max` everywhere: scatters into the dense
+    [num_groups] state.  XLA-CPU runs them at memory speed (0.007 us a row
+    a scatter); the TPU serializes them (0.104 us a row on a v5e: PERF.md
+    section 6, PR 36's kernels alone; a dense min/max past 1,024 slots
+    still pays that there), and the dense state is initialised, read back
+    and searched for its seen groups whatever its occupancy;
   * where the group space is sparse (more slots than rows, or no dense
     code at all: float keys, computed keys, a space past the executor's
     MAX_GROUPS) the rows are sorted by their keys instead (`sort_order`),
@@ -30,6 +35,7 @@ the kernel is traced for (`dispatch_backend`):
 """
 from __future__ import annotations
 
+import math
 from typing import NamedTuple, Optional
 
 import jax
@@ -72,9 +78,22 @@ def split_codes(gids: np.ndarray, cards: list[int]) -> list[np.ndarray]:
     return list(reversed(out))
 
 
-#: Matmul-lowered segment sums are used on TPU up to this group count; the
+#: Three forms reduce a dense space's sums and counts on the TPU
+#: (`agg_form`).  Up to this group count the one-hot GEMM is flat; its
 #: one-hot chunk buffer is CHUNK_ROWS × groups × 4B (≤ 256 MB at the cap).
 MATMUL_MAX_GROUPS = 1 << 10
+#: From there up to this group count the one-hot GEMM is factored by the
+#: group id's two digits (`_onehot2_gemm`); past it sums and counts scatter.
+#: The executor's SPARSE_MIN_GROUPS: a space past it with fewer rows than
+#: slots sorts, and no denser one has been measured.  Read on a v5e at the
+#: scan cell's shapes (8,388,608-row bucket, 4,587,520 live; count / f64 sum
+#: / INT64 sum, ms a call; PERF.md section 6, PR 37's kernels alone):
+#: `segment_sum` 594 / 631 / 595 at 2,048 slots and 893 / 933 / 894 at
+#: 65,536; the flat one-hot at 2,048 slots (512 MB a chunk) 7.5 / 15.6 / 9.2;
+#: the factored one 1.3 / 4.8 / 4.0 at 2,048, 9.8 / 10.7 / 11.8 at 8,192,
+#: 10.4 / 11.7 / 13.3 at 16,384 and 9.7 / 37.5 / 43.3 at 65,536, each at
+#: `onehot2_lo`'s width; a first call compiles in 0.4-4.4 s.
+ONEHOT2_MAX_GROUPS = 1 << 16
 #: Documented bound: an f64 group sum (and so a mean) from the one-hot GEMM
 #: agrees with an exact f64 sum to this relative tolerance.  chip_smoke.py
 #: holds every f64 mean it reads on the chip to it.
@@ -111,13 +130,38 @@ def encode_against(lut: jax.Array, values: jax.Array) -> jax.Array:
     return jnp.searchsorted(lut, values).astype(jnp.int32)
 
 
+def agg_form(n: int, num_groups: int) -> str:
+    """The form in which `masked_segment_sum` and `masked_segment_count`,
+    traced now, reduce `n` rows into a dense space of `num_groups` slots:
+    `onehot` (the flat one-hot GEMM), `onehot2` (the factored one) or
+    `scatter` (`jax.ops.segment_sum`).  It reads the slot count, the rows
+    and the platform the kernel is traced for and nothing else; the
+    kernels dispatch on it and the executor writes it on the chain's span
+    (`agg_form`), so the span says what the program does."""
+    if (dispatch_backend() != "tpu" or n < 4096
+            or n % min(n, CHUNK_ROWS) or num_groups > ONEHOT2_MAX_GROUPS):
+        return "scatter"
+    return "onehot" if num_groups <= MATMUL_MAX_GROUPS else "onehot2"
+
+
 def _use_matmul(n: int, num_groups: int) -> bool:
-    return (
-        dispatch_backend() == "tpu"
-        and num_groups <= MATMUL_MAX_GROUPS
-        and n >= 4096
-        and (n % min(n, CHUNK_ROWS)) == 0
-    )
+    return agg_form(n, num_groups) != "scatter"
+
+
+def onehot2_lo(num_groups: int) -> int:
+    """The width of the factored one-hot's low digit: the power of two at
+    or above sqrt(2 * num_groups), so that the one-hot operand is about
+    twice as wide as a lane's rows of the other are many.  The chip's
+    readings, not a model (ms a call, f64 sum / INT64 sum, as
+    ONEHOT2_MAX_GROUPS' comment): at 2,048 slots 64 wide 4.8 / 4.0 against
+    7.6 / 6.4 at 32 and 6.7 / 8.3 at 128; at 8,192 128 wide 10.7 / 11.8
+    against 21.3 / 28.3 at 64; at 16,384 256 wide 11.7 / 13.3 against 24.8
+    / 28.2 at 128 and 9.8 / 16.1 at 512; at 65,536 512 wide 37.5 / 43.3
+    against 66.0 / 56.2 at 256 and 224 / 208 at 64.  (A count alone reads
+    1.3-2.7 ms at 64 wide and 9.5-10.4 at any wider width tried, whatever
+    the slot count: unexplained, and under the sums' difference past 4,096
+    slots.)"""
+    return next_pow2(math.isqrt(2 * num_groups))
 
 
 def _chunked_onehot_sum(v32: jax.Array, gid: jax.Array, num_groups: int,
@@ -204,6 +248,8 @@ def _chunked_onehot_multi_sum(lanes_fn, v, gid: jax.Array, num_groups: int,
     chunk); stacking all L lanes into ONE [L,CH] @ [CH,G] GEMM builds it
     once instead of L times — the 8-limb exact-int64 sum was measured
     HBM-bound on exactly this (8 one-hot rebuilds per column per chunk).
+    Past MATMUL_MAX_GROUPS slots (`agg_form`) the chunk's GEMM is the
+    factored one (`_onehot2_gemm`), the same sums from two narrow operands.
     `precision`: None for bf16-exact lanes, Precision.HIGHEST for float
     lanes (_lanes_gemm).
 
@@ -216,14 +262,49 @@ def _chunked_onehot_multi_sum(lanes_fn, v, gid: jax.Array, num_groups: int,
     n = v.shape[0]
     ch = min(n, CHUNK_ROWS)
     c = n // ch
+    factored = agg_form(n, num_groups) == "onehot2"
 
     def chunk(xs):
         vv, gg = xs
+        if factored:
+            return _onehot2_gemm(lanes_fn(vv), gg, num_groups, precision)
         oh = jax.nn.one_hot(gg, num_groups, dtype=jnp.float32)
         return _lanes_gemm(lanes_fn(vv), oh, precision)
 
     return scan_sum(chunk, (v.reshape(c, ch), gid.reshape(c, ch)),
                     *live_chunks(mask, ch))
+
+
+def _onehot2_gemm(lanes: jax.Array, gg: jax.Array, num_groups: int,
+                  precision) -> jax.Array:
+    """[L, CH] f32 lanes and the chunk's [CH] group ids → [L, G] f64, the
+    flat one-hot GEMM's sums with both operands narrow.  The id's own two
+    digits, gid = hi * R + lo (R = `onehot2_lo(G)`, hi < C1 = ceil(G / R)):
+
+        A[l * C1 + hi(r), r] = lanes[l, r]    a lane's value at the row's
+                                              high digit, 0 in the other
+                                              C1 - 1 rows
+        B[r, lo(r)]          = 1              [CH, R]
+        (A @ B)[l * C1 + h, j] = sum of lanes[l, r] over the rows r of
+                                 group h * R + j
+
+    CH * (L * C1 + R) operand elements a chunk where the flat one-hot has
+    CH * G.  Every output cell sums exactly the values the flat one-hot's
+    cell of that group would, through the same `_lanes_gemm`: an entry of
+    A is a lane's value or a zero, so limb and mask lanes stay bf16-exact
+    and a chunk's sum exact in f32, float lanes keep Precision.HIGHEST and
+    their FLOAT_RUN_ROWS runs; the exactness arguments of `_lanes_gemm`,
+    `_chunked_onehot_multi_sum` and `masked_segment_sum` hold word for
+    word.  An id outside [0, G) meets no row of A or a column past G, as it
+    meets no column of the flat one-hot."""
+    n_lanes, ch = lanes.shape
+    r = onehot2_lo(num_groups)
+    c1 = -(-num_groups // r)
+    at_hi = (gg // r)[None, :] == jnp.arange(c1, dtype=jnp.int32)[:, None]
+    a = jnp.where(at_hi[None], lanes[:, None, :], jnp.float32(0))
+    b = jax.nn.one_hot(gg % r, r, dtype=jnp.float32)
+    s = _lanes_gemm(a.reshape(n_lanes * c1, ch), b, precision)
+    return s.reshape(n_lanes, c1 * r)[:, :num_groups]
 
 
 class SortedRuns(NamedTuple):
@@ -388,8 +469,9 @@ def masked_segment_sum(values: jax.Array, gid: jax.Array, num_groups: int, mask:
 
 @jax.named_scope("px.groupby_count")
 def masked_segment_count(gid: jax.Array, num_groups: int, mask: jax.Array) -> jax.Array:
-    """Rows per group (int64, exact): f32 one-hot matmul of the mask on TPU
-    (per-chunk counts ≤ CHUNK_ROWS are exact in f32), scatter elsewhere."""
+    """Rows per group (int64, exact): f32 one-hot matmul of the mask on TPU,
+    flat or factored (per-chunk counts ≤ CHUNK_ROWS are exact in f32),
+    scatter elsewhere and past ONEHOT2_MAX_GROUPS (`agg_form`)."""
     if isinstance(gid, SortedRuns):
         return _run_reduce(mask.astype(jnp.int64), gid, jnp.add)
     n = gid.shape[0]

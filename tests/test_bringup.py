@@ -289,36 +289,70 @@ def test_native_build_failure_with_gxx_present_is_an_error(
 
 
 # --------------------------------------- TPU formulations, traced on XLA-CPU
-def test_tpu_groupby_formulations_trace_and_agree(monkeypatch):
+@pytest.mark.parametrize("groups", [48, 2048, 1500, 5000])
+def test_tpu_groupby_formulations_trace_and_agree(monkeypatch, groups):
     """The one-hot limb-GEMM group-by only engages when the dispatch
     platform is "tpu"; trace it on XLA-CPU against the scatter form so a
     jax upgrade cannot break it unseen (its TPU arithmetic — bf16 operand
-    rounding unless Precision.HIGHEST — is checked by chip_smoke.py)."""
+    rounding unless Precision.HIGHEST — is checked by chip_smoke.py).  Up
+    to MATMUL_MAX_GROUPS slots it is the flat one-hot, past them the
+    factored one, whose last high digit is partly empty where the slot
+    count is no multiple of its low digit's width (`onehot2_lo`: 64 at
+    1,500 and 2,048 slots, 128 at 5,000)."""
     import jax
 
     from pixie_tpu.ops import groupby as gb
 
     rng = np.random.default_rng(3)
-    n, groups = 1 << 17, 48
+    n = 1 << 17
     gid = rng.integers(0, groups, n).astype(np.int32)
     mask = rng.random(n) < 0.9
     cases = {"i64": rng.integers(-(1 << 40), 1 << 40, n),
+             "bool": rng.random(n) < 0.3,
              "f64": rng.exponential(50.0, n)}
-    want = {k: np.asarray(gb.masked_segment_sum(v, gid, groups, mask))
-            for k, v in cases.items()}
+    # (the scatter takes no bool: the planner hands it the widened column)
+    want = {k: np.asarray(gb.masked_segment_sum(
+        v.astype(np.int64) if k == "bool" else v, gid, groups, mask))
+        for k, v in cases.items()}
     want_cnt = np.asarray(gb.masked_segment_count(gid, groups, mask))
     monkeypatch.setattr(gb, "dispatch_backend", lambda: "tpu")
-    assert gb._use_matmul(n, groups)
+    assert gb.agg_form(n, groups) == (
+        "onehot" if groups <= gb.MATMUL_MAX_GROUPS else "onehot2")
     got_cnt = jax.jit(lambda g, m: gb.masked_segment_count(g, groups, m))(
         gid, mask)
     np.testing.assert_array_equal(np.asarray(got_cnt), want_cnt)
     for k, v in cases.items():
-        got = jax.jit(lambda v, g, m: gb.masked_segment_sum(
-            v, g, groups, m))(v, gid, mask)
-        if k == "i64":
-            np.testing.assert_array_equal(np.asarray(got), want[k])
+        got = np.asarray(jax.jit(lambda v, g, m: gb.masked_segment_sum(
+            v, g, groups, m))(v, gid, mask))
+        assert got.dtype == want[k].dtype and got.shape == (groups,)
+        if k == "f64":
+            np.testing.assert_allclose(got, want[k], rtol=1e-6)
         else:
-            np.testing.assert_allclose(np.asarray(got), want[k], rtol=1e-6)
+            np.testing.assert_array_equal(got, want[k])
+
+
+def test_agg_form_reads_slots_rows_and_platform(monkeypatch):
+    """The form function alone (no traced run: at the cap the operands
+    would be heavy on XLA-CPU): the flat one-hot up to MATMUL_MAX_GROUPS,
+    the factored one from the next slot to ONEHOT2_MAX_GROUPS, the scatter
+    one slot past it, under `_use_matmul`'s floor of rows and on every
+    platform but the TPU."""
+    from pixie_tpu.ops import groupby as gb
+
+    n = 1 << 20
+    assert gb.agg_form(n, 2048) == "scatter"  # this process traces for XLA-CPU
+    monkeypatch.setattr(gb, "dispatch_backend", lambda: "tpu")
+    assert gb.agg_form(n, 1) == gb.agg_form(n, gb.MATMUL_MAX_GROUPS) == "onehot"
+    assert gb.agg_form(n, gb.MATMUL_MAX_GROUPS + 1) == "onehot2"
+    assert gb.agg_form(n, gb.ONEHOT2_MAX_GROUPS) == "onehot2"
+    assert gb.agg_form(n, gb.ONEHOT2_MAX_GROUPS + 1) == "scatter"
+    assert gb.agg_form(4096, 2048) == "onehot2"
+    assert gb.agg_form(1024, 2048) == gb.agg_form(1024, 48) == "scatter"
+    assert gb.agg_form(gb.CHUNK_ROWS + 4096, 2048) == "scatter"  # no whole chunks
+    for rows, slots in ((n, 48), (n, 2048), (1024, 2048),
+                        (n, gb.ONEHOT2_MAX_GROUPS + 1)):
+        assert gb._use_matmul(rows, slots) == (
+            gb.agg_form(rows, slots) != "scatter")
 
 
 def _count(v, g, m, groups):
@@ -367,21 +401,28 @@ def test_live_chunks_is_the_range_of_chunks_with_a_live_row():
     assert int(got) == int(xs[2:5].sum())
 
 
+#: (where the live rows sit, slots): every range under the flat one-hot; a
+#: masked head and tail under the factored one, whole high digits and a
+#: partly empty last one
+LOOP_CASES = [(live, 48) for live in LIVE_RANGES] + [
+    ("middle", 2048), ("middle", 1500)]
+
+
 @pytest.mark.parametrize("kernel", list(LOOP_KERNELS))
-@pytest.mark.parametrize("live", list(LIVE_RANGES))
+@pytest.mark.parametrize("live,groups", LOOP_CASES)
 def test_chunk_loop_over_live_chunks_is_the_loop_over_all(
-        monkeypatch, kernel, live):
-    """The one-hot GEMM group-by visits the chunks that hold a live row and
-    no other.  Wherever the live rows sit in the pow2 bucket, its answer is
-    the answer of the loop over every chunk bit for bit, and the scatter
-    formulation's (exactly for counts and integers, to F64_SUM_RTOL for
-    float sums)."""
+        monkeypatch, kernel, live, groups):
+    """The one-hot GEMM group-by, flat or factored, visits the chunks that
+    hold a live row and no other.  Wherever the live rows sit in the pow2
+    bucket, its answer is the answer of the loop over every chunk bit for
+    bit, and the scatter formulation's (exactly for counts and integers, to
+    F64_SUM_RTOL for float sums)."""
     import jax
 
     from pixie_tpu.ops import groupby as gb
     from pixie_tpu.testing.live_chunks import live_mask, scan_every_chunk
 
-    c, groups = 8, 48
+    c = 8
     n = c * gb.CHUNK_ROWS
     rng = np.random.default_rng(7)
     gid = rng.integers(0, groups, n).astype(np.int32)
@@ -396,7 +437,8 @@ def test_chunk_loop_over_live_chunks_is_the_loop_over_all(
     want = np.asarray(fn(v.astype(np.int64) if kernel == "bool" else v,
                          gid, mask))
     monkeypatch.setattr(gb, "dispatch_backend", lambda: "tpu")
-    assert gb._use_matmul(n, groups)
+    assert gb.agg_form(n, groups) == (
+        "onehot" if groups <= gb.MATMUL_MAX_GROUPS else "onehot2")
     got = np.asarray(jax.jit(fn)(v, gid, mask))
     monkeypatch.setattr(gb, "scan_sum", scan_every_chunk)
     # (a new callable: jit would hand back fn's program, traced before)
@@ -412,11 +454,16 @@ def test_chunk_loop_over_live_chunks_is_the_loop_over_all(
         np.testing.assert_array_equal(got, want)
 
 
-def test_chunk_loops_walk_each_shards_own_range_under_shard_map(monkeypatch):
+@pytest.mark.parametrize("groups", [16, 2048, 1500])
+def test_chunk_loops_walk_each_shards_own_range_under_shard_map(
+        monkeypatch, groups):
     """Under `jax.shard_map` every shard derives its own live range: the
     loop's bounds vary over the mesh axis as its carry does.  Eight shards,
     one live range each, every chunk-loop kernel: each shard's answer is
-    the single-device scatter formulation's of its rows."""
+    the single-device scatter formulation's of its rows.  Past
+    MATMUL_MAX_GROUPS slots the group-by kernels are the factored one-hot
+    (what `Agent(n_devices=4)` traces for a windowed chart on a chip); the
+    sketch has its own form and cap and stays at 16."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
@@ -427,7 +474,7 @@ def test_chunk_loops_walk_each_shards_own_range_under_shard_map(monkeypatch):
     from pixie_tpu.testing.live_chunks import live_mask
 
     lh = LogHistogram()
-    c, groups = 8, 16
+    c = 8
     ch = lh.CHUNK
     per = c * ch
     names = list(LIVE_RANGES)
@@ -436,13 +483,15 @@ def test_chunk_loops_walk_each_shards_own_range_under_shard_map(monkeypatch):
     gid = rng.integers(0, groups, (len(names), per)).astype(np.int32)
     mask = np.stack([live_mask(name, c, ch, seed=i)
                      for i, name in enumerate(names)])
-    kernels = dict(LOOP_KERNELS, sketch=(
-        lambda rng, n: rng.exponential(50.0, n),
-        lambda v, g, m, groups: lh._update_gemm(
-            lh.init(groups), g, lh.bin_index(v), m, groups)))
-    scatter = dict(kernels, sketch=(
-        None, lambda v, g, m, groups: lh._update_segment(
-            lh.init(groups), g, lh.bin_index(v), m, groups)))
+    kernels, scatter = dict(LOOP_KERNELS), dict(LOOP_KERNELS)
+    if groups <= gb.MATMUL_MAX_GROUPS:
+        kernels["sketch"] = (
+            lambda rng, n: rng.exponential(50.0, n),
+            lambda v, g, m, groups: lh._update_gemm(
+                lh.init(groups), g, lh.bin_index(v), m, groups))
+        scatter["sketch"] = (
+            None, lambda v, g, m, groups: lh._update_segment(
+                lh.init(groups), g, lh.bin_index(v), m, groups))
     vals = {k: values(rng, len(names) * per).reshape(len(names), per)
             for k, (values, _fn) in kernels.items()}
     want = {k: [np.asarray(fn(
@@ -451,7 +500,8 @@ def test_chunk_loops_walk_each_shards_own_range_under_shard_map(monkeypatch):
         for k, (_values, fn) in scatter.items()}
     monkeypatch.setattr(gb, "dispatch_backend", lambda: "tpu")
     monkeypatch.setattr(gb, "CHUNK_ROWS", ch)
-    assert gb._use_matmul(per, groups)
+    assert gb.agg_form(per, groups) == (
+        "onehot" if groups <= gb.MATMUL_MAX_GROUPS else "onehot2")
     for k, (_values, fn) in kernels.items():
         f = jax.jit(shard_map(
             lambda v, g, m: fn(v[0], g[0], m[0], groups)[None], mesh=mesh,
@@ -467,27 +517,58 @@ def test_chunk_loops_walk_each_shards_own_range_under_shard_map(monkeypatch):
                     got[i], want[k][i], err_msg=f"{k} {name}")
 
 
-def test_tpu_formulations_trace_under_shard_map(monkeypatch):
+#: 512 windows of 512 ns over `_store`'s 2^18 rows x 4 services: 2,048 slots
+WINDOWED_SCRIPT = """
+df = px.DataFrame(table='http_events')
+df = df[df.status != 404]
+df.time_ = px.bin(df.time_, 512)
+df = df.groupby(['time_', 'service']).agg(cnt=('latency', px.count),
+                                          avg=('latency', px.mean))
+px.display(df, 'out')
+"""
+
+
+@pytest.mark.parametrize("script,form,exact,close", [
+    (SCRIPT, "onehot", ["cnt", "p50"], []),
+    (WINDOWED_SCRIPT, "onehot2", ["cnt"], ["avg"]),
+], ids=["by_service", "windowed"])
+def test_tpu_formulations_trace_under_shard_map(monkeypatch, script, form,
+                                                exact, close):
     """On a chip the SPMD partial step traces the one-hot GEMM group-by and
     the limb-factored sketch GEMM INSIDE `jax.shard_map`, whose scans must
     keep their carry's varying-axes type (the first 4-chip run died on
     exactly that).  Trace them over the virtual CPU mesh, two chunks per
-    shard, against the single-device scatter formulations."""
+    shard, against the single-device scatter formulations: four slots
+    under the flat one-hot, a windowed chart's 2,048 under the factored
+    one; the chain's frame says the form its shards traced."""
+    from pixie_tpu.compiler import compile_pxl
     from pixie_tpu.engine.executor import PlanExecutor
     from pixie_tpu.ops import groupby as gb
     from pixie_tpu.parallel.spmd import make_mesh
 
-    ts = _store(rows=1 << 18)
-    plain = PlanExecutor(_plan(ts), ts, mesh=None, force_backend="device")
-    want = plain.run()["out"].to_pandas().sort_values("service")
+    def run(mesh):
+        # fresh store: the kernel cache must not hand back another form
+        ts = _store(rows=1 << 18)
+        ex = PlanExecutor(compile_pxl(script, ts.schemas()).plan, ts,
+                          mesh=mesh, force_backend="device")
+        out = ex.run()["out"].to_pandas()
+        keys = [c for c in ("time_", "service") if c in out]
+        return ex, out.sort_values(keys).reset_index(drop=True)
+
+    plain, want = run(None)
     monkeypatch.setattr(gb, "dispatch_backend", lambda: "tpu")
-    # fresh store: the kernel cache must not hand back the scatter kernels
-    ts2 = _store(rows=1 << 18)
-    ex = PlanExecutor(_plan(ts2), ts2, mesh=make_mesh(2),
-                      force_backend="device")
-    got = ex.run()["out"].to_pandas().sort_values("service")
+    ex, got = run(make_mesh(2))
     assert ex.stats["spmd_feeds"] >= 1
-    np.testing.assert_array_equal(got["cnt"].to_numpy(),
-                                  want["cnt"].to_numpy())
-    np.testing.assert_array_equal(got["p50"].to_numpy(),
-                                  want["p50"].to_numpy())
+    (span,) = [r["span"] for r in ex.op_stats if "agg_form" in r.get("span", {})]
+    (scatter,) = [r["span"] for r in plain.op_stats
+                  if "agg_form" in r.get("span", {})]
+    assert span["agg_form"] == form and scatter["agg_form"] == "scatter"
+    assert span["groups"] == scatter["groups"] == (
+        4 if form == "onehot" else 2048)
+    assert len(got) == len(want)
+    for col in exact:
+        np.testing.assert_array_equal(got[col].to_numpy(),
+                                      want[col].to_numpy())
+    for col in close:
+        np.testing.assert_allclose(got[col].to_numpy(), want[col].to_numpy(),
+                                   rtol=gb.F64_SUM_RTOL)

@@ -51,13 +51,12 @@ class OtlpCollector:
                 for s in ss["spans"]]
 
 
-def _mkstore(seed: int, now_ns: int) -> TableStore:
+def _mkstore(seed: int, now_ns: int, n: int = 3000) -> TableStore:
     ts = TableStore()
     rel = Relation.of(("time_", DT.TIME64NS), ("service", DT.STRING),
                       ("latency", DT.INT64))
     t = ts.create("http_events", rel, batch_rows=512)
     rng = np.random.default_rng(seed)
-    n = 3000
     t.write({
         "time_": now_ns - np.arange(n, dtype=np.int64)[::-1] * 1_000_000,
         "service": rng.choice(["a", "b"], n).tolist(),
