@@ -394,6 +394,13 @@ def _src_rows(src) -> Optional[int]:
         return None
 
 
+def _shard_skew(rows) -> float:
+    """Max over mean of the valid rows the mesh's shards were handed; 1.0
+    is even, and what no rows at all read."""
+    mean = sum(rows) / max(len(rows), 1)
+    return float(max(rows) / mean) if mean > 0 else 1.0
+
+
 def _route_backend(src, scale: int = 1) -> str:
     """Route for this input: "cpu" (the host engines and XLA-CPU) or
     "device" (the process's JAX default device / mesh — WHICH platform that
@@ -1623,8 +1630,7 @@ class PlanExecutor:
             acc = [0] * len(rows)
         acc = [a + r for a, r in zip(acc, rows)]
         self.stats["shard_rows"] = acc
-        mean = sum(acc) / max(len(acc), 1)
-        skew = (max(acc) / mean) if mean > 0 else 1.0
+        skew = _shard_skew(acc)
         self.stats["shard_skew_frac"] = round(skew, 4)
         from pixie_tpu import metrics as _metrics
 
@@ -2488,7 +2494,8 @@ class PlanExecutor:
         dtypes, dicts, names, visible, chain = _prune_to_needed(
             head, chain, dtypes, dicts, names, visible, time_col, needed,
         )
-        self._name_route(src, head, chain, op)  # _agg_state observes it
+        if self.mesh is None:  # the mesh serves the chain: nothing to route
+            self._name_route(src, head, chain, op)  # _agg_state observes it
 
         # Agg kernels bake data-dependent key sets (intdevice uniques, window
         # origins) unless every group key is dictionary-backed; cover that with
@@ -2680,8 +2687,12 @@ class PlanExecutor:
                                       num_groups=num_groups)
                 else:
                     if spmd_step is not None:
+                        # no router took this decision (_agg_setup gave the
+                        # chain no key): the span says what ran
                         self._note_engine("device_chain", rec, src, kern,
                                           num_groups)
+                        rec["span"].update(arm="device",
+                                           mesh_devices=self.mesh.size)
                     else:
                         self._note_chain(src, rec, kern, num_groups)
                     state_np = self._agg_feed_loop(
@@ -2889,6 +2900,7 @@ class PlanExecutor:
             # PEM-partial → Kelvin-finalize, but over ICI).
             partials = []
             n_dev = self.mesh.size if self.mesh is not None else 1
+            shard_rows = np.zeros(n_dev, np.int64)  # this chain's, a shard
             backend = ("device" if spmd_step is not None
                        else self._backend_for(src))
             # Accelerator-backend feeds normally end in a DEVICE merge (+
@@ -2973,6 +2985,12 @@ class PlanExecutor:
                     partials.append(spmd_step(cols, nv, t_lo, t_hi, luts))
                     self.stats["spmd_feeds"] = self.stats.get("spmd_feeds", 0) + 1
                     self._note_shard_rows(nv)
+                    # the same two on the chain's span: the SPMD executions
+                    # it dispatched, and max over mean of the valid rows a
+                    # shard was handed over all of them (1.0 is even)
+                    shard_rows += nv
+                    span["spmd_feeds"] = span.get("spmd_feeds", 0) + 1
+                    span["shard_skew"] = round(_shard_skew(shard_rows), 4)
                 else:
                     dispatch_plain(cols, n_valid)
                 if self.analyze:
@@ -3022,41 +3040,47 @@ class PlanExecutor:
                     if not dev:
                         return host_state
                     return _DeferredState(dev, merge_fn, host_state)
-                if device_merge_ok:
-                    # ONE device execution merges every per-feed partial and
-                    # finalizes large-state UDAs (sketch → quantiles) in
-                    # place, so the readback wave carries kilobytes of
-                    # answers instead of megabytes of state (reference
-                    # bar: zero-copy batch handoff,
-                    # exec_graph.cc:177-260).
-                    udas_by_name = {name: uda
-                                    for name, uda, _dt in init_specs}
-                    rt = {name: uda.reduce_ops()
-                          for name, uda, _dt in init_specs}
-                    # the distributed partial path ships RAW state (it must
-                    # stay mergeable across agents): device-merge the feeds
-                    # but never finalize
-                    fin_ok = not getattr(self, "_partial_wire", False)
-                    spec_key = ("mfz", fin_ok, tuple(
-                        (name, type(uda).__qualname__,
-                         getattr(uda, "q", None))
-                        for name, uda, _dt in init_specs))
-                    finals, rest = _merge_finalize_fn(
-                        spec_key, rt, udas_by_name,
-                        finalize_ok=fin_ok)(*partials)
-                    finals_np, rest_np = transfer.pull((finals, rest))
-                    out = dict(rest_np)
-                    for k, v in finals_np.items():
-                        out[k] = _FinalizedCol(v)
-                    return out
-                pulled = transfer.pull(
-                    [p.buf if isinstance(p, _PackedState) else p
-                     for p in partials])
-                states = [
-                    p.unpack(buf) if isinstance(p, _PackedState) else buf
-                    for p, buf in zip(partials, pulled)
-                ]
-                return merge_fn(*states)
+                # under a mesh each feed's state is already merged across
+                # the shards in its program (replicated): what is left, the
+                # merge of the feeds' states and its read-back, has a span
+                # of its own, so "more than one feed" has a cost by name
+                with (self._timed("mesh_merge", []) if spmd_step is not None
+                      else _contextlib.nullcontext()):
+                    if device_merge_ok:
+                        # ONE device execution merges every per-feed partial
+                        # and finalizes large-state UDAs (sketch → quantiles)
+                        # in place, so the readback wave carries kilobytes of
+                        # answers instead of megabytes of state (reference
+                        # bar: zero-copy batch handoff,
+                        # exec_graph.cc:177-260).
+                        udas_by_name = {name: uda
+                                        for name, uda, _dt in init_specs}
+                        rt = {name: uda.reduce_ops()
+                              for name, uda, _dt in init_specs}
+                        # the distributed partial path ships RAW state (it must
+                        # stay mergeable across agents): device-merge the feeds
+                        # but never finalize
+                        fin_ok = not getattr(self, "_partial_wire", False)
+                        spec_key = ("mfz", fin_ok, tuple(
+                            (name, type(uda).__qualname__,
+                             getattr(uda, "q", None))
+                            for name, uda, _dt in init_specs))
+                        finals, rest = _merge_finalize_fn(
+                            spec_key, rt, udas_by_name,
+                            finalize_ok=fin_ok)(*partials)
+                        finals_np, rest_np = transfer.pull((finals, rest))
+                        out = dict(rest_np)
+                        for k, v in finals_np.items():
+                            out[k] = _FinalizedCol(v)
+                        return out
+                    pulled = transfer.pull(
+                        [p.buf if isinstance(p, _PackedState) else p
+                         for p in partials])
+                    states = [
+                        p.unpack(buf) if isinstance(p, _PackedState) else buf
+                        for p, buf in zip(partials, pulled)
+                    ]
+                    return merge_fn(*states)
 
         if state is None:  # no feeds at all: identity state
             state = {name: uda.init(num_groups, in_dt)

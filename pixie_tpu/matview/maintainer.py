@@ -174,11 +174,14 @@ _FOLD_STAT_KEYS = ("device", "autotune", "resident_feeds", "h2d_bytes",
 class MatViewManager:
     """Standing views over ONE table store (one agent's data)."""
 
-    def __init__(self, store, registry=None):
+    def __init__(self, store, registry=None, mesh="auto"):
         if registry is None:
             from pixie_tpu.udf import registry as registry  # noqa: PLW0127
         self.store = store
         self.registry = registry
+        #: the mesh the cron tick's refreshes run on (`serve` is handed its
+        #: caller's)
+        self.mesh = mesh
         self._views: dict[str, StandingView] = {}
         self._lock = threading.Lock()
         self._ticker = None
@@ -522,7 +525,7 @@ class MatViewManager:
         for view in views:
             table = self._resolve_table(view.prefix.head)
             with view.lock:
-                info = (self._refresh_locked(view, table)
+                info = (self._refresh_locked(view, table, mesh=self.mesh)
                         if table is not None else None)
                 if info is None:
                     with self._lock:

@@ -93,6 +93,15 @@ class Agent:
         self.registry = registry
         self.heartbeat_s = heartbeat_s
         self.n_devices = n_devices
+        #: the mesh this agent's executors shard their feeds over: the first
+        #: `n_devices` local devices (fewer fails here, at start-up, with
+        #: make_mesh's message; 1 = none), or with None the executor's own
+        #: "auto", every local device
+        self.mesh = "auto"
+        if n_devices is not None:
+            from pixie_tpu.parallel.spmd import make_mesh
+
+            self.mesh = make_mesh(n_devices) if n_devices > 1 else None
         self.conn: Optional[Connection] = None
         self.asid: Optional[int] = None
         self._registered = threading.Event()
@@ -116,7 +125,7 @@ class Agent:
         #: standing materialized views over this agent's store: repeated
         #: scan→filter→map→partial-agg plans answer from incrementally
         #: refreshed state instead of rescanning (pixie_tpu.matview)
-        self.matviews = MatViewManager(self.store, registry)
+        self.matviews = MatViewManager(self.store, registry, mesh=self.mesh)
         #: req_id → in-flight window semaphore; chunk_ack frames release it
         self._windows: dict[str, threading.Semaphore] = {}
         self._windows_lock = threading.Lock()
@@ -587,7 +596,7 @@ class Agent:
                 if not meta.get("analyze") and not serve_for:
                     served = self.matviews.serve(
                         plan, route_scale=int(meta.get("route_scale", 1)),
-                        tenant=str(meta.get("tenant") or ""),
+                        mesh=self.mesh, tenant=str(meta.get("tenant") or ""),
                         stale_ok=bool(meta.get("stale_ok")))
                 if served is not None:
                     cid, pb, mv_info = served
@@ -600,6 +609,7 @@ class Agent:
                         plan, exec_store, self.registry,
                         analyze=bool(meta.get("analyze", False)),
                         route_scale=int(meta.get("route_scale", 1)),
+                        mesh=self.mesh,
                     )
                     stream = ex.run_agent_stream(
                         agg_chunk_groups=int(

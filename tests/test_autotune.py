@@ -291,7 +291,8 @@ def test_sample_excludes_compile_seconds():
     plan = compile_pxl(src, store.schemas()).plan
 
     def run():
-        ex = PlanExecutor(plan, store)
+        # no mesh: a chain a mesh serves takes no routing decision (PR 38)
+        ex = PlanExecutor(plan, store, mesh=None)
         ex.run()
         dec = next(d for d in ex.stats["autotune"]
                    if d["gate"] == GATE_CPU_CROSSOVER)
@@ -416,7 +417,9 @@ def test_autotune_off_is_bit_identical_and_silent():
     """PX_AUTOTUNE=0 removes every model read AND write; with the flag on,
     decisions appear in stats and the answers stay BIT-equal."""
     stores = {f"pem{i}": mkstore(i, 8_000) for i in range(2)}
-    cluster = LocalCluster(stores)
+    # one device an agent: a mesh would serve the chains and no router
+    # would decide them (PR 38)
+    cluster = LocalCluster(stores, n_devices_per_agent=1)
     # standing matviews would serve every repeat from cached fragments
     # and the routing gate would never run — the gate is what's under test
     flags.set_for_testing("PL_MATVIEW_ENABLED", False)
