@@ -8,8 +8,8 @@ all string work at compile time against dictionary snapshots:
 
   * numeric ops → jnp ops on column tensors (device, fused by XLA);
   * string scalar UDFs → host evaluation over dictionary values producing LUT
-    arrays, applied on device with one gather, or a compare-select for small
-    tables on the TPU (`_lookup`);
+    arrays, applied on device with one gather, or compare-selects for
+    tables up to a few thousand entries on the TPU (`_lookup`);
   * string equality / select → dictionary code translation at compile time,
     integer compare / where on device.
 
@@ -56,47 +56,68 @@ class SVal:
     origin: Optional[tuple] = None
 
 
-#: A LUT of at most this many entries, of values at most 32 bits wide, is
-#: applied in a program traced for the TPU as a chain of compare-selects
-#: instead of a gather (`_lookup`).  Read on one TPU v5e chip over the served
-#: path's 8,388,608-row bucket (PR 28; ms, gather against one chain of
-#: selects, int32): K=110 67.4 / 0.96, 1,024 45.6 / 7.88, 4,096 60.8 / 28.0,
-#: 16,384 60.7 / (cut into runs of 32 under a `fori_loop`) 55.5.  In time
-#: the select crosses the gather above 8,000 entries; what binds sooner is
-#: the compile, 16.5 s for a chain of 1,024 and 66 s for 4,096, and inside
-#: the by-status program (where XLA copies the chain into each consumer)
-#: already 4.8 s more a program at 110.  Runs under a loop compile in under
-#: a second at any K, but their carried buffer slowed the two scatters of
-#: the windowed chain by 0.27 s a query: not used.  64-bit values keep the
-#: gather (128.3 ms at K=110): a chain of them runs in 3.5 ms but XLA hands
-#: its compares between the value's 32-bit halves as [rows] predicates, 483
-#: MB of temporaries at K=110.
+#: How a program traced for the TPU applies a LUT of values at most 32 bits
+#: wide (`lut_form`, `_lookup`): up to `LUT_SELECT_MAX` entries as one chain
+#: of compare-selects, up to `LUT_BLOCKED_MAX` as that chain over
+#: `LUT_SELECT_MAX` entries at a time under one `fori_loop`, longer tables
+#: by a gather.  Kernels alone on one TPU v5e chip over a 4,718,592-row
+#: bucket (one shard of `http_scan_4chip`'s two feeds; PR 39; ms, medians
+#: of 15, int32): gather / blocks of 32 / 64 / 128:
+#:
+#:     K=  129  39.76 / 1.01 / 1.08 / 1.15
+#:         440  39.63 / 1.65 / 1.64 / 1.70
+#:       1,024  26.00 / 2.85 / 2.88 / 2.77
+#:       2,048  34.70 / 5.04 / 5.14 / 4.82
+#:       4,096  34.60 / 9.22 / 9.51 / 8.94
+#:       8,192  34.60 / 17.69 / 18.20 / 17.10
+#:
+#: (K=110: gather 38.35, the unlooped chain 0.83.)  Every loop compiles in
+#: 0.4-2.6 s whatever K is, where a straight chain took 16.5 s at 1,024 and
+#: 66 s at 4,096 (PR 28), and inside the by-status program (where XLA
+#: copies the chain into each consumer) 4.8 s more a program at 110: so the
+#: unlooped chain stops at 128, where it still fuses into its consumers.
+#: The blocked form is under half the gather's time up to 8,192; the cap
+#: stays at 4,096, below `nslookup`'s ~10,000-entry table. 64-bit values
+#: keep the gather (128.3 ms at K=110, PR 28): a chain of them runs in 3.5
+#: ms but XLA hands its compares between the value's 32-bit halves as [rows]
+#: predicates, 483 MB of temporaries at K=110.
 LUT_SELECT_MAX = 128
+LUT_BLOCKED_MAX = 4096
 
 
-def lut_selects(k: int, itemsize: int) -> bool:
-    """Whether a program traced right now applies a LUT of `k` entries of
-    `itemsize` bytes as a compare-select: decided by what the trace can
-    observe, the dispatch platform and the table's static length and value
-    width (as `ops.groupby._use_matmul`)."""
-    return (0 < k <= LUT_SELECT_MAX and itemsize <= 4
-            and _groupby.dispatch_backend() == "tpu")
+def lut_form(k: int, itemsize: int) -> Optional[str]:
+    """How a program traced right now applies a LUT of `k` entries of
+    `itemsize` bytes: "select", "blocked" or "gather" (None for an empty
+    table, which is all fill).  Decided by what the trace can observe, the
+    dispatch platform and the table's static length and value width (as
+    `ops.groupby._use_matmul`)."""
+    if k == 0:
+        return None
+    if (itemsize > 4 or k > LUT_BLOCKED_MAX
+            or _groupby.dispatch_backend() != "tpu"):
+        return "gather"
+    return "select" if k <= LUT_SELECT_MAX else "blocked"
 
 
 def _lookup(lut: jax.Array, idx: jax.Array) -> jax.Array:
     """lut[idx], for idx already clipped into [0, len(lut)).
 
-    One algorithm, two formulations.  XLA's TPU gather is serial per row
+    One algorithm, three formulations.  XLA's TPU gather is serial per row
     whatever the table's size (8 ns a row for 440 bytes of table), while
-    `where(idx == k, lut[k], acc)` over the entries is elementwise VPU work
-    that fuses into its consumers: no [rows, K] intermediate exists, the
-    accumulator is the one live value a row has.  It is a select in the
-    LUT's own dtype, so the result is bit-equal to the gather's.  Elsewhere
-    (XLA-CPU gathers from a table in L1), and for longer or wider tables,
-    the gather stays."""
-    if not lut_selects(lut.shape[0], lut.dtype.itemsize):
-        return jnp.take(lut, idx)
-    return _select_chain(lut, idx)
+    `where(idx == k, lut[k], acc)` over the entries is elementwise VPU work:
+    no [rows, K] intermediate exists, the accumulator is the one live value
+    a row has.  A short table is one unlooped chain, which fuses into its
+    consumers; a longer one is the same chain over one block of the table
+    at a time under a loop, whose body compiles once whatever K is.  Either
+    is a select in the LUT's own dtype, so the result is bit-equal to the
+    gather's.  Elsewhere (XLA-CPU gathers from a table in L1), and for
+    tables past `LUT_BLOCKED_MAX` or wider than 32 bits, the gather stays."""
+    form = lut_form(lut.shape[0], lut.dtype.itemsize)
+    if form == "select":
+        return _select_chain(lut, idx)
+    if form == "blocked":
+        return _blocked_select(lut, idx)
+    return jnp.take(lut, idx)
 
 
 def _select_chain(lut: jax.Array, idx: jax.Array) -> jax.Array:
@@ -108,11 +129,36 @@ def _select_chain(lut: jax.Array, idx: jax.Array) -> jax.Array:
     return acc
 
 
+def _blocked_select(lut: jax.Array, idx: jax.Array) -> jax.Array:
+    """`_select_chain` a block of `LUT_SELECT_MAX` entries at a time under
+    one loop: the table padded to whole blocks, trip b selecting where
+    idx - b * block is 0..block-1.  Every idx in [0, len(lut)) matches one
+    real entry, so the padding never does and the accumulator's start is
+    never read."""
+    block = LUT_SELECT_MAX
+    k = lut.shape[0]
+    trips = -(-k // block)
+    padded = jnp.pad(lut, (0, trips * block - k))
+
+    def trip(b, acc):
+        base = b * block
+        run = jax.lax.dynamic_slice(padded, (base,), (block,))
+        rel = idx - base.astype(idx.dtype)
+        for i in range(block):
+            acc = jnp.where(rel == i, run[i], acc)
+        return acc
+
+    # zeros_like keeps idx's sharding under a shard_map, as the carry must
+    return jax.lax.fori_loop(0, trips, trip,
+                             jnp.zeros_like(idx, dtype=lut.dtype))
+
+
 @jax.named_scope("px.md_lookup")
 def apply_lut(lut: jax.Array, codes: jax.Array, fill):
-    """Safe LUT application (a gather, or a compare-select for small tables
-    on the TPU: `_lookup`): codes may be -1 (null / no-translation) → fill.
-    An EMPTY lut (no dictionary values yet — empty table) yields all-fill."""
+    """Safe LUT application (a gather, or compare-selects for tables up to
+    `LUT_BLOCKED_MAX` on the TPU: `_lookup`): codes may be -1 (null /
+    no-translation) → fill.  An EMPTY lut (no dictionary values yet —
+    empty table) yields all-fill."""
     if lut.shape[0] == 0:
         return jnp.full(jnp.shape(codes), fill, dtype=jnp.asarray(lut).dtype)
     out = _lookup(lut, jnp.clip(codes, 0, lut.shape[0] - 1))
@@ -181,10 +227,10 @@ class ExprCompiler:
 
     def lut_forms(self) -> dict:
         """How a program traced right now applies the chain's LUTs: the count
-        of compare-selects and of gathers (an empty table is neither)."""
-        sel = sum(lut_selects(k, size) for k, size in self.lut_sizes)
-        return {"lut_select": sel,
-                "lut_gather": sum(k > 0 for k, _ in self.lut_sizes) - sel}
+        of tables in each of `lut_form`'s forms (an empty table is none)."""
+        forms = [lut_form(k, size) for k, size in self.lut_sizes]
+        return {f"lut_{f}": forms.count(f)
+                for f in ("select", "blocked", "gather")}
 
     def _cast(self, v: SVal, target: DT) -> SVal:
         if v.dtype == target:

@@ -1437,12 +1437,12 @@ class PlanExecutor:
         `rec`, the chain's _timed frame, takes the engine and the routing
         decision `src` ran under as the attributes of its trace span, and,
         for a jitted chain over `kern`, how its program applies its LUTs
-        (`lut_select`/`lut_gather`: call this inside the chain's device
-        context, where the program is traced).  A dense aggregate passes
-        `num_groups`, the span's `groups`; `agg_form` beside it says how
-        its sums and counts reduce them: the host engines scatter, a
-        jitted chain's feeds say theirs as they are dispatched
-        (_agg_feed_loop)."""
+        (`lut_select`/`lut_blocked`/`lut_gather`: call this inside the
+        chain's device context, where the program is traced).  A dense
+        aggregate passes `num_groups`, the span's `groups`; `agg_form`
+        beside it says how its sums and counts reduce them: the host
+        engines scatter, a jitted chain's feeds say theirs as they are
+        dispatched (_agg_feed_loop)."""
         if rec is not None:
             span = rec.setdefault("span", {})  # _feed may have come first
             span.update(engine=engine, **self._route_attrs(src))

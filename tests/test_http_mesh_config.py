@@ -248,36 +248,67 @@ def test_a_chain_the_mesh_serves_takes_no_routing_decision(served):
         assert "mesh_merge" not in [s.name for s in q["spans"]]
 
 
-def test_the_lookup_over_440_pods_gathers_in_a_program_traced_for_the_tpu(
-        monkeypatch):
-    """A cluster's K is past `LUT_SELECT_MAX`: the chain's program gathers
-    where one node's 110 pods are compare-selects.  A store of its own: the
-    kernel cache must not hand back a program traced for the CPU."""
-    from pixie_tpu.engine import eval as ev
-
+@pytest.fixture(scope="module")
+def lookup_440():
+    """One by-status query over a store of its own under the file's 440
+    pods, served by the mesh agent with its kernels traced for XLA-CPU and
+    then for the TPU (the kernel cache cleared in between, so that neither
+    is handed the other's program)."""
     old = mdstate.global_manager()
-    monkeypatch.setattr(groupby, "dispatch_backend", lambda: "tpu")
     config = small_config(batches=1)
-    assert config["metadata"]["pods"] > ev.LUT_SELECT_MAX
     tables = datagen.generate(config, SEED + 1)
     script = traffic.load_script("http_by_status")
-    try:
-        datagen.install_metadata(config)
-        store = datagen.load_store(config, tables)
-        with serving(store, 4) as client:
-            q = ask(client, script, config, 1)
-    finally:
-        mdstate.set_global_manager(old)
+    out = {"config": config, "tables": tables, "script": script}
+    with pytest.MonkeyPatch.context() as mp:
+        try:
+            datagen.install_metadata(config)
+            store = datagen.load_store(config, tables)
+            for backend in ("cpu", "tpu"):
+                mp.setattr(groupby, "dispatch_backend", lambda b=backend: b)
+                executor_mod._KERNEL_CACHE.clear()
+                with serving(store, 4) as client:
+                    out[backend] = ask(client, script, config, 1)
+        finally:
+            executor_mod._KERNEL_CACHE.clear()
+            mdstate.set_global_manager(old)
+    return out
+
+
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+def test_the_lookup_over_440_pods_on_the_mesh(backend, lookup_440):
+    """A cluster's K is past `LUT_SELECT_MAX` and under `LUT_BLOCKED_MAX`:
+    a program traced for the TPU applies it as blocks of compare-selects
+    under one loop, where XLA-CPU gathers; the answers are the same, and
+    the reference's."""
+    from pixie_tpu.engine import eval as ev
+
+    config, script = lookup_440["config"], lookup_440["script"]
+    assert (ev.LUT_SELECT_MAX < config["metadata"]["pods"]
+            <= ev.LUT_BLOCKED_MAX)
+    q = lookup_440[backend]
     (chain,) = [c for c in chains(q["spans"])
                 if c.name.endswith("partial_agg")]
-    assert chain.attributes["lut_gather"] >= 1
-    assert chain.attributes["lut_select"] == 0
-    assert chain.attributes["agg_form"] == "onehot"
-    assert chain.attributes["groups"] == 128
+    attrs = chain.attributes
+    if backend == "tpu":
+        assert (attrs["lut_blocked"], attrs["lut_gather"]) == (1, 0)
+    else:
+        assert attrs["lut_gather"] >= 1 and attrs["lut_blocked"] == 0
+    assert attrs["lut_select"] == 0
+    assert attrs["agg_form"] == ("onehot" if backend == "tpu" else "scatter")
+    assert attrs["groups"] == 128
     mod = compare.load_reference(script["reference"])
-    numbers = mod.compare(
-        q["df"], mod.reference(tables, config, script, q["start"]), config)
+    numbers = mod.compare(q["df"], mod.reference(
+        lookup_440["tables"], config, script, q["start"]), config)
     assert all(v <= lim for v, lim in numbers.values()), numbers
+    # the lookup is bit-equal; the TPU's one-hot sums round as they do
+    keys = ["service", "resp_status"]
+    got = q["df"].sort_values(keys).reset_index(drop=True)
+    cpu = lookup_440["cpu"]["df"].sort_values(keys).reset_index(drop=True)
+    for col in keys + ["cnt", "p50"]:
+        np.testing.assert_array_equal(got[col].to_numpy(), cpu[col].to_numpy())
+    np.testing.assert_allclose(got["avg_lat"].to_numpy(),
+                               cpu["avg_lat"].to_numpy(),
+                               rtol=config["guarantees"]["mean_rtol"])
 
 
 # --------------------------------------------- (d) the width of the mesh

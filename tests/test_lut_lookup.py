@@ -1,12 +1,13 @@
-"""How a small lookup table is applied to a column of codes.
+"""How a lookup table is applied to a column of codes.
 
-`engine/eval._lookup` has one algorithm in two formulations: in a program
+`engine/eval._lookup` has one algorithm in three formulations: in a program
 traced for the TPU a table of at most `LUT_SELECT_MAX` entries of at most
-32 bits is a chain of compare-selects, elsewhere and for longer or wider
-tables it is a gather.  The two are bit-equal for every dtype a LUT has; the
-choice follows the dispatch platform and the table's static length and
-width, as the lowered text of a by-status chain shows; a served query's
-chain span says which form its program holds."""
+32 bits is a chain of compare-selects, one of at most `LUT_BLOCKED_MAX` is
+that chain a block of the table at a time under one loop, and elsewhere and
+for longer or wider tables it is a gather.  All are bit-equal for every
+dtype a LUT has; the choice follows the dispatch platform and the table's
+static length and width, as the lowered text of a by-status chain shows; a
+served query's chain span says which form its program holds."""
 from __future__ import annotations
 
 import os
@@ -32,8 +33,8 @@ from tests.test_trace_layers import _agent_spans, _chains, fresh_ring, serving  
 BY_STATUS = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmarks", "scripts", "http_by_status.pxl")
 
-DTYPES = {"int32": np.int32, "bool": np.bool_, "int64": np.int64,
-          "float64": np.float64}
+DTYPES = {"int32": np.int32, "bool": np.bool_, "float32": np.float32,
+          "int64": np.int64, "float64": np.float64}
 
 
 def _trace_for(monkeypatch, backend: str) -> None:
@@ -47,10 +48,12 @@ def _lut(dtype: str, k: int) -> np.ndarray:
     rng = np.random.default_rng(k)
     if dtype == "bool":
         return rng.integers(0, 2, k).astype(np.bool_)
-    if dtype == "float64":
-        out = rng.standard_normal(k) * 1e300
+    if dtype.startswith("float"):
+        ft = np.finfo(DTYPES[dtype])
+        out = (rng.standard_normal(k) * (ft.max / 8)).astype(ft.dtype)
         # values a sum or a GEMM would not carry through; a select does
-        out[: min(k, 5)] = [np.nan, -0.0, np.inf, 5e-324, -np.inf][: min(k, 5)]
+        odd = [np.nan, -0.0, np.inf, ft.smallest_subnormal, -np.inf]
+        out[: min(k, 5)] = odd[: min(k, 5)]
         return out
     info = np.iinfo(DTYPES[dtype])
     return rng.integers(info.min, info.max, k, dtype=DTYPES[dtype])
@@ -66,11 +69,24 @@ def _codes(k: int) -> np.ndarray:
 
 def _bits(a) -> np.ndarray:
     a = np.asarray(a)
-    return a.view(np.int64) if a.dtype == np.float64 else a
+    if a.dtype.kind == "f":
+        return a.view({8: np.int64, 4: np.int32}[a.dtype.itemsize])
+    return a
 
 
 def _gathers(text: str) -> int:
     return text.count('"stablehlo.gather"(')
+
+
+def _whiles(text: str) -> int:
+    return text.count("stablehlo.while(")
+
+
+def _md_whiles(text: str) -> int:
+    """The loops that `_lookup` emitted, in a text lowered with debug info
+    (a chain has others: the group-by's chunk loops)."""
+    return len(re.findall(r'^#loc\d+ = loc\("[^"]*px\.md_lookup/while"',
+                          text, re.M))
 
 
 def _md_gathers(text: str) -> int:
@@ -89,22 +105,24 @@ def _md_gathers(text: str) -> int:
 
 
 def _apply(lut, codes, fill):
+    """The lowered text of `apply_lut` over `codes`, and its answer."""
     fn = jax.jit(lambda lt, c: ev.apply_lut(lt, c, fill))
-    return _gathers(fn.lower(lut, codes).as_text()), fn(lut, codes)
+    return fn.lower(lut, codes).as_text(), fn(lut, codes)
 
 
 # ------------------------------------------------------------- bit-equality
 
 
 WIDE = {"int64", "float64"}
+NARROW = sorted(set(DTYPES) - WIDE)
 
 
 @pytest.mark.parametrize("k", [1, 2, 110, ev.LUT_SELECT_MAX])
 @pytest.mark.parametrize("dtype", sorted(DTYPES))
 def test_select_is_bit_equal_to_the_gather(dtype, k, monkeypatch):
     """The chain against `jnp.take` for every dtype; through `apply_lut`,
-    traced for the TPU, the narrow dtypes take it and the wide ones keep
-    the gather."""
+    traced for the TPU, the narrow dtypes take it, unlooped, and the wide
+    ones keep the gather."""
     lut, codes = _lut(dtype, k), _codes(k)
     safe = np.clip(codes, 0, k - 1)
     chained = jax.jit(ev._select_chain)(lut, safe)
@@ -113,11 +131,35 @@ def test_select_is_bit_equal_to_the_gather(dtype, k, monkeypatch):
     np.testing.assert_array_equal(_bits(chained), _bits(jax.jit(
         lambda lt, i: jax.numpy.take(lt, i))(lut, safe)))
     fill = False if dtype == "bool" else -1
-    gathers, gathered = _apply(lut, codes, fill)
-    assert gathers == 1
+    text, gathered = _apply(lut, codes, fill)
+    assert _gathers(text) == 1
     _trace_for(monkeypatch, "tpu")
-    gathers, selected = _apply(lut, codes, fill)
-    assert gathers == (dtype in WIDE)
+    text, selected = _apply(lut, codes, fill)
+    assert _gathers(text) == (dtype in WIDE) and _whiles(text) == 0
+    assert selected.dtype == gathered.dtype == lut.dtype
+    np.testing.assert_array_equal(_bits(selected), _bits(gathered))
+    want = np.where(codes >= 0, lut[safe], np.asarray(fill, lut.dtype))
+    np.testing.assert_array_equal(_bits(selected), _bits(want))
+
+
+@pytest.mark.parametrize("k", [ev.LUT_SELECT_MAX + 1, 440, 512, 1000,
+                               ev.LUT_BLOCKED_MAX])
+@pytest.mark.parametrize("dtype", NARROW)
+def test_blocked_is_bit_equal_to_the_gather(dtype, k, monkeypatch):
+    """Blocks of compare-selects under one loop against `jnp.take`; through
+    `apply_lut`, traced for the TPU, one loop and no gather, answering as
+    the gather does."""
+    lut, codes = _lut(dtype, k), _codes(k)
+    safe = np.clip(codes, 0, k - 1)
+    blocked = jax.jit(ev._blocked_select)(lut, safe)
+    assert blocked.dtype == lut.dtype
+    np.testing.assert_array_equal(_bits(blocked), _bits(lut[safe]))
+    fill = False if dtype == "bool" else -1
+    text, gathered = _apply(lut, codes, fill)
+    assert _gathers(text) == 1 and _whiles(text) == 0
+    _trace_for(monkeypatch, "tpu")
+    text, selected = _apply(lut, codes, fill)
+    assert _gathers(text) == 0 and _whiles(text) == 1
     assert selected.dtype == gathered.dtype == lut.dtype
     np.testing.assert_array_equal(_bits(selected), _bits(gathered))
     want = np.where(codes >= 0, lut[safe], np.asarray(fill, lut.dtype))
@@ -127,10 +169,10 @@ def test_select_is_bit_equal_to_the_gather(dtype, k, monkeypatch):
 @pytest.mark.parametrize("dtype", sorted(DTYPES))
 def test_a_longer_table_keeps_the_gather(dtype, monkeypatch):
     _trace_for(monkeypatch, "tpu")
-    k = ev.LUT_SELECT_MAX + 1
+    k = ev.LUT_BLOCKED_MAX + 1
     lut, codes = _lut(dtype, k), _codes(k)
-    gathers, out = _apply(lut, codes, 0)
-    assert gathers == 1
+    text, out = _apply(lut, codes, 0)
+    assert _gathers(text) == 1 and _whiles(text) == 0
     want = np.where(codes >= 0, lut[np.clip(codes, 0, k - 1)],
                     np.asarray(0, lut.dtype))
     np.testing.assert_array_equal(_bits(out), _bits(want))
@@ -141,18 +183,24 @@ def test_a_longer_table_keeps_the_gather(dtype, monkeypatch):
 def test_an_empty_table_is_all_fill(dtype, backend, monkeypatch):
     _trace_for(monkeypatch, backend)
     fill = True if dtype == "bool" else 7
-    gathers, out = _apply(np.empty(0, DTYPES[dtype]), _codes(0), fill)
-    assert gathers == 0 and out.dtype == DTYPES[dtype]
+    text, out = _apply(np.empty(0, DTYPES[dtype]), _codes(0), fill)
+    assert _gathers(text) == _whiles(text) == 0
+    assert out.dtype == DTYPES[dtype]
     assert np.asarray(out).tolist() == [fill] * len(_codes(0))
 
 
 def test_the_form_follows_platform_length_and_width(monkeypatch):
-    assert not ev.lut_selects(110, 4)  # this process dispatches to XLA-CPU
+    # this process dispatches to XLA-CPU
+    assert ev.lut_form(110, 4) == ev.lut_form(440, 4) == "gather"
     _trace_for(monkeypatch, "tpu")
-    assert ev.lut_selects(1, 1) and ev.lut_selects(ev.LUT_SELECT_MAX, 4)
-    assert not ev.lut_selects(0, 4)
-    assert not ev.lut_selects(ev.LUT_SELECT_MAX + 1, 4)
-    assert not ev.lut_selects(110, 8)
+    assert ev.lut_form(0, 4) is None
+    assert ev.lut_form(1, 1) == ev.lut_form(ev.LUT_SELECT_MAX, 4) == "select"
+    assert ev.lut_form(ev.LUT_SELECT_MAX + 1, 4) == "blocked"
+    assert ev.lut_form(ev.LUT_BLOCKED_MAX, 1) == "blocked"
+    assert ev.lut_form(ev.LUT_BLOCKED_MAX + 1, 4) == "gather"
+    for k in (110, 440, ev.LUT_BLOCKED_MAX + 1):
+        assert ev.lut_form(k, 8) == "gather"
+    assert ev.LUT_SELECT_MAX < ev.LUT_BLOCKED_MAX <= 4096
 
 
 # ------------------------------------------------- through the ExprCompiler
@@ -165,8 +213,8 @@ def _compile(expr, dtypes, dicts=None):
 
     def run(cols):
         fn = jax.jit(lambda c, lt: sval.build({"cols": c, "luts": lt}))
-        return (_gathers(fn.lower(cols, luts).as_text()),
-                np.asarray(fn(cols, luts)))
+        text = fn.lower(cols, luts).as_text()
+        return _gathers(text), _whiles(text), np.asarray(fn(cols, luts))
     return ec, sval, run
 
 
@@ -184,17 +232,19 @@ def test_int_domain_lookup_in_and_out_of_domain(fn, backend, monkeypatch):
     _trace_for(monkeypatch, backend)
     x = INT_DOMAIN[fn]
     ec, sval, run = _compile(Call(fn, (Column("x"),)), {"x": DT.INT64})
-    gathers, codes = run({"x": x})
-    # http_resp_message's 500 entries are over the constant, protocol_name's
-    # 13 under it
+    gathers, whiles, codes = run({"x": x})
+    # http_resp_message's 500 entries are over the unlooped chain's
+    # constant and under the loop's, protocol_name's 13 under both
     (k,) = [len(lut) for lut in ec.luts.values()]
-    selects = backend == "tpu" and k <= ev.LUT_SELECT_MAX
     assert (k > ev.LUT_SELECT_MAX) == (fn == "http_resp_message")
-    assert gathers == (not selects)
+    assert k <= ev.LUT_BLOCKED_MAX
+    form = ("gather" if backend == "cpu" else
+            "select" if k <= ev.LUT_SELECT_MAX else "blocked")
+    assert gathers == (form == "gather") and whiles == (form == "blocked")
     host = registry.scalar(fn, (DT.INT64,)).fn
     assert sval.dictionary.decode(codes) == [host(int(v)) for v in x]
-    assert ec.lut_forms() == {"lut_select": int(selects),
-                              "lut_gather": int(not selects)}
+    assert ec.lut_forms() == {f"lut_{f}": int(f == form)
+                              for f in ("select", "blocked", "gather")}
 
 
 @pytest.mark.parametrize("backend", ["cpu", "tpu"])
@@ -220,7 +270,7 @@ def test_dictionary_udfs_agree_across_forms(out, backend, monkeypatch):
     }[out]
     ec, sval, run = _compile(expr, {"a": DT.STRING, "b": DT.STRING},
                              {"a": da, "b": db})
-    gathers, got = run({"a": a, "b": b})
+    gathers, _whiles, got = run({"a": a, "b": b})
     # `length` gives an INT64 LUT, which keeps the gather on either platform
     assert gathers == (backend == "cpu" or out == "int")
     if sval.dictionary is not None:
@@ -296,10 +346,16 @@ def _by_status_step(ts) -> tuple[str, dict]:
     return lowered.as_text(debug_info=True), s.kern.lut_forms()
 
 
+def _forms(select=0, blocked=0, gather=0) -> dict:
+    return {"lut_select": select, "lut_blocked": blocked, "lut_gather": gather}
+
+
 @pytest.mark.parametrize("backend,k,forms", [
-    ("tpu", 110, {"lut_select": 1, "lut_gather": 0}),
-    ("tpu", ev.LUT_SELECT_MAX + 1, {"lut_select": 0, "lut_gather": 1}),
-    (None, 110, {"lut_select": 0, "lut_gather": 1}),
+    ("tpu", 110, _forms(select=1)),
+    ("tpu", ev.LUT_SELECT_MAX + 1, _forms(blocked=1)),
+    ("tpu", ev.LUT_BLOCKED_MAX + 1, _forms(gather=1)),
+    (None, 110, _forms(gather=1)),
+    (None, ev.LUT_SELECT_MAX + 1, _forms(gather=1)),
 ])
 def test_by_status_chain_gathers_only_where_it_should(
         backend, k, forms, metadata, monkeypatch):
@@ -311,12 +367,15 @@ def test_by_status_chain_gathers_only_where_it_should(
     assert "/px.md_lookup/" in text
     assert got == forms
     assert _md_gathers(text) == forms["lut_gather"]
+    assert _md_whiles(text) == forms["lut_blocked"]
 
 
-def test_by_status_answers_equal_across_forms(metadata, monkeypatch):
-    """The same plan run with its LUT as a gather and as a compare-select
-    (both on XLA-CPU, the second traced as for the TPU) answers alike,
-    and its counts are the rows of each service that are not 404s."""
+@pytest.mark.parametrize("form", ["select", "blocked"])
+def test_by_status_answers_equal_across_forms(form, metadata, monkeypatch):
+    """The same plan run with its LUT as a gather and as compare-selects,
+    unlooped or in blocks (all on XLA-CPU, the second traced as for the
+    TPU) answers alike, and its counts are the rows of each service that
+    are not 404s."""
     ts, m, who, status = _by_status_store(110)
     metadata(m)
     _src, plan = _by_status_plan(ts)
@@ -327,8 +386,8 @@ def test_by_status_answers_equal_across_forms(metadata, monkeypatch):
         return df.sort_values(["service", "resp_status"]).reset_index(drop=True)
 
     gathered = answer()
-    monkeypatch.setattr(ev, "lut_selects", lambda k, size: (
-        0 < k <= ev.LUT_SELECT_MAX and size <= 4))
+    monkeypatch.setattr(ev, "lut_form", lambda k, size: (
+        form if k and size <= 4 else "gather"))
     selected = answer()
     ex._KERNEL_CACHE.clear()
     assert gathered.equals(selected)
@@ -343,9 +402,9 @@ def test_by_status_answers_equal_across_forms(metadata, monkeypatch):
 @pytest.mark.parametrize("backend", ["cpu", "tpu"])
 def test_served_by_status_chain_span_says_how_its_lut_is_applied(
         backend, metadata, monkeypatch, fresh_ring):
-    """A served `http_by_status` query's chain span carries `lut_select` and
-    `lut_gather` beside engine, arm and source: the one metadata LUT, in the
-    form the dispatch platform gives it."""
+    """A served `http_by_status` query's chain span carries `lut_select`,
+    `lut_blocked` and `lut_gather` beside engine, arm and source: the one
+    metadata LUT, in the form the dispatch platform gives it."""
     ts, m, _who, _status = _by_status_store(110)
     metadata(m)
     _trace_for(monkeypatch, backend)
@@ -356,5 +415,5 @@ def test_served_by_status_chain_span_says_how_its_lut_is_applied(
     (chain,) = _chains(_agent_spans())
     a = chain.attributes
     assert a["engine"] == "xla_cpu_chain" and a["source"] == "cold"
-    assert (a["lut_select"], a["lut_gather"]) == (
-        (1, 0) if backend == "tpu" else (0, 1))
+    assert (a["lut_select"], a["lut_blocked"], a["lut_gather"]) == (
+        (1, 0, 0) if backend == "tpu" else (0, 0, 1))
