@@ -61,6 +61,7 @@ from pixie_tpu.table.dictionary import Dictionary
 from pixie_tpu.types import STORAGE_DTYPE, ColumnSchema, DataType as DT, Relation
 
 from pixie_tpu.ops import groupby as _gb
+from pixie_tpu.ops import sketch as _sketch
 from pixie_tpu.ops.groupby import next_pow2
 
 INT64_MIN = np.iinfo(np.int64).min
@@ -2865,20 +2866,31 @@ class PlanExecutor:
         state = None
         span = (self._stat_stack[-1].setdefault("span", {})
                 if self._stat_stack else {})  # the chain's, as in _feed
+        from pixie_tpu.udf.udf import QuantilesUDA, QuantileUDA
+
         forms: dict = {}  # form -> its largest feed
+        sketch_forms: dict = {}  # the same for the quantile sketches
+        sketched = any(isinstance(uda, (QuantileUDA, QuantilesUDA))
+                       for _n, uda, _dt in init_specs)
+
+        def join(seen: dict, form: str, rows: int) -> str:
+            seen[form] = max(seen.get(form, 0), rows)
+            return "+".join(sorted(seen, key=lambda f: -seen[f]))
 
         def note_form(rows: int) -> None:
             # A feed of `rows` rows (one shard's, under a mesh) is about to
             # be dispatched: its sums and counts take the form
-            # ops/groupby.agg_form says, which is what the kernels dispatch
-            # on (call this inside the context the step is traced in).  The
-            # chain's span names the forms of its feeds as `agg_form`,
+            # ops/groupby.agg_form says, and its quantile sketches the one
+            # ops/sketch.sketch_form says, which is what the kernels
+            # dispatch on (call this inside the context the step is traced
+            # in).  The chain's span names the forms of its feeds as
+            # `agg_form` (and `sketch_form`, where the chain has a sketch),
             # largest feed first, joined by `+`: a 1,024-row hot remainder
             # scatters beside a bucket that takes a GEMM.
-            form = _gb.agg_form(rows, num_groups)
-            forms[form] = max(forms.get(form, 0), rows)
-            span["agg_form"] = "+".join(
-                sorted(forms, key=lambda f: -forms[f]))
+            span["agg_form"] = join(forms, _gb.agg_form(rows, num_groups), rows)
+            if sketched:
+                span["sketch_form"] = join(
+                    sketch_forms, _sketch.sketch_form(rows, num_groups), rows)
 
         if kern.has_limit:
             # Limit queries must thread the budgets, so the feed steps chain;

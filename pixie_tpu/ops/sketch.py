@@ -11,28 +11,34 @@ bins 1..NBINS count positive values by ceil(log_gamma(v)); the last bin absorbs
 overflow. With gamma = 1.0404 and 512 bins the dynamic range is ~6.6e8 at ~2%
 relative error, which covers latency-in-ns style telemetry after scaling.
 
-Update formulations (the FLOP bulk of a quantile query is this histogram
-scatter — rows × groups × width on the old full-width one-hot GEMM):
+Update formulations (`sketch_form` says which one a feed takes, from its
+rows, its group count and the platform it is traced for; `update`
+dispatches on it and the executor writes it on the chain's span):
 
-  * LIMB-FACTORED GEMM (TPU, low group count): the bin index factors into
-    two limbs, ``bin = digit * 257 + lane`` — the lane stays one-hot and the
-    digit rides the GEMM *value* as a base-4096 digit (the same trick
-    ops/groupby.py uses to sum int64 via 8-bit f32 limbs).  One narrow
-    [G,CH]@[CH,257] GEMM then unpacks into the two histogram halves with an
-    exact divmod — HALF the MXU FLOPs of the 514-wide one-hot at bit-equal
-    counts.  Exactness: per-chunk per-cell counts ≤ CHUNK (2048) occupy the
-    low digit, 4096·count the high one; their sum stays < 2^23, exact in
-    f32 MXU accumulation; 1.0 and 4096.0 are exact in bf16, so bf16
-    operands with f32 accumulation stay exact at 2x the f32 MXU rate.
-  * SORTED SEGMENT-COUNT (high group count, mirrors the agg's sorted
-    fallback): sort the flat (gid, bin) key — values only, no payload — and
-    diff a searchsorted over the static G·W cell edges.  Model cost is
-    O(n log n) comparisons with NO group factor, vs rows × G × 257 GEMM
-    MACs: the win grows linearly in G.  The crossover is picked by
-    measurement (`measure_update_crossover`), default from the measured
-    CPU crossover (sorted ties segment_sum at G=128, wins 2.3x at
-    G=1024), override via PX_SKETCH_SORT_MIN_GROUPS.
-  * segment_sum (CPU, low group count): XLA-CPU native scatter, unchanged.
+  * `pallas` (TPU, up to SKETCH_KERNEL_MAX_GROUPS groups): the
+    LIMB-FACTORED GEMM as one Pallas kernel.  The bin index factors into
+    two limbs, ``bin = digit * 257 + lane``: the lane stays one-hot and
+    the digit rides the GEMM *value* as a base-4096 digit (the same trick
+    ops/groupby.py uses to sum int64 via 8-bit f32 limbs).  A narrow
+    [257, CH] x [G, CH] GEMM a sub-block of CH rows unpacks into the two
+    histogram halves with an exact divmod: HALF the MXU FLOPs of the
+    514-wide one-hot at bit-equal counts.  Exactness: per-sub-block
+    per-cell counts <= CHUNK (2048) occupy the low digit, 4096·count the
+    high one; their sum stays < 2^23, exact in f32 MXU accumulation; 1.0
+    and 4096.0 are exact in bf16, so bf16 operands with f32 accumulation
+    stay exact at 2x the f32 MXU rate.  The histogram stays in VMEM across
+    the kernel's grid and is written to HBM once; the grid visits the
+    blocks that hold a live row (`groupby.live_chunks`) and no other.
+  * `sorted` (high group count, mirrors the agg's sorted fallback): sort
+    the flat (gid, bin) key — values only, no payload — and diff a
+    searchsorted over the static G·W cell edges.  Model cost is O(n log n)
+    comparisons with NO group factor, vs rows × G × 257 GEMM MACs: the win
+    grows linearly in G.  The crossover is picked by measurement
+    (`measure_update_crossover`), default from the measured CPU crossover
+    (sorted ties segment_sum at G=128, wins 2.3x at G=1024), override via
+    PX_SKETCH_SORT_MIN_GROUPS.
+  * `segment` (CPU, low group count, and what the others leave):
+    XLA-CPU native scatter, unchanged.
 
 All formulations produce identical histograms (tests/test_sketch_kernels.py
 asserts bit-equality), and every one is an elementwise ADD into the state,
@@ -84,6 +90,40 @@ def _sort_min_groups(backend: str) -> int:
     return 4097 if backend == "tpu" else 512
 
 
+#: The Pallas kernel serves every quantile sketch on the TPU up to this many
+#: groups; the sorted form (or the scatter, for a feed too small for the
+#: sort) above.  From the kernels alone (PERF.md section 6; one v5e chip,
+#: an 8,388,608-row bucket, 4,587,520 live rows, ms): the kernel against
+#: an XLA loop of one GEMM a 2,048-row chunk 2.85 / 13.40 at 128 groups,
+#: 7.74 / 15.60 at 512, 14.84 / 19.04 at 1,024, 28.44 / 34.92 at 2,048 and
+#: 55.83 / 72.19 at 4,096.
+SKETCH_KERNEL_MAX_GROUPS = 4096
+#: The kernel's key packs the group id over the bin: bins < 1024.
+KEY_SHIFT = 10
+KEY_BINS = 1 << KEY_SHIFT
+#: Sub-blocks of CHUNK rows a grid step of the kernel takes: 8, 16 and 32
+#: read the same (PERF.md section 6).
+KERNEL_BLOCK_SUBS = 8
+#: Groups a one-hot tile of the kernel spans (the MXU's weights; the lane
+#: side streams): 512 was the fastest of 128, 256 and 512 at every group
+#: count from 512 up.
+KERNEL_GROUP_TILE = 512
+#: The kernel's VMEM: its [544, G] f32 state (8.9 MB at 4,096 groups),
+#: double-buffered, and a sub-block's one-hots and iotas (~8 MB).
+KERNEL_VMEM_BYTES = 64 << 20
+
+
+def _loop32(n: int, body) -> None:
+    """body(i) for i in [0, n), on an int32 counter.  (`lax.fori_loop`
+    over static bounds counts in Python ints, which are int64 in a process
+    with x64 on, and a Pallas TPU kernel has no 64-bit integers.)"""
+    def step(i):
+        body(i)
+        return i + 1
+
+    jax.lax.while_loop(lambda i: i < n, step, np.int32(0))
+
+
 @dataclasses.dataclass(frozen=True)
 class LogHistogram:
     nbins: int = 512
@@ -124,25 +164,11 @@ class LogHistogram:
         mask: jax.Array,
         num_groups: int,
     ) -> jax.Array:
-        """Add values into per-group histograms.
-
-        Formulation dispatch (see module docstring): limb-factored GEMM on
-        TPU at low group counts, sorted segment-count above the measured
-        crossover, segment_sum otherwise.  All paths bit-equal.
-        """
-        n = gid.shape[0]
-        bins = self.bin_index(values)
-        from pixie_tpu.ops.groupby import dispatch_backend
-
-        backend = dispatch_backend()
-        if (num_groups >= _sort_min_groups(backend) and n >= (1 << 14)
-                and num_groups * self.width <= 4 * n):
-            # the cell-edge diff costs O(G·W): only worth it while the cell
-            # space stays comparable to the row count
-            return self._update_sorted(hist, gid, bins, mask, num_groups)
-        if backend == "tpu" and num_groups <= 4096 and n >= 4096:
-            return self._update_gemm(hist, gid, bins, mask, num_groups)
-        return self._update_segment(hist, gid, bins, mask, num_groups)
+        """Add values into per-group histograms, in the form `sketch_form`
+        says (module docstring).  All forms bit-equal."""
+        form = sketch_form(gid.shape[0], num_groups, self.width)
+        return getattr(self, "_update_" + form)(
+            hist, gid, self.bin_index(values), mask, num_groups)
 
     @jax.named_scope("px.sketch_update_segment")
     def _update_segment(self, hist, gid, bins, mask, num_groups):
@@ -175,61 +201,148 @@ class LogHistogram:
         cnt = (bounds[1:] - bounds[:-1]).astype(hist.dtype)
         return hist + cnt.reshape(num_groups, self.width)
 
-    @jax.named_scope("px.sketch_update_gemm")
-    def _update_gemm(self, hist, gid, bins, mask, num_groups):
-        """Limb-factored one-hot GEMM (TPU): bin = digit·LANES + lane; the
-        lane is one-hot, the digit is the VALUE (1 or DIGIT) — one narrow
-        [G,CH]@[CH,LANES] MXU GEMM per chunk, then an exact divmod unpack
-        into the histogram halves.  Half the MXU FLOPs of the full-width
-        one-hot (LANES = width/2) at bit-equal counts.
+    @jax.named_scope("px.sketch_update_pallas")
+    def _update_pallas(self, hist, gid, bins, mask, num_groups, *,
+                       sub_rows=None, block_subs=None):
+        """The limb-factored GEMM as one Pallas kernel (TPU; module
+        docstring): a sub-block's packed GEMM, its exact unpack and the sum
+        over sub-blocks all run in VMEM, and the histogram is written to HBM
+        once.  A grid step takes a block of `block_subs` sub-blocks of
+        `sub_rows` rows (CHUNK and KERNEL_BLOCK_SUBS unless a test asks for
+        smaller); the steps past the live blocks (`live_chunks`,
+        scalar-prefetched) repeat the last live block's index, so they
+        fetch nothing and compute nothing.  The rows ride the lane axis:
+        both one-hots are built from an iota compare, [LANES_P, CH] and
+        [tile of G, CH], and contracted over their last axes.  The group id
+        and bin travel packed in one int32 key (`gid * KEY_BINS + bin`, -1
+        where masked): the bins are XLA's (`bin_index`), the kernel only
+        takes them apart.  The state is [2 * LANES_P, G] (the two digits'
+        halves, groups on the lanes) and is laid out as [G, width] after
+        the kernel.  Counts, and so the histogram, are the other forms' bit
+        for bit: each sub-block's packed cell is below 2^23 (sub_rows <=
+        CHUNK < DIGIT) and is unpacked before it is added; a masked or
+        padding row matches no group and adds zeros."""
+        from jax.experimental import pallas as pl
+        from jax.experimental.pallas import tpu as pltpu
 
-        The chunk loop visits the chunks from the first that holds a live
-        row of `mask` to the last (groupby.scan_sum, live_chunks), not the
-        whole pow2 bucket: a chunk of masked rows has an all-zero group
-        one-hot, so its packed GEMM and both unpacked digits are exact
-        zeros and the histogram is the all-chunks loop's bit for bit."""
+        from pixie_tpu.ops.groupby import live_chunks
+
+        ch = sub_rows or self.CHUNK
+        r = block_subs or KERNEL_BLOCK_SUBS
+        tile = KERNEL_GROUP_TILE
+        # groups on the state's lanes: a whole number of 128-lane tiles,
+        # and of one-hot tiles past one
+        step = tile if num_groups > tile else 128
+        gp = -(-num_groups // step) * step
+        tg = min(gp, tile)
+        lanes, digit = self.LANES, float(self.DIGIT)
+        lp = -(-lanes // 16) * 16  # bf16 rows come in sixteens
         n = gid.shape[0]
-        ch = min(n, self.CHUNK)
-        if n % ch:
-            # pad to a whole number of chunks with masked-out rows — zero
-            # contributions, so exactness and bit-equality are unaffected
-            pad = ch - n % ch
-            gid = jnp.concatenate([gid, jnp.zeros(pad, gid.dtype)])
-            bins = jnp.concatenate([bins, jnp.zeros(pad, bins.dtype)])
-            mask = jnp.concatenate([mask, jnp.zeros(pad, mask.dtype)])
-            n += pad
-        g32 = gid.astype(jnp.int32)
-        c = n // ch
-        digit = jnp.float32(self.DIGIT)
+        subs = -(-n // ch)
+        if subs <= r:
+            r = subs  # one block: the whole (padded) feed
+        subs = -(-subs // r) * r
+        key = jnp.where(mask, gid.astype(jnp.int32) * KEY_BINS
+                        + bins.astype(jnp.int32), -1)
+        live = mask
+        if subs * ch != n:
+            # masked rows to a whole number of blocks: keys of -1 match no
+            # group, so they add zeros
+            key = jnp.concatenate([key, jnp.full(subs * ch - n, -1, jnp.int32)])
+            live = jnp.concatenate([mask, jnp.zeros(subs * ch - n, bool)])
+        lo, hi = live_chunks(live, r * ch)
 
-        def gemm(gg, bb, mm):
-            # group side: exact {0,1} bf16 one-hot, masked
-            ohg = jax.nn.one_hot(gg, num_groups,
-                                 dtype=jnp.bfloat16) * mm[:, None]
-            lane = bb % self.LANES
-            hi = (bb // self.LANES).astype(jnp.bfloat16)
-            # lane side: one-hot scaled by the digit base when the bin sits
-            # in the upper half — 1.0 and 4096.0 are both exact in bf16
-            val = jnp.float32(1.0) + hi.astype(jnp.float32) * (digit - 1.0)
-            ohb = jax.nn.one_hot(lane, self.LANES,
-                                 dtype=jnp.bfloat16) * val.astype(
-                                     jnp.bfloat16)[:, None]
-            packed = jax.lax.dot_general(
-                ohg, ohb, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)  # [G, LANES]
-            # exact unpack: packed = c_lo + DIGIT * c_hi with
-            # c_lo, c_hi <= CHUNK < DIGIT and packed < 2^23
-            c_hi = jnp.floor(packed / digit)
-            c_lo = packed - c_hi * digit
-            return jnp.concatenate([c_lo, c_hi], axis=1)[:, :self.width]
+        def kernel(lo_ref, hi_ref, key_ref, acc_ref):
+            i = pl.program_id(0)
 
-        from pixie_tpu.ops.groupby import live_chunks, scan_sum
+            @pl.when(i == 0)
+            def _():
+                acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        mb = jnp.where(mask, 1.0, 0.0).astype(jnp.bfloat16)
-        return hist + scan_sum(
-            lambda xs: gemm(*xs).astype(hist.dtype),
-            (g32.reshape(c, ch), bins.reshape(c, ch), mb.reshape(c, ch)),
-            *live_chunks(mask, ch))
+            @pl.when(lo_ref[0] + i < hi_ref[0])
+            def _():
+                gi = jax.lax.broadcasted_iota(jnp.int32, (tg, ch), 0)
+                li = jax.lax.broadcasted_iota(jnp.int32, (lp, ch), 0)
+
+                def sub(j):
+                    k = key_ref[pl.ds(j, 1), :]  # [1, CH]
+                    g = jnp.right_shift(k, KEY_SHIFT)  # -1 where masked
+                    b = jnp.bitwise_and(k, KEY_BINS - 1)
+                    up = b >= lanes
+                    lane = jnp.where(up, b - lanes, b)
+                    # lane side: one-hot scaled by the digit base when the
+                    # bin sits in the upper half (1 and 4096 exact in bf16)
+                    # (no Python scalar in a select: with x64 on it would
+                    # be a 64-bit operand, which Mosaic has not)
+                    val = jnp.where(up, jnp.full_like(lane, int(digit)),
+                                    jnp.ones_like(lane)).astype(jnp.float32)
+                    ohb = jnp.where(li == lane, val, jnp.zeros_like(val))
+                    ohb = ohb.astype(jnp.bfloat16)
+
+                    def tile_step(t):
+                        at = pl.multiple_of(t * tg, tg)
+                        ohg = (gi == g - at).astype(jnp.float32).astype(
+                            jnp.bfloat16)
+                        # the lane side streams through the MXU and the
+                        # group tile is its weights: [LANES_P, tg], no
+                        # padding of the 257 lanes to 384
+                        packed = jax.lax.dot_general(
+                            ohb, ohg, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+                        c_hi = jnp.floor(packed * (1.0 / digit))
+                        c_lo = packed - c_hi * digit
+                        acc_ref[:lp, pl.ds(at, tg)] += c_lo
+                        acc_ref[lp:, pl.ds(at, tg)] += c_hi
+
+                    _loop32(gp // tg, tile_step)
+
+                _loop32(r, sub)
+
+        def call(lo, hi, key, interpret):
+            return pl.pallas_call(
+                kernel,
+                # (under `jax.shard_map` the state varies over the mesh axes
+                # the rows do: each shard counts its own)
+                out_shape=jax.ShapeDtypeStruct((2 * lp, gp), jnp.float32,
+                                               vma=jax.typeof(key).vma),
+                grid_spec=pltpu.PrefetchScalarGridSpec(
+                    num_scalar_prefetch=2,
+                    grid=(subs // r,),
+                    in_specs=[pl.BlockSpec(
+                        (r, ch), lambda i, lo, hi: (
+                            jnp.minimum(lo[0] + i, hi[0] - 1), np.int32(0)))],
+                    out_specs=pl.BlockSpec((2 * lp, gp), lambda i, lo, hi: (
+                        np.int32(0), np.int32(0))),
+                ),
+                compiler_params=pltpu.CompilerParams(
+                    dimension_semantics=("arbitrary",),
+                    vmem_limit_bytes=KERNEL_VMEM_BYTES),
+                interpret=interpret,
+                name="px_sketch_hist",
+            )(lo.reshape(1), hi.reshape(1), key.reshape(subs, ch))
+
+        axes = tuple(sorted(jax.typeof(key).vma))
+        if jax.default_backend() == "tpu":
+            acc = call(lo, hi, key, interpret=False)
+        elif not axes:
+            acc = call(lo, hi, key, interpret=True)
+        else:
+            # Off the chip under `jax.shard_map` (tests that trace for the
+            # TPU): the interpreter's loop is not typed for operands that
+            # vary over the mesh, and the TPU interpreter holds the mesh's
+            # devices at barriers that can deadlock against the program's
+            # own collectives.  So each shard interprets every shard's
+            # kernel, on that shard's live range, over operands gathered
+            # by a psum (which makes them invariant), and keeps its own.
+            me = jax.lax.axis_index(axes)
+            every = jax.lax.psum(jax.tree.map(lambda x: jnp.zeros(
+                (jax.lax.psum(1, axes),) + x.shape, x.dtype).at[me].set(x),
+                (lo, hi, key)), axes)
+            acc = jax.lax.pcast(jax.lax.map(
+                lambda a: call(*a, interpret=True), every), axes,
+                to="varying")[me]
+        add = jnp.concatenate([acc[:lanes], acc[lp:lp + lanes]], axis=0)
+        return hist + add[:self.width, :num_groups].T.astype(hist.dtype)
 
     def init(self, num_groups: int, dtype=jnp.float32) -> jax.Array:
         return jnp.zeros((num_groups, self.width), dtype=dtype)
@@ -285,11 +398,34 @@ class LogHistogram:
         return jnp.where(totals > 0, out, jnp.nan)
 
 
+def sketch_form(n: int, num_groups: int,
+                width: int = LogHistogram().width) -> str:
+    """The form in which `LogHistogram.update`, traced now, adds `n` rows
+    (one shard's, under a mesh) into `num_groups` histograms of `width`
+    bins: `pallas`, `sorted` or `segment` (module docstring).  It reads the
+    rows, the group count and the platform the kernels are traced for and
+    nothing else; `update` dispatches on it and the executor writes it on
+    the chain's span (`sketch_form`), so the span says what the program
+    does."""
+    from pixie_tpu.ops.groupby import dispatch_backend
+
+    backend = dispatch_backend()
+    if (num_groups >= _sort_min_groups(backend) and n >= (1 << 14)
+            and num_groups * width <= 4 * n):
+        # the cell-edge diff costs O(G·W): only worth it while the cell
+        # space stays comparable to the row count
+        return "sorted"
+    if backend == "tpu" and num_groups <= SKETCH_KERNEL_MAX_GROUPS and n >= 4096:
+        return "pallas"
+    return "segment"
+
+
 def measure_update_crossover(n: int = 1 << 21, groups=(128, 256, 512, 1024),
                              repeats: int = 3) -> dict:
     """Measure the dense-vs-sorted sketch-update crossover ON THIS BACKEND.
 
-    Times the dense formulation (GEMM on TPU dispatch, segment_sum on CPU)
+    Times the dense formulation (the Pallas kernel on TPU dispatch,
+    segment_sum on CPU)
     against the sorted segment-count kernel at each group count and returns
     {"backend", "points": {G: {"dense_ms", "sorted_ms"}}, "crossover":
     smallest measured G where sorted wins}.  The default
@@ -312,10 +448,9 @@ def measure_update_crossover(n: int = 1 << 21, groups=(128, 256, 512, 1024),
     for g in sorted(groups):
         gid = jax.device_put((gidh % g).astype(np.int32))
         hist = lh.init(g)
-        if backend == "tpu":
-            dense = jax.jit(lambda h, i, b, m: lh._update_gemm(h, i, b, m, g))
-        else:
-            dense = jax.jit(lambda h, i, b, m: lh._update_segment(h, i, b, m, g))
+        dense_form = "pallas" if backend == "tpu" else "segment"
+        dense = jax.jit(lambda h, i, b, m: getattr(
+            lh, "_update_" + dense_form)(h, i, b, m, g))
         srt = jax.jit(lambda h, i, b, m: lh._update_sorted(h, i, b, m, g))
         out = {}
         for name, fn in (("dense_ms", dense), ("sorted_ms", srt)):

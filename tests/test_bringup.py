@@ -458,12 +458,14 @@ def test_chunk_loop_over_live_chunks_is_the_loop_over_all(
 def test_chunk_loops_walk_each_shards_own_range_under_shard_map(
         monkeypatch, groups):
     """Under `jax.shard_map` every shard derives its own live range: the
-    loop's bounds vary over the mesh axis as its carry does.  Eight shards,
-    one live range each, every chunk-loop kernel: each shard's answer is
-    the single-device scatter formulation's of its rows.  Past
-    MATMUL_MAX_GROUPS slots the group-by kernels are the factored one-hot
-    (what `Agent(n_devices=4)` traces for a windowed chart on a chip); the
-    sketch has its own form and cap and stays at 16."""
+    loop's bounds vary over the mesh axis as its carry does, and the sketch
+    kernel's scalar-prefetched block range does too.  Eight shards, one
+    live range each, every chunk-loop kernel and the sketch's Pallas kernel
+    (blocks of two chunks, so that each shard's range starts and ends inside
+    one): each shard's answer is the single-device scatter formulation's of
+    its rows.  Past MATMUL_MAX_GROUPS slots the group-by kernels are the
+    factored one-hot (what `Agent(n_devices=4)` traces for a windowed chart
+    on a chip); the sketch has its own form and cap and stays at 16."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
@@ -487,8 +489,9 @@ def test_chunk_loops_walk_each_shards_own_range_under_shard_map(
     if groups <= gb.MATMUL_MAX_GROUPS:
         kernels["sketch"] = (
             lambda rng, n: rng.exponential(50.0, n),
-            lambda v, g, m, groups: lh._update_gemm(
-                lh.init(groups), g, lh.bin_index(v), m, groups))
+            lambda v, g, m, groups: lh._update_pallas(
+                lh.init(groups), g, lh.bin_index(v), m, groups,
+                sub_rows=512, block_subs=8))
         scatter["sketch"] = (
             None, lambda v, g, m, groups: lh._update_segment(
                 lh.init(groups), g, lh.bin_index(v), m, groups))
@@ -535,12 +538,12 @@ px.display(df, 'out')
 def test_tpu_formulations_trace_under_shard_map(monkeypatch, script, form,
                                                 exact, close):
     """On a chip the SPMD partial step traces the one-hot GEMM group-by and
-    the limb-factored sketch GEMM INSIDE `jax.shard_map`, whose scans must
+    the sketch's Pallas kernel INSIDE `jax.shard_map`, whose scans must
     keep their carry's varying-axes type (the first 4-chip run died on
     exactly that).  Trace them over the virtual CPU mesh, two chunks per
     shard, against the single-device scatter formulations: four slots
     under the flat one-hot, a windowed chart's 2,048 under the factored
-    one; the chain's frame says the form its shards traced."""
+    one; the chain's frame says the forms its shards traced."""
     from pixie_tpu.compiler import compile_pxl
     from pixie_tpu.engine.executor import PlanExecutor
     from pixie_tpu.ops import groupby as gb
@@ -563,6 +566,9 @@ def test_tpu_formulations_trace_under_shard_map(monkeypatch, script, form,
     (scatter,) = [r["span"] for r in plain.op_stats
                   if "agg_form" in r.get("span", {})]
     assert span["agg_form"] == form and scatter["agg_form"] == "scatter"
+    if "p50" in exact:
+        assert (span["sketch_form"], scatter["sketch_form"]) == (
+            "pallas", "segment")
     assert span["groups"] == scatter["groups"] == (
         4 if form == "onehot" else 2048)
     assert len(got) == len(want)

@@ -295,6 +295,8 @@ def test_the_lookup_over_440_pods_on_the_mesh(backend, lookup_440):
         assert attrs["lut_gather"] >= 1 and attrs["lut_blocked"] == 0
     assert attrs["lut_select"] == 0
     assert attrs["agg_form"] == ("onehot" if backend == "tpu" else "scatter")
+    assert attrs["sketch_form"] == ("pallas" if backend == "tpu"
+                                    else "segment")
     assert attrs["groups"] == 128
     mod = compare.load_reference(script["reference"])
     numbers = mod.compare(q["df"], mod.reference(

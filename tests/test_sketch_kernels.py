@@ -1,12 +1,14 @@
 """Bit-equality of the sketch-update formulations (ops/sketch.py).
 
-The limb-factored GEMM (bin digit packed into the one-hot value) and the
-sorted segment-count kernel must produce IDENTICAL histograms to the
-segment_sum scatter — the sketch's accuracy contract is formulation-
-independent, and the distributed merge (psum) assumes every agent's state
-came from the same arithmetic.  Edge shapes from the satellite list: zero
-bin, overflow bin, empty mask, 1 and 4096 groups, post-psum merge parity
-across a mesh.
+The Pallas kernel (the limb-factored GEMM: bin digit packed into the
+one-hot value, the histogram in VMEM across the grid) and the sorted
+segment-count kernel must produce IDENTICAL histograms to the segment_sum
+scatter — the sketch's accuracy contract is formulation-independent, and
+the distributed merge (psum) assumes every agent's state came from the same
+arithmetic.  Edge shapes: zero bin, overflow bin, the digits' edge, empty
+mask, 1 to 4096 groups, a feed that is not a whole number of the kernel's
+blocks, live rows anywhere in a bucket, post-psum merge parity across a
+mesh.  Off the chip the kernel runs interpreted.
 """
 import numpy as np
 import pytest
@@ -16,8 +18,9 @@ import jax
 import jax.numpy as jnp
 
 from pixie_tpu import flags
+from pixie_tpu.ops import sketch as sk
 from pixie_tpu.ops.sketch import LogHistogram, _sort_min_groups
-from pixie_tpu.testing.live_chunks import LIVE_RANGES, live_mask, scan_every_chunk
+from pixie_tpu.testing.live_chunks import LIVE_RANGES, live_mask
 
 
 @pytest.fixture(scope="module")
@@ -31,7 +34,7 @@ def _paths(lh, gid, vals, mask, G):
     return {
         "segment": np.asarray(lh._update_segment(h0, gid, bins, mask, G)),
         "sorted": np.asarray(lh._update_sorted(h0, gid, bins, mask, G)),
-        "gemm": np.asarray(lh._update_gemm(h0, gid, bins, mask, G)),
+        "pallas": np.asarray(lh._update_pallas(h0, gid, bins, mask, G)),
     }
 
 
@@ -111,11 +114,16 @@ class TestBitEquality:
         np.testing.assert_array_equal(want, got)
 
 
+#: the kernel's blocks in the tests: 8 sub-blocks of 512 rows, two CHUNKs,
+#: so that a live range starts and ends inside a block
+SMALL_BLOCK = {"sub_rows": 512, "block_subs": 8}
+
+
 @pytest.mark.parametrize("live", list(LIVE_RANGES))
 def test_gemm_over_live_chunks_is_the_gemm_over_all(lh, monkeypatch, live):
-    """The GEMM's chunk loop visits the chunks that hold a live row and no
-    other: wherever the live rows sit in the pow2 bucket its histogram is
-    the scatter's, and the loop over every chunk's, bit for bit."""
+    """The kernel's grid visits the blocks that hold a live row and no
+    other: wherever the live rows sit in the pow2 bucket, its histogram is
+    the scatter's, and that of the kernel over every block, bit for bit."""
     from pixie_tpu.ops import groupby as gb
 
     c, G = 8, 16
@@ -125,16 +133,135 @@ def test_gemm_over_live_chunks_is_the_gemm_over_all(lh, monkeypatch, live):
     bins = lh.bin_index(jnp.asarray(rng.exponential(50.0, n)))
     mask = live_mask(live, c, lh.CHUNK)
 
-    def gemm(g, b, m):
-        return lh._update_gemm(lh.init(G), g, b, m, G)
+    def kernel(g, b, m):
+        return lh._update_pallas(lh.init(G), g, b, m, G, **SMALL_BLOCK)
 
     want = np.asarray(lh._update_segment(lh.init(G), gid, bins, mask, G))
-    got = np.asarray(jax.jit(gemm)(gid, bins, mask))
-    monkeypatch.setattr(gb, "scan_sum", scan_every_chunk)
-    # (a new callable: jit would hand back gemm's program, traced before)
-    every = np.asarray(jax.jit(lambda *a: gemm(*a))(gid, bins, mask))
+    got = np.asarray(jax.jit(kernel)(gid, bins, mask))
+    blocks = n // (SMALL_BLOCK["sub_rows"] * SMALL_BLOCK["block_subs"])
+    monkeypatch.setattr(gb, "live_chunks", lambda m, ch: (
+        jnp.int32(0), jnp.int32(blocks)))
+    # (a new callable: jit would hand back kernel's program, traced before)
+    every = np.asarray(jax.jit(lambda *a: kernel(*a))(gid, bins, mask))
     assert got.tobytes() == every.tobytes() == want.tobytes()
     assert got.sum() == mask.sum()
+
+
+@pytest.mark.parametrize("live", list(LIVE_RANGES))
+def test_kernel_at_its_own_block_over_live_chunks(lh, live):
+    """The block the chip runs (KERNEL_BLOCK_SUBS sub-blocks of CHUNK rows)
+    over four of them: wherever the live rows start and end inside a block,
+    the scatter's histogram."""
+    G = 16
+    c = 4 * sk.KERNEL_BLOCK_SUBS
+    n = c * lh.CHUNK
+    rng = np.random.default_rng(8)
+    gid = jnp.asarray(rng.integers(0, G, n).astype(np.int32))
+    bins = lh.bin_index(jnp.asarray(rng.exponential(50.0, n)))
+    mask = jnp.asarray(live_mask(live, c, lh.CHUNK))
+    want = np.asarray(lh._update_segment(lh.init(G), gid, bins, mask, G))
+    got = np.asarray(jax.jit(lambda g, b, m: lh._update_pallas(
+        lh.init(G), g, b, m, G))(gid, bins, mask))
+    assert got.tobytes() == want.tobytes()
+    assert got.sum() == int(np.asarray(mask).sum())
+
+
+@pytest.mark.parametrize("G", [4, 128, 512, 2048])
+def test_kernel_is_the_scatter_at_any_group_count(lh, G):
+    """One group tile (4 and 128 padded to a lane tile), one whole tile
+    (512) and several (2,048): the same histogram as the scatter's."""
+    rng = np.random.default_rng(G)
+    n = 1 << 14
+    gid = jnp.asarray(rng.integers(0, G, n).astype(np.int32))
+    bins = lh.bin_index(jnp.asarray(rng.exponential(50.0, n)))
+    mask = jnp.asarray(rng.random(n) < 0.9)
+    want = np.asarray(lh._update_segment(lh.init(G), gid, bins, mask, G))
+    got = np.asarray(jax.jit(lambda g, b, m: lh._update_pallas(
+        lh.init(G), g, b, m, G, **SMALL_BLOCK))(gid, bins, mask))
+    assert got.tobytes() == want.tobytes()
+    assert got.sum() == int(np.asarray(mask).sum())
+
+
+def test_kernel_over_rows_that_are_not_whole_blocks(lh):
+    """5 blocks and 1,234 rows: the kernel pads to a whole block with rows
+    that match no group, the last of them live."""
+    G = 24
+    n = 5 * 4096 + 1234
+    rng = np.random.default_rng(9)
+    gid = jnp.asarray(rng.integers(0, G, n).astype(np.int32))
+    bins = lh.bin_index(jnp.asarray(rng.exponential(50.0, n)))
+    mask = np.zeros(n, bool)
+    mask[3000:] = True
+    want = np.asarray(lh._update_segment(lh.init(G), gid, bins, mask, G))
+    got = np.asarray(lh._update_pallas(lh.init(G), gid, bins, mask, G,
+                                       **SMALL_BLOCK))
+    assert got.tobytes() == want.tobytes()
+    assert got.sum() == n - 3000
+
+
+@pytest.mark.parametrize("edge", [0, 256, 257, 513])
+def test_kernel_at_the_edge_bins_and_the_digits_edge(lh, edge):
+    """Bin 0 (the zero bin) and 513 (overflow) are the histogram's edges;
+    256 is the low digit's last lane and 257 the high digit's first: every
+    row of a saturated sub-block in one such bin, beside a spread."""
+    G, n = 8, 8192
+    rng = np.random.default_rng(edge)
+    bins = rng.integers(0, lh.width, n).astype(np.int32)
+    bins[: 2 * lh.CHUNK] = edge  # two whole chunks of one bin
+    gid = rng.integers(0, G, n).astype(np.int32)
+    gid[: 2 * lh.CHUNK] = 3
+    mask = np.ones(n, bool)
+    want = np.asarray(lh._update_segment(lh.init(G), gid, bins, mask, G))
+    got = np.asarray(lh._update_pallas(lh.init(G), gid, bins, mask, G))
+    assert got.tobytes() == want.tobytes()
+    assert got[3, edge] >= 2 * lh.CHUNK
+
+
+def test_sketch_form_reads_rows_groups_and_platform(monkeypatch):
+    """The kernel on the TPU up to SKETCH_KERNEL_MAX_GROUPS groups and from
+    4,096 rows; the sorted form past the crossover where the cell space
+    stays under four times the rows; the scatter everywhere else."""
+    from pixie_tpu.ops import groupby as gb
+
+    n = 1 << 20
+    assert sk.sketch_form(n, 128) == "segment"  # this process: XLA-CPU
+    assert sk.sketch_form(n, 1024) == "sorted"
+    monkeypatch.setattr(gb, "dispatch_backend", lambda: "tpu")
+    assert sk.sketch_form(n, 1) == sk.sketch_form(4096, 128) == "pallas"
+    assert sk.sketch_form(n, sk.SKETCH_KERNEL_MAX_GROUPS) == "pallas"
+    assert sk.sketch_form(n, sk.SKETCH_KERNEL_MAX_GROUPS + 1) == "sorted"
+    assert sk.sketch_form(4095, 128) == "segment"
+    assert sk.sketch_form(4096, sk.SKETCH_KERNEL_MAX_GROUPS + 1) == "segment"
+
+
+def test_kernel_compiles_for_a_v5e_mesh_beside_its_psum(lh, monkeypatch):
+    """What each chip of `http_scan_4chip` runs: the sketch's update traced
+    for the TPU under `jax.shard_map`, each shard over its own rows, and
+    the states summed across the mesh by a psum.  Lowered and compiled for
+    a described v5e 2x2 (no chip needed), the program holds the Mosaic
+    kernel and the all-reduce."""
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from pixie_tpu.ops import groupby as gb
+
+    monkeypatch.setenv("TPU_LOG_DIR", "disabled")
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    mesh = Mesh(np.array(topo.devices), ("agents",))
+    monkeypatch.setattr(gb, "dispatch_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    G, per = 128, 1 << 16
+    assert sk.sketch_form(per, G) == "pallas"
+    step = jax.jit(jax.shard_map(
+        lambda v, g, m: jax.lax.psum(
+            lh.update(lh.init(G), g[0], v[0], m[0], G), "agents"),
+        mesh=mesh, in_specs=(P("agents"),) * 3, out_specs=P()))
+    sharded = NamedSharding(mesh, P("agents"))
+    program = step.lower(*(jax.ShapeDtypeStruct((4, per), dt, sharding=sharded)
+                           for dt in (jnp.float32, jnp.int32, jnp.bool_))
+                         ).compile().as_text()
+    assert "px_sketch_hist" in program and "all-reduce" in program
 
 
 class TestDigitPacking:

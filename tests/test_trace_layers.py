@@ -307,6 +307,80 @@ def test_chain_span_says_the_aggregates_form_and_slots(
                 assert c.attributes["rows"] == rows
 
 
+#: a p50 by window and service: 3,072 rows a millisecond apart in windows of
+#: {ms} ms (a pow2 bucket of them) x 2 services; the filter keeps it off the
+#: numpy partial loop
+SKETCH_QUERY = """
+import px
+df = px.DataFrame(table='http_events')
+df = df[df.latency > 5]
+df.time_ = px.bin(df.time_, px.millis({ms}))
+df = df.groupby(['time_', 'service']).agg(p50=('latency', px.p50))
+px.display(df, 'out')
+"""
+
+
+@pytest.mark.parametrize("backend,ms,slots,form", [
+    # by-status' shape on the chip: 128 slots
+    ("tpu", 64, 128, "pallas"),
+    # past SKETCH_KERNEL_MAX_GROUPS a 4,096-row bucket is too small for the
+    # sorted form (its cell space would pass four times the rows)
+    ("tpu", 1, 8192, "segment"),
+    (None, 64, 128, "segment"),
+])
+def test_chain_span_says_the_sketchs_form(
+        monkeypatch, fresh_ring, backend, ms, slots, form):
+    """A chain with a quantile sketch says `sketch_form` beside `agg_form`:
+    what `ops/sketch.sketch_form` says for each feed where the chain is
+    traced, which is what the sketch's update dispatches on: the Pallas
+    kernel where the kernels are traced for the TPU, up to its cap, on
+    either arm; the scatter past the cap for a small feed, and anywhere
+    else."""
+    from pixie_tpu.ops import groupby
+    from pixie_tpu.ops import sketch
+
+    if backend:
+        monkeypatch.setattr(groupby, "dispatch_backend", lambda: backend)
+    assert (backend == "tpu" and slots <= sketch.SKETCH_KERNEL_MAX_GROUPS
+            ) == (form == "pallas")
+    with serving(_mkstore(1, time.time_ns(), 3072), monkeypatch) as client:
+        for _ in range(autotune.COLD_PROBE_PERIOD):
+            client.execute_script(SKETCH_QUERY.format(ms=ms))
+    chains = _chains(_agent_spans())
+    assert [c.attributes["arm"] for c in chains] == ["cpu"] * 3 + ["device"]
+    for c in chains:
+        assert c.attributes["groups"] == slots
+        assert c.attributes["sketch_form"] == form, c.attributes
+
+
+def test_a_chain_without_a_sketch_says_no_sketch_form(monkeypatch,
+                                                       fresh_ring):
+    """`net_flow_by_service` sums and counts: no chain of it carries
+    `sketch_form`, on the TPU's dispatch either."""
+    from tests.test_net_flow_config import datagen, small_config, traffic
+
+    from pixie_tpu.metadata import state as mdstate
+    from pixie_tpu.ops import groupby
+
+    monkeypatch.setattr(groupby, "dispatch_backend", lambda: "tpu")
+    config = small_config()
+    script = traffic.load_script("net_flow_by_service")
+    text = script["text"].replace("__START_TIME__",
+                                  str(int(config["time_base_ns"])))
+    old = mdstate.global_manager()
+    try:
+        datagen.install_metadata(config)
+        store = datagen.load_store(config, datagen.generate(config, 3))
+        with serving(store, monkeypatch) as client:
+            for _ in range(autotune.COLD_PROBE_PERIOD):
+                client.execute_script(text)
+    finally:
+        mdstate.set_global_manager(old)
+    chains = [c for c in _chains(trace.recent()) if "agg_form" in c.attributes]
+    assert len(chains) >= autotune.COLD_PROBE_PERIOD
+    assert not [c for c in chains if "sketch_form" in c.attributes]
+
+
 JOIN_QUERY = """
 import px
 a = px.DataFrame(table='http_events')
@@ -581,7 +655,7 @@ SCOPES = {
     "px.int_limbs@onehot2": _lower_groupby(
         "masked_segment_sum", np.int64, "tpu", 2048),
     "px.sketch_bin": _lower_sketch("bin_index"),
-    "px.sketch_update_gemm": _lower_sketch("_update_gemm"),
+    "px.sketch_update_pallas": _lower_sketch("_update_pallas"),
     "px.sketch_update_sorted": _lower_sketch("_update_sorted"),
     "px.sketch_update_segment": _lower_sketch("_update_segment"),
     # a program of its own (the executor calls it between two others)
