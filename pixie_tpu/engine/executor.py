@@ -1128,6 +1128,25 @@ class _FinalizedCol:
     col: np.ndarray
 
 
+@_contextlib.contextmanager
+def sketch_finalize_span(uda, state):
+    """A `sketch_finalize` span around the finalize of a quantile sketch's
+    host state (its histograms as `groups`, their bytes as
+    `sketch_state_bytes`) into its column, wherever that runs: the
+    merger's `finalize_partial`, a local aggregate's host finalize.  No
+    span for any other aggregate."""
+    from pixie_tpu import trace
+    from pixie_tpu.udf.udf import QuantilesUDA, QuantileUDA
+
+    if not isinstance(uda, (QuantileUDA, QuantilesUDA)):
+        yield
+        return
+    state = np.asarray(state)
+    with trace.span("sketch_finalize", groups=int(state.shape[0]),
+                    sketch_state_bytes=int(state.nbytes)):
+        yield
+
+
 #: jitted merge(+device finalize) of per-feed partials, keyed by the agg's
 #: UDA spec — the single execution that replaces N per-feed state pulls +
 #: a host merge with one small readback wave
@@ -2870,8 +2889,21 @@ class PlanExecutor:
 
         forms: dict = {}  # form -> its largest feed
         sketch_forms: dict = {}  # the same for the quantile sketches
-        sketched = any(isinstance(uda, (QuantileUDA, QuantilesUDA))
-                       for _n, uda, _dt in init_specs)
+        sketches = [name for name, uda, _dt in init_specs
+                    if isinstance(uda, (QuantileUDA, QuantilesUDA))]
+        sketched = bool(sketches)
+
+        def read_back(out: dict) -> dict:
+            # `out` was pulled from the device: the chain's span counts the
+            # bytes of quantile-sketch state in it as `sketch_state_bytes`
+            # (0 where the sketches were finalized on the device)
+            if sketched:
+                span["sketch_state_bytes"] = span.get(
+                    "sketch_state_bytes", 0) + sum(
+                    np.asarray(leaf).nbytes for name in sketches
+                    if not isinstance(out.get(name), _FinalizedCol)
+                    for leaf in jax.tree.leaves(out.get(name)))
+            return out
 
         def join(seen: dict, form: str, rows: int) -> str:
             seen[form] = max(seen.get(form, 0), rows)
@@ -3038,7 +3070,7 @@ class PlanExecutor:
                 out = dict(rest_np)
                 for k, v in finals_np.items():
                     out[k] = _FinalizedCol(v)
-                return out
+                return read_back(out)
             if partials:
                 # deferral is scoped to the distributed partial path
                 # (_partial_agg_batch) — the local finalize path reads the
@@ -3090,12 +3122,13 @@ class PlanExecutor:
                         out = dict(rest_np)
                         for k, v in finals_np.items():
                             out[k] = _FinalizedCol(v)
-                        return out
+                        return read_back(out)
                     pulled = transfer.pull(
                         [p.buf if isinstance(p, _PackedState) else p
                          for p in partials])
                     states = [
-                        p.unpack(buf) if isinstance(p, _PackedState) else buf
+                        read_back(p.unpack(buf) if isinstance(p, _PackedState)
+                                  else buf)
                         for p, buf in zip(partials, pulled)
                     ]
                     return merge_fn(*states)
@@ -3103,7 +3136,7 @@ class PlanExecutor:
         if state is None:  # no feeds at all: identity state
             state = {name: uda.init(num_groups, in_dt)
                      for name, uda, in_dt in init_specs}
-        return transfer.pull(state)
+        return read_back(transfer.pull(state))
 
     def _decode_key_column(self, k: GroupKey, codes: np.ndarray):
         """Seen-group codes → (np column, dictionary|None) for key k."""
@@ -3559,7 +3592,8 @@ class PlanExecutor:
                 full = uda.finalize_dict(
                     jax.tree.map(lambda x: x, st), val_dicts[out_name])
             else:
-                full = uda.finalize_host(jax.tree.map(lambda x: x, st))
+                with sketch_finalize_span(uda, st):
+                    full = uda.finalize_host(jax.tree.map(lambda x: x, st))
             vals = np.asarray(full)[gids]
             # Use the DECLARED input DataType so e.g. min(time_) stays TIME64NS
             # (matching the compile-time schema); fall back to array inference

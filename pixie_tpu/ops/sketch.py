@@ -29,14 +29,22 @@ dispatches on it and the executor writes it on the chain's span):
     stay exact at 2x the f32 MXU rate.  The histogram stays in VMEM across
     the kernel's grid and is written to HBM once; the grid visits the
     blocks that hold a live row (`groupby.live_chunks`) and no other.
-  * `sorted` (high group count, mirrors the agg's sorted fallback): sort
-    the flat (gid, bin) key — values only, no payload — and diff a
-    searchsorted over the static G·W cell edges.  Model cost is O(n log n)
-    comparisons with NO group factor, vs rows × G × 257 GEMM MACs: the win
-    grows linearly in G.  The crossover is picked by measurement
-    (`measure_update_crossover`), default from the measured CPU crossover
-    (sorted ties segment_sum at G=128, wins 2.3x at G=1024), override via
-    PX_SKETCH_SORT_MIN_GROUPS.
+  * `tiles` (TPU, past SKETCH_KERNEL_MAX_GROUPS): the packed (group, bin)
+    key sorted once, values only, so that a tile of KERNEL_GROUP_TILE
+    groups is one contiguous range of rows; then one Pallas kernel counts
+    each tile's rows into that tile's state in VMEM with the same packed
+    GEMM and exact unpack, over a scalar-prefetched schedule of (tile, row
+    block) steps, and writes each tile once.  Each live row meets one
+    tile: the MXU's work grows with the rows, not with rows x G.
+  * `sorted` (XLA-CPU, high group count, mirrors the agg's sorted
+    fallback): sort the flat (gid, bin) key — values only, no payload —
+    and diff a searchsorted over the static G·W cell edges.  Model cost is
+    O(n log n) comparisons with NO group factor.  The crossover is picked by
+    measurement (`measure_update_crossover`), default from the measured
+    CPU crossover (sorted ties segment_sum at G=128, wins 2.3x at G=1024),
+    override via PX_SKETCH_SORT_MIN_GROUPS.  Never on the TPU: the Pallas
+    kernel and the tiles kernel beat it 6-137x at every group count
+    measured (PERF.md section 6).
   * `segment` (CPU, low group count, and what the others leave):
     XLA-CPU native scatter, unchanged.
 
@@ -63,16 +71,15 @@ from pixie_tpu import flags
 SORT_MIN_GROUPS = flags.define_int(
     "PX_SKETCH_SORT_MIN_GROUPS", 0,
     "group count at which the sketch update switches from the dense "
-    "(GEMM/segment_sum) formulation to the sorted segment-count kernel; "
-    "0 = measured per-backend default (512 on CPU, 4097 on TPU)")
+    "(segment_sum) formulation to the sorted segment-count kernel on "
+    "XLA-CPU; 0 = the fitted or measured default (512)")
 
 
 def _sort_min_groups(backend: str) -> int:
-    """Effective sorted-kernel crossover for `backend` — the flag when set,
-    else the measured default: 512 on CPU (sorted ties segment_sum at G=128
-    and wins 2.3x at G=1024), 4097 on TPU (the narrow GEMM is MXU-bound and
-    beats the bitonic sort up to its 4096-group cap; beyond the cap the old
-    code fell back to the serialized scatter, which the sort replaces)."""
+    """Effective sorted-kernel crossover for `backend`, which is never the
+    TPU (`sketch_form` takes the kernels there) — the flag when set, else a
+    crossover fitted for `backend`, else the measured 512 (sorted ties
+    segment_sum at G=128 and wins 2.3x at G=1024 on XLA-CPU)."""
     v = flags.get("PX_SKETCH_SORT_MIN_GROUPS")
     if v > 0:
         return v
@@ -87,17 +94,26 @@ def _sort_min_groups(backend: str) -> int:
         fitted = _autotune.MODEL.sketch_threshold(backend)
         if fitted is not None:
             return fitted
-    return 4097 if backend == "tpu" else 512
+    return 512
 
 
 #: The Pallas kernel serves every quantile sketch on the TPU up to this many
-#: groups; the sorted form (or the scatter, for a feed too small for the
-#: sort) above.  From the kernels alone (PERF.md section 6; one v5e chip,
+#: groups; the tiles kernel above (or the scatter, for a feed under 4,096
+#: rows).  From the kernels alone (PERF.md section 6; one v5e chip,
 #: an 8,388,608-row bucket, 4,587,520 live rows, ms): the kernel against
 #: an XLA loop of one GEMM a 2,048-row chunk 2.85 / 13.40 at 128 groups,
 #: 7.74 / 15.60 at 512, 14.84 / 19.04 at 1,024, 28.44 / 34.92 at 2,048 and
 #: 55.83 / 72.19 at 4,096.
 SKETCH_KERNEL_MAX_GROUPS = 4096
+#: Past that cap the tiles kernel (`_update_tiles`) serves the TPU up to
+#: this many groups: its packed key (`gid * KEY_BINS + bin`, and one past
+#: the last tile for a masked row) must stay an int32.  From the kernels
+#: alone (PERF.md section 6, as above, gids uniform), the tiles kernel
+#: against the sorted form and the scatter: 20.8 / 771 / 84.8 ms at 8,192
+#: groups, 21.5 / 1,537 / 85.0 at 16,384, 22.5 / 3,068 / 85.2 at 32,768 and
+#: 24.4 / - / 86.2 at 65,536, of which its sort is 16.4; no crossover in
+#: sight, so the cap is the key's.
+SKETCH_TILES_MAX_GROUPS = (1 << 21) - 1024
 #: The kernel's key packs the group id over the bin: bins < 1024.
 KEY_SHIFT = 10
 KEY_BINS = 1 << KEY_SHIFT
@@ -111,6 +127,58 @@ KERNEL_GROUP_TILE = 512
 #: The kernel's VMEM: its [544, G] f32 state (8.9 MB at 4,096 groups),
 #: double-buffered, and a sub-block's one-hots and iotas (~8 MB).
 KERNEL_VMEM_BYTES = 64 << 20
+
+
+def _call_per_shard(call, *operands):
+    """`call(*operands, interpret=...)`, a kernel's pallas_call: compiled on
+    the chip, interpreted off it.  The last operand (the rows) says over
+    which mesh axes the operands vary under `jax.shard_map`.  Off the chip
+    under a mesh (tests that trace for the TPU) the interpreter's loop is
+    not typed for operands that vary over the mesh, and the TPU
+    interpreter holds the mesh's devices at barriers that can deadlock
+    against the program's own collectives.  So there each shard interprets
+    every shard's kernel, over operands gathered by a psum (which makes
+    them invariant), and keeps its own."""
+    axes = tuple(sorted(jax.typeof(operands[-1]).vma))
+    if jax.default_backend() == "tpu":
+        return call(*operands, interpret=False)
+    if not axes:
+        return call(*operands, interpret=True)
+    me = jax.lax.axis_index(axes)
+    every = jax.lax.psum(jax.tree.map(lambda x: jnp.zeros(
+        (jax.lax.psum(1, axes),) + x.shape, x.dtype).at[me].set(x),
+        operands), axes)
+    return jax.lax.pcast(jax.lax.map(
+        lambda a: call(*a, interpret=True), every), axes, to="varying")[me]
+
+
+def _lane_onehot(k, li, lanes: int, digit: float):
+    """The lane side of a sub-block's packed GEMM, [LANES_P, CH] bf16: the
+    one-hot of each row's lane (`li` its iota), scaled by the digit base
+    where the row's bin sits in the upper half (1 and 4096 are exact in
+    bf16).  `k` holds the rows' packed keys, `gid * KEY_BINS + bin`.  (No
+    Python scalar in a select: with x64 on it would be a 64-bit operand,
+    which Mosaic has not.)"""
+    b = jnp.bitwise_and(k, KEY_BINS - 1)
+    up = b >= lanes
+    lane = jnp.where(up, b - lanes, b)
+    val = jnp.where(up, jnp.full_like(lane, int(digit)),
+                    jnp.ones_like(lane)).astype(jnp.float32)
+    return jnp.where(li == lane, val, jnp.zeros_like(val)).astype(
+        jnp.bfloat16)
+
+
+def _tile_counts(ohb, hit, digit: float):
+    """The (low digit, high digit) counts, [LANES_P, tile] f32 each, of one
+    sub-block against one tile of groups (`hit`: [tile, CH], a row's group
+    is the tile's i-th): the lane side streams through the MXU and the
+    group tile is its weights, so the 257 lanes pad to 272, not 384.  A
+    packed cell stays below 2^23, so the unpack is exact."""
+    packed = jax.lax.dot_general(
+        ohb, hit.astype(jnp.float32).astype(jnp.bfloat16),
+        (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+    c_hi = jnp.floor(packed * (1.0 / digit))
+    return packed - c_hi * digit, c_hi
 
 
 def _loop32(n: int, body) -> None:
@@ -267,30 +335,11 @@ class LogHistogram:
                 def sub(j):
                     k = key_ref[pl.ds(j, 1), :]  # [1, CH]
                     g = jnp.right_shift(k, KEY_SHIFT)  # -1 where masked
-                    b = jnp.bitwise_and(k, KEY_BINS - 1)
-                    up = b >= lanes
-                    lane = jnp.where(up, b - lanes, b)
-                    # lane side: one-hot scaled by the digit base when the
-                    # bin sits in the upper half (1 and 4096 exact in bf16)
-                    # (no Python scalar in a select: with x64 on it would
-                    # be a 64-bit operand, which Mosaic has not)
-                    val = jnp.where(up, jnp.full_like(lane, int(digit)),
-                                    jnp.ones_like(lane)).astype(jnp.float32)
-                    ohb = jnp.where(li == lane, val, jnp.zeros_like(val))
-                    ohb = ohb.astype(jnp.bfloat16)
+                    ohb = _lane_onehot(k, li, lanes, digit)
 
                     def tile_step(t):
                         at = pl.multiple_of(t * tg, tg)
-                        ohg = (gi == g - at).astype(jnp.float32).astype(
-                            jnp.bfloat16)
-                        # the lane side streams through the MXU and the
-                        # group tile is its weights: [LANES_P, tg], no
-                        # padding of the 257 lanes to 384
-                        packed = jax.lax.dot_general(
-                            ohb, ohg, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-                        c_hi = jnp.floor(packed * (1.0 / digit))
-                        c_lo = packed - c_hi * digit
+                        c_lo, c_hi = _tile_counts(ohb, gi == g - at, digit)
                         acc_ref[:lp, pl.ds(at, tg)] += c_lo
                         acc_ref[lp:, pl.ds(at, tg)] += c_hi
 
@@ -321,26 +370,110 @@ class LogHistogram:
                 name="px_sketch_hist",
             )(lo.reshape(1), hi.reshape(1), key.reshape(subs, ch))
 
-        axes = tuple(sorted(jax.typeof(key).vma))
-        if jax.default_backend() == "tpu":
-            acc = call(lo, hi, key, interpret=False)
-        elif not axes:
-            acc = call(lo, hi, key, interpret=True)
-        else:
-            # Off the chip under `jax.shard_map` (tests that trace for the
-            # TPU): the interpreter's loop is not typed for operands that
-            # vary over the mesh, and the TPU interpreter holds the mesh's
-            # devices at barriers that can deadlock against the program's
-            # own collectives.  So each shard interprets every shard's
-            # kernel, on that shard's live range, over operands gathered
-            # by a psum (which makes them invariant), and keeps its own.
-            me = jax.lax.axis_index(axes)
-            every = jax.lax.psum(jax.tree.map(lambda x: jnp.zeros(
-                (jax.lax.psum(1, axes),) + x.shape, x.dtype).at[me].set(x),
-                (lo, hi, key)), axes)
-            acc = jax.lax.pcast(jax.lax.map(
-                lambda a: call(*a, interpret=True), every), axes,
-                to="varying")[me]
+        acc = _call_per_shard(call, lo, hi, key)
+        add = jnp.concatenate([acc[:lanes], acc[lp:lp + lanes]], axis=0)
+        return hist + add[:self.width, :num_groups].T.astype(hist.dtype)
+
+    @jax.named_scope("px.sketch_update_tiles")
+    def _update_tiles(self, hist, gid, bins, mask, num_groups, *,
+                      sub_rows=None, block_subs=None):
+        """Past the kernel's group cap (TPU; module docstring): the packed
+        (group, bin) key sorted once, values only, so that the rows of a
+        tile of KERNEL_GROUP_TILE groups are one contiguous range; then one
+        Pallas kernel counts each tile's rows into that tile's [544, tile]
+        state in VMEM with `_update_pallas`'s packed-digit GEMM and exact
+        unpack, and writes each tile once.  Its grid is a schedule of
+        (tile, row block) steps, scalar-prefetched: each tile's blocks from
+        the one that holds its first row to the one that holds its last,
+        one step for a tile with no row (it writes zeros), and the steps
+        past the schedule's end repeat its last one and compute nothing.
+        A block's rows of a neighbouring tile, and the masked rows (sorted
+        past every tile), match no group of the step's tile and add zeros.
+        So the MXU's work grows with the rows (each live row meets one
+        tile), not with rows x G, and the search is over G / tile + 1 tile
+        edges, not over the G x 514 cells the `sorted` form diffs.  Bit
+        for bit the other forms' histogram, as `_update_pallas` is."""
+        from jax.experimental import pallas as pl
+        from jax.experimental.pallas import tpu as pltpu
+
+        ch = sub_rows or self.CHUNK
+        r = block_subs or KERNEL_BLOCK_SUBS
+        tg = KERNEL_GROUP_TILE
+        tiles = -(-num_groups // tg)
+        lanes, digit = self.LANES, float(self.DIGIT)
+        lp = -(-lanes // 16) * 16  # bf16 rows come in sixteens
+        n = gid.shape[0]
+        blocks = -(-n // (r * ch))
+        pad = blocks * r * ch - n
+        past = tiles * tg * KEY_BINS  # sorts after every tile's keys
+        key = jnp.where(mask, gid.astype(jnp.int32) * KEY_BINS
+                        + bins.astype(jnp.int32), past)
+        if pad:
+            key = jnp.concatenate([key, jnp.full(pad, past, jnp.int32)])
+        key = jnp.sort(key)
+        edges = jnp.arange(tiles + 1, dtype=jnp.int32) * (tg * KEY_BINS)
+        bounds = jnp.searchsorted(key, edges, side="left").astype(jnp.int32)
+        first = jnp.minimum(bounds[:-1] // (r * ch), blocks - 1)
+        empty = bounds[1:] == bounds[:-1]
+        last = jnp.where(empty, first, (bounds[1:] - 1) // (r * ch))
+        count = last - first + 1  # a tile's steps
+        ends = jnp.cumsum(count)
+        steps = blocks + tiles  # at least the schedule's length
+        i = jnp.arange(steps, dtype=jnp.int32)
+        tile = jnp.minimum(jnp.searchsorted(ends, i, side="right"), tiles - 1)
+        off = i - (ends[tile] - count[tile])
+        live = i < ends[-1]
+        block = jnp.where(live, first[tile] + off, last[-1]).astype(jnp.int32)
+        # bit 0: the step opens its tile (zero the state); bit 1: it counts
+        flag = (jnp.where(live & (off == 0), 1, 0)
+                + jnp.where(live & ~empty[tile], 2, 0)).astype(jnp.int32)
+        tile = tile.astype(jnp.int32)
+
+        def kernel(tile_ref, block_ref, flag_ref, key_ref, acc_ref):
+            f = flag_ref[pl.program_id(0)]
+            at = tile_ref[pl.program_id(0)] * tg
+
+            @pl.when(jnp.bitwise_and(f, 1) == 1)
+            def _():
+                acc_ref[...] = jnp.zeros_like(acc_ref)
+
+            @pl.when(jnp.bitwise_and(f, 2) == 2)
+            def _():
+                gi = jax.lax.broadcasted_iota(jnp.int32, (tg, ch), 0)
+                li = jax.lax.broadcasted_iota(jnp.int32, (lp, ch), 0)
+
+                def sub(j):
+                    k = key_ref[pl.ds(j, 1), :]  # [1, CH]
+                    c_lo, c_hi = _tile_counts(
+                        _lane_onehot(k, li, lanes, digit),
+                        gi == jnp.right_shift(k, KEY_SHIFT) - at, digit)
+                    acc_ref[:lp, :] += c_lo
+                    acc_ref[lp:, :] += c_hi
+
+                _loop32(r, sub)
+
+        def call(tile, block, flag, key, interpret):
+            return pl.pallas_call(
+                kernel,
+                out_shape=jax.ShapeDtypeStruct((2 * lp, tiles * tg),
+                                               jnp.float32,
+                                               vma=jax.typeof(key).vma),
+                grid_spec=pltpu.PrefetchScalarGridSpec(
+                    num_scalar_prefetch=3,
+                    grid=(steps,),
+                    in_specs=[pl.BlockSpec(
+                        (r, ch), lambda i, t, b, f: (b[i], np.int32(0)))],
+                    out_specs=pl.BlockSpec(
+                        (2 * lp, tg), lambda i, t, b, f: (np.int32(0), t[i])),
+                ),
+                compiler_params=pltpu.CompilerParams(
+                    dimension_semantics=("arbitrary",),
+                    vmem_limit_bytes=KERNEL_VMEM_BYTES),
+                interpret=interpret,
+                name="px_sketch_tiles",
+            )(tile, block, flag, key.reshape(blocks * r, ch))
+
+        acc = _call_per_shard(call, tile, block, flag, key)
         add = jnp.concatenate([acc[:lanes], acc[lp:lp + lanes]], axis=0)
         return hist + add[:self.width, :num_groups].T.astype(hist.dtype)
 
@@ -402,21 +535,27 @@ def sketch_form(n: int, num_groups: int,
                 width: int = LogHistogram().width) -> str:
     """The form in which `LogHistogram.update`, traced now, adds `n` rows
     (one shard's, under a mesh) into `num_groups` histograms of `width`
-    bins: `pallas`, `sorted` or `segment` (module docstring).  It reads the
-    rows, the group count and the platform the kernels are traced for and
-    nothing else; `update` dispatches on it and the executor writes it on
-    the chain's span (`sketch_form`), so the span says what the program
-    does."""
+    bins: `pallas`, `tiles`, `sorted` or `segment` (module docstring).  On
+    the TPU, from 4,096 rows, it is `pallas` up to SKETCH_KERNEL_MAX_GROUPS
+    groups and `tiles` past it up to SKETCH_TILES_MAX_GROUPS; the sorted
+    form's crossover governs XLA-CPU alone.  It reads the rows, the group
+    count and the platform the kernels are traced for and nothing else;
+    `update` dispatches on it and the executor writes it on the chain's
+    span (`sketch_form`), so the span says what the program does."""
     from pixie_tpu.ops.groupby import dispatch_backend
 
     backend = dispatch_backend()
+    if backend == "tpu":
+        if n >= 4096 and num_groups <= SKETCH_KERNEL_MAX_GROUPS:
+            return "pallas"
+        if n >= 4096 and num_groups <= SKETCH_TILES_MAX_GROUPS:
+            return "tiles"
+        return "segment"
     if (num_groups >= _sort_min_groups(backend) and n >= (1 << 14)
             and num_groups * width <= 4 * n):
         # the cell-edge diff costs O(G·W): only worth it while the cell
         # space stays comparable to the row count
         return "sorted"
-    if backend == "tpu" and num_groups <= SKETCH_KERNEL_MAX_GROUPS and n >= 4096:
-        return "pallas"
     return "segment"
 
 

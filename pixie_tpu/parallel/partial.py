@@ -17,7 +17,7 @@ import dataclasses
 
 import numpy as np
 
-from pixie_tpu.engine.executor import HostBatch
+from pixie_tpu.engine.executor import HostBatch, sketch_finalize_span
 from pixie_tpu.plan.plan import AggOp
 from pixie_tpu.status import Internal, InvalidArgument
 from pixie_tpu.table.dictionary import Dictionary
@@ -212,7 +212,8 @@ def finalize_partial(
         # finalize_host is host-pure by contract (no instance state from
         # init) — calling uda.init here would dispatch a device op with a
         # poll-varying group-count shape, i.e. a fresh XLA compile per poll.
-        col = uda.finalize_host(pb.states[ae.out_name])
+        with sketch_finalize_span(uda, pb.states[ae.out_name]):
+            col = uda.finalize_host(pb.states[ae.out_name])
         out_dt = uda.out_type(pb.in_types.get(ae.out_name))
         vals = np.asarray(col)
         out_dtypes[ae.out_name] = out_dt

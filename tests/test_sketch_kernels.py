@@ -217,29 +217,49 @@ def test_kernel_at_the_edge_bins_and_the_digits_edge(lh, edge):
     assert got[3, edge] >= 2 * lh.CHUNK
 
 
-def test_sketch_form_reads_rows_groups_and_platform(monkeypatch):
+MAX = sk.SKETCH_KERNEL_MAX_GROUPS
+
+
+@pytest.mark.parametrize("backend,n,groups,form", [
+    # this process's own dispatch: XLA-CPU
+    ("cpu", 1 << 20, 128, "segment"),
+    ("cpu", 1 << 20, 1024, "sorted"),
+    ("cpu", 1 << 20, MAX + 1, "sorted"),
+    # the TPU: the kernel up to its cap from 4,096 rows
+    ("tpu", 1 << 20, 1, "pallas"),
+    ("tpu", 4096, 128, "pallas"),
+    ("tpu", 1 << 20, MAX, "pallas"),
+    ("tpu", 4095, 128, "segment"),
+    # past the cap the tiles kernel, from 4,096 rows, up to its own cap
+    ("tpu", 1 << 20, MAX + 1, "tiles"),
+    ("tpu", 4096, MAX + 1, "tiles"),
+    ("tpu", 1 << 23, 32768, "tiles"),
+    ("tpu", 1 << 23, sk.SKETCH_TILES_MAX_GROUPS, "tiles"),
+    ("tpu", 4095, MAX + 1, "segment"),
+    ("tpu", 1 << 23, sk.SKETCH_TILES_MAX_GROUPS + 1, "segment"),
+])
+def test_sketch_form_reads_rows_groups_and_platform(monkeypatch, backend, n,
+                                                    groups, form):
     """The kernel on the TPU up to SKETCH_KERNEL_MAX_GROUPS groups and from
-    4,096 rows; the sorted form past the crossover where the cell space
+    4,096 rows, the tiles kernel past that up to SKETCH_TILES_MAX_GROUPS;
+    on XLA-CPU the sorted form past the crossover where the cell space
     stays under four times the rows; the scatter everywhere else."""
     from pixie_tpu.ops import groupby as gb
 
-    n = 1 << 20
-    assert sk.sketch_form(n, 128) == "segment"  # this process: XLA-CPU
-    assert sk.sketch_form(n, 1024) == "sorted"
-    monkeypatch.setattr(gb, "dispatch_backend", lambda: "tpu")
-    assert sk.sketch_form(n, 1) == sk.sketch_form(4096, 128) == "pallas"
-    assert sk.sketch_form(n, sk.SKETCH_KERNEL_MAX_GROUPS) == "pallas"
-    assert sk.sketch_form(n, sk.SKETCH_KERNEL_MAX_GROUPS + 1) == "sorted"
-    assert sk.sketch_form(4095, 128) == "segment"
-    assert sk.sketch_form(4096, sk.SKETCH_KERNEL_MAX_GROUPS + 1) == "segment"
+    monkeypatch.setattr(gb, "dispatch_backend", lambda: backend)
+    assert sk.sketch_form(n, groups) == form
 
 
-def test_kernel_compiles_for_a_v5e_mesh_beside_its_psum(lh, monkeypatch):
-    """What each chip of `http_scan_4chip` runs: the sketch's update traced
-    for the TPU under `jax.shard_map`, each shard over its own rows, and
-    the states summed across the mesh by a psum.  Lowered and compiled for
-    a described v5e 2x2 (no chip needed), the program holds the Mosaic
-    kernel and the all-reduce."""
+@pytest.mark.parametrize("G,kernel", [(128, "px_sketch_hist"),
+                                      (16384, "px_sketch_tiles")])
+def test_kernel_compiles_for_a_v5e_mesh_beside_its_psum(lh, monkeypatch, G,
+                                                       kernel):
+    """What each chip of `http_scan_4chip` runs (128 groups), and a service
+    map's edges past the cap (16,384): the sketch's update traced for the
+    TPU under `jax.shard_map`, each shard over its own rows, and the states
+    summed across the mesh by a psum.  Lowered and compiled for a described
+    v5e 2x2 (no chip needed), the program holds the Mosaic kernel and the
+    all-reduce."""
     from jax.experimental import topologies
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -251,8 +271,9 @@ def test_kernel_compiles_for_a_v5e_mesh_beside_its_psum(lh, monkeypatch):
     mesh = Mesh(np.array(topo.devices), ("agents",))
     monkeypatch.setattr(gb, "dispatch_backend", lambda: "tpu")
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    G, per = 128, 1 << 16
-    assert sk.sketch_form(per, G) == "pallas"
+    per = 1 << 16
+    assert sk.sketch_form(per, G) == kernel.replace("px_sketch_hist", "pallas"
+                                                    ).replace("px_sketch_", "")
     step = jax.jit(jax.shard_map(
         lambda v, g, m: jax.lax.psum(
             lh.update(lh.init(G), g[0], v[0], m[0], G), "agents"),
@@ -261,7 +282,67 @@ def test_kernel_compiles_for_a_v5e_mesh_beside_its_psum(lh, monkeypatch):
     program = step.lower(*(jax.ShapeDtypeStruct((4, per), dt, sharding=sharded)
                            for dt in (jnp.float32, jnp.int32, jnp.bool_))
                          ).compile().as_text()
-    assert "px_sketch_hist" in program and "all-reduce" in program
+    assert kernel in program and "all-reduce" in program
+
+
+#: the tiles kernel's blocks in the tests: 4 sub-blocks of 512 rows
+TILES_BLOCK = {"sub_rows": 512, "block_subs": 4}
+
+
+def _tiles_case(case: str, G: int):
+    """(gid, values, mask) of a test of the tiles kernel at G groups: rows
+    that are not whole blocks with a hot group, a masked stretch, a full
+    bucket of whole blocks, or every row in the first and last tiles (the
+    ones between have none)."""
+    rng = np.random.default_rng(G + len(case))
+    n = {"full_bucket": 4 * 4 * 512}.get(case, 3 * 2048 + 777)
+    gid = rng.integers(0, G, n).astype(np.int32)
+    mask = np.ones(n, bool)
+    if case == "hot_group":
+        gid[rng.random(n) < 0.6] = G // 3
+    elif case == "masked_rows":
+        mask[500:4500] = False
+        mask[rng.random(n) < 0.2] = False
+    elif case == "empty_tiles":
+        gid = np.where(rng.random(n) < 0.5, gid % 7, G - 1 - gid % 5)
+    vals = rng.exponential(50.0, n)
+    vals[::97] = 0.0  # the zero bin
+    vals[::89] = 1e30  # the overflow bin
+    return gid.astype(np.int32), vals, mask
+
+
+@pytest.mark.parametrize("case", ["hot_group", "masked_rows", "full_bucket",
+                                  "empty_tiles"])
+@pytest.mark.parametrize("G", [MAX + 1, 16384, 32768])
+def test_tiles_kernel_is_add_at(lh, G, case):
+    """Past the kernel's cap: the tiles kernel's histogram is np.add.at's
+    bit for bit, whatever the group count (one row past a whole tile, 32
+    and 64 tiles), a hot group, masked rows, rows that are not whole
+    blocks, a full bucket, and tiles with no rows."""
+    gid, vals, mask = _tiles_case(case, G)
+    bins = lh.bin_index(jnp.asarray(vals))
+    want = np.zeros((G, lh.width), np.float32)
+    np.add.at(want, (gid[mask], np.asarray(bins)[mask]), 1.0)
+    got = np.asarray(jax.jit(lambda g, b, m: lh._update_tiles(
+        lh.init(G), g, b, m, G, **TILES_BLOCK))(gid, bins, mask))
+    assert got.tobytes() == want.tobytes()
+    assert got.sum() == mask.sum()
+
+
+def test_tiles_kernel_at_its_own_block(lh):
+    """The block the chip runs (KERNEL_BLOCK_SUBS sub-blocks of CHUNK
+    rows), over two blocks and a part of a third, past the cap."""
+    G = 16384
+    n = 2 * sk.KERNEL_BLOCK_SUBS * lh.CHUNK + 3000
+    rng = np.random.default_rng(11)
+    gid = rng.integers(0, G, n).astype(np.int32)
+    gid[: n // 3] = 4242
+    bins = lh.bin_index(jnp.asarray(rng.exponential(50.0, n)))
+    mask = rng.random(n) < 0.9
+    want = np.asarray(lh._update_segment(lh.init(G), gid, bins, mask, G))
+    got = np.asarray(jax.jit(lambda g, b, m: lh._update_tiles(
+        lh.init(G), g, b, m, G))(gid, bins, mask))
+    assert got.tobytes() == want.tobytes()
 
 
 class TestDigitPacking:
@@ -292,26 +373,44 @@ class TestDigitPacking:
         assert int(np.nonzero(outs["segment"][0])[0][0]) >= lh.LANES
 
 
-class TestSortMinGroups:
-    def test_backend_defaults(self):
-        assert _sort_min_groups("cpu") == 512
-        assert _sort_min_groups("tpu") == 4097
+def _tpu_forms(monkeypatch) -> set:
+    """The forms the TPU's dispatch takes over group counts from 1 to past
+    the tiles kernel's cap, at a full bucket."""
+    from pixie_tpu.ops import groupby as gb
 
-    def test_flag_override(self):
+    monkeypatch.setattr(gb, "dispatch_backend", lambda: "tpu")
+    return {sk.sketch_form(1 << 23, g) for g in
+            (1, 7, 128, 1024, MAX, MAX + 1, 32768,
+             sk.SKETCH_TILES_MAX_GROUPS + 1)}
+
+
+class TestSortMinGroups:
+    def test_backend_defaults(self, monkeypatch):
+        """512 on XLA-CPU; the TPU never takes the sorted form."""
+        assert _sort_min_groups("cpu") == 512
+        assert _tpu_forms(monkeypatch) == {"pallas", "tiles", "segment"}
+
+    def test_flag_override(self, monkeypatch):
+        """The flag moves XLA-CPU's crossover and not the TPU's forms."""
         flags.set_for_testing("PX_SKETCH_SORT_MIN_GROUPS", 7)
         try:
             assert _sort_min_groups("cpu") == 7
-            assert _sort_min_groups("tpu") == 7
+            assert _tpu_forms(monkeypatch) == {"pallas", "tiles", "segment"}
         finally:
             flags.set_for_testing("PX_SKETCH_SORT_MIN_GROUPS", 0)
 
 
 class TestPsumMergeParity:
-    def test_mesh_psum_merge(self, lh):
+    @pytest.mark.parametrize("form,G", [
+        ("_update_sorted", 32), ("_update_segment", 32),
+        ("_update_tiles", 32), ("_update_tiles", MAX + 1)])
+    def test_mesh_psum_merge(self, lh, form, G):
         """Per-shard updates psum-merged across an 8-device CPU mesh equal
-        the single-device update over all rows — for BOTH per-shard
-        formulations (sorted and segment), since a mixed-formulation mesh
-        (e.g. heterogeneous backends) must still merge exactly."""
+        the single-device update over all rows — for every per-shard
+        formulation (sorted and segment, and the tiles kernel, which each
+        shard interprets over its own rows off the chip, within its cap and
+        past the dense kernel's), since a mixed-formulation mesh (e.g.
+        heterogeneous backends) must still merge exactly."""
         from jax.sharding import PartitionSpec as P
 
         from pixie_tpu.parallel.spmd import (
@@ -319,33 +418,32 @@ class TestPsumMergeParity:
         )
 
         n_dev, per = 8, 2048
-        n, G = n_dev * per, 32
+        n = n_dev * per
         rng = np.random.default_rng(5)
         gid = rng.integers(0, G, n).astype(np.int32)
         vals = rng.exponential(50.0, n)
         mask = rng.random(n) < 0.9
         mesh = make_mesh(n_dev)
         bins = np.asarray(lh.bin_index(jnp.asarray(vals)))
+        upd = getattr(lh, form)
+        kw = TILES_BLOCK if form == "_update_tiles" else {}
 
-        for form in ("_update_sorted", "_update_segment"):
-            upd = getattr(lh, form)
+        def shard_fn(g, b, m):
+            h = upd(lh.init(G), g[0], b[0], m[0], G, **kw)
+            return jax.lax.psum(h, "agents")[None]
 
-            def shard_fn(g, b, m):
-                h = upd(lh.init(G), g[0], b[0], m[0], G)
-                return jax.lax.psum(h, "agents")[None]
-
-            f = jax.jit(shard_map(
-                shard_fn, mesh=mesh,
-                in_specs=(P("agents"), P("agents"), P("agents")),
-                out_specs=P("agents"),
-            ))
-            f = serialize_cpu_collectives(f, mesh)
-            merged = np.asarray(f(
-                gid.reshape(n_dev, per),
-                bins.reshape(n_dev, per),
-                mask.reshape(n_dev, per),
-            ))[0]
-            want = np.asarray(lh._update_segment(
-                lh.init(G), jnp.asarray(gid), jnp.asarray(bins),
-                jnp.asarray(mask), G))
-            np.testing.assert_array_equal(want, merged, err_msg=form)
+        f = jax.jit(shard_map(
+            shard_fn, mesh=mesh,
+            in_specs=(P("agents"), P("agents"), P("agents")),
+            out_specs=P("agents"),
+        ))
+        f = serialize_cpu_collectives(f, mesh)
+        merged = np.asarray(f(
+            gid.reshape(n_dev, per),
+            bins.reshape(n_dev, per),
+            mask.reshape(n_dev, per),
+        ))[0]
+        want = np.asarray(lh._update_segment(
+            lh.init(G), jnp.asarray(gid), jnp.asarray(bins),
+            jnp.asarray(mask), G))
+        np.testing.assert_array_equal(want, merged, err_msg=form)

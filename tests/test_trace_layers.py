@@ -323,9 +323,9 @@ px.display(df, 'out')
 @pytest.mark.parametrize("backend,ms,slots,form", [
     # by-status' shape on the chip: 128 slots
     ("tpu", 64, 128, "pallas"),
-    # past SKETCH_KERNEL_MAX_GROUPS a 4,096-row bucket is too small for the
-    # sorted form (its cell space would pass four times the rows)
-    ("tpu", 1, 8192, "segment"),
+    # past SKETCH_KERNEL_MAX_GROUPS the tiles kernel, from a 4,096-row
+    # bucket on
+    ("tpu", 1, 8192, "tiles"),
     (None, 64, 128, "segment"),
 ])
 def test_chain_span_says_the_sketchs_form(
@@ -334,7 +334,7 @@ def test_chain_span_says_the_sketchs_form(
     what `ops/sketch.sketch_form` says for each feed where the chain is
     traced, which is what the sketch's update dispatches on: the Pallas
     kernel where the kernels are traced for the TPU, up to its cap, on
-    either arm; the scatter past the cap for a small feed, and anywhere
+    either arm, and the tiles kernel past it; the scatter anywhere
     else."""
     from pixie_tpu.ops import groupby
     from pixie_tpu.ops import sketch
@@ -351,6 +351,35 @@ def test_chain_span_says_the_sketchs_form(
     for c in chains:
         assert c.attributes["groups"] == slots
         assert c.attributes["sketch_form"] == form, c.attributes
+
+
+def test_a_past_cap_chain_counts_the_sketch_state_it_reads_back(
+        monkeypatch, fresh_ring):
+    """A p50 over 8,192 slots traced for the TPU (the tiles kernel): each
+    chain span says `sketch_state_bytes`, the [8192, 514] f32 state the
+    agent reads back to ship as partial state, on either arm; the broker's
+    merger finalizes the live groups' histograms under a `sketch_finalize`
+    span that says their count and bytes."""
+    from pixie_tpu.ops import groupby
+    from pixie_tpu.ops.sketch import LogHistogram
+
+    monkeypatch.setattr(groupby, "dispatch_backend", lambda: "tpu")
+    width = LogHistogram().width
+    with serving(_mkstore(1, time.time_ns(), 3072), monkeypatch) as client:
+        for _ in range(autotune.COLD_PROBE_PERIOD):
+            out = client.execute_script(SKETCH_QUERY.format(ms=1))
+    groups = next(iter(out.values())).num_rows
+    chains = _chains(_agent_spans())
+    assert [c.attributes["arm"] for c in chains] == ["cpu"] * 3 + ["device"]
+    for c in chains:
+        assert c.attributes["sketch_form"] == "tiles"
+        assert c.attributes["sketch_state_bytes"] == 8192 * width * 4
+    finals = [s for s in trace.recent() if s.name == "sketch_finalize"]
+    assert len(finals) == autotune.COLD_PROBE_PERIOD
+    for s in finals:
+        assert s.service == "broker"
+        assert s.attributes == {"groups": groups,
+                                "sketch_state_bytes": groups * width * 4}
 
 
 def test_a_chain_without_a_sketch_says_no_sketch_form(monkeypatch,
@@ -916,3 +945,102 @@ def test_readers_read_nothing_where_there_is_nothing(monkeypatch, fresh_ring):
     monkeypatch.delattr(trace, "recent")
     for name in READERS:
         assert _reader(name).read(run) is None
+
+
+# ------------------------------------------------- the service map's readers
+
+#: the three readers of the service map's own spans and kernel
+SERVICE_MAP_READERS = ("sketch_state_bytes_per_query", "sketch_finalize_ms",
+                       "sketch_kernel_roofline")
+
+
+def _service_map_window(tr: trace.Tracer) -> dict:
+    """Two service-map queries and a status table, from t=1000 ms, each a
+    trace of an `exec` root, its chain spans (a device-arm one with the
+    sketch's state read back and its group count) and the broker's
+    `sketch_finalize` spans; the trace of the traced span's device: the
+    tiles kernel in two entries, 20 ms in all, beside its sort's 30 ms."""
+    import json
+
+    def span(name, t0_ms, dur_ms, trace_id="", parent="", on=tr, **attrs):
+        sp = on.start_span(name, trace_id=trace_id or None,
+                           parent_span_id=parent, attributes=attrs or None,
+                           start_ns=int(t0_ms * MS))
+        on.finish(sp, end_ns=int((t0_ms + dur_ms) * MS))
+        return sp
+
+    broker = trace.Tracer("broker")
+    for t0, chains, finals in ((1000, (30_000_000,), (40, 10)),
+                               (1400, (12_000_000, 8_000_000), (30,)),
+                               (1800, (1_000_000,), (5,))):
+        root = span("exec", t0, 200)
+        for i, nbytes in enumerate(chains):
+            span("scan(http_events)->partial_agg", t0 + 1 + i, 150,
+                 root.trace_id, root.span_id, engine="device_chain",
+                 arm="device", groups=32768, sketch_form="tiles",
+                 sketch_state_bytes=nbytes)
+        for i, ms in enumerate(finals):
+            span("sketch_finalize", t0 + 210 + i * 50, ms, root.trace_id,
+                 on=broker, groups=14080)
+    with open(os.path.join(os.path.dirname(METRICS_DIR), "configs",
+                           "pem_http_edges_512m.json")) as f:
+        config = json.load(f)
+    digest = {"engine": "device"}
+    t0 = int(config["time_base_ns"])
+    queries = [{"script": "service_edges", "start_time": t0, "digest": digest,
+                "t0_unix_ns": 1000 * MS, "wall_ms": 300.0},
+               {"script": "service_edges", "start_time": t0, "digest": digest,
+                "t0_unix_ns": 1400 * MS, "wall_ms": 300.0},
+               {"script": "http_by_status", "start_time": t0,
+                "digest": digest, "t0_unix_ns": 1800 * MS, "wall_ms": 300.0}]
+    scripts = {"service_edges": {"table": "http_events"},
+               "http_by_status": {"table": "http_events"}}
+    device = {"busy_s": 0.5, "lo_unix_ns": 900 * MS, "hi_unix_ns": 2200 * MS,
+              "device_ops": [["fusion.3", 0.1], ["sort.5", 0.03],
+                             ["px_sketch_tiles.1", 0.015],
+                             ["px_sketch_tiles", 0.005]]}
+    return {"queries": queries, "window_s": 1.2, "config": config,
+            "scripts": scripts, "trace": device,
+            "peaks": {"hbm_bytes_per_s": 819e9, "bf16_flops_per_s": 197e12}}
+
+
+@pytest.mark.parametrize("hi_ms,shares", [(2200, 2.0), (1476, 1.5)])
+def test_service_map_readers_compute_from_hand_made_spans(fresh_ring, hi_ms,
+                                                         shares):
+    """The status table's spans set nothing: the state bytes a service-map
+    query read back (30 MB and 12 + 8 MB: the lower median), its summed
+    finalizes (50 and 30 ms), and the update's device time (its sort's
+    30 ms and the tiles kernel's 20 ms) against the bytes of two updates of
+    4,587,520 rows (the whole table: `latency`, `upid` and `remote_addr`) at
+    32,768 groups (the [G, 544] f32 state once) at 819 GB/s.  A traced span
+    that ends half way through the second query's chain counts half of its
+    bytes."""
+    from data import column_bytes
+
+    run = _service_map_window(trace.Tracer("pem0"))
+    run["trace"]["hi_unix_ns"] = hi_ms * MS
+    one = (4_587_520 * column_bytes(run["config"], "http_events",
+                                    ["latency", "upid", "remote_addr"])
+           + 32768 * 544 * 4)
+    got = [_reader(n).read(run) for n in SERVICE_MAP_READERS]
+    assert got == pytest.approx([20_000_000.0, 30.0,
+                                 100.0 * shares * one / 819e9 / 0.050])
+
+
+@pytest.mark.parametrize("name", SERVICE_MAP_READERS)
+def test_service_map_readers_read_nothing_where_there_is_nothing(
+        name, monkeypatch, fresh_ring, capsys):
+    """An empty window, a window of status tables, a program without the
+    ring or without the kernel (the parent, laid under these readers by
+    the benchmark): nothing to read, and no error."""
+    run = _service_map_window(trace.Tracer("pem0"))
+    reader = _reader(name)
+    assert reader.read(run) is not None
+    assert reader.read(dict(run, queries=[], trace=None)) is None
+    widgets = [dict(q, script="http_by_status") for q in run["queries"]]
+    assert reader.read(dict(run, queries=widgets)) is None
+    if name == "sketch_kernel_roofline":
+        run["trace"]["device_ops"] = [["fusion.3", 0.1], ["sort.4", 0.3]]
+    else:
+        monkeypatch.delattr(trace, "recent")
+    assert reader.read(run) is None
