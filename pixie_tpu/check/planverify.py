@@ -36,6 +36,8 @@ Checked invariants (each names itself in the raised PlanVerifyError):
       stage's n_parts (the shard-axis consistency contract)
   channel-agg-mismatch     an agg_state channel's declared agg (what the
       merger finalizes with) matches the partial agg its producers run
+  sole-producer            a partial agg marked as its channel's sole
+      producer (it may ship finalized sketches) has no other producer
   matview-prefix-divergence all producers of an agg_state channel
       canonicalize to the SAME standing-view key (broker matcher and agent
       maintainers must agree on what the state is a function of)
@@ -595,6 +597,12 @@ def verify_distributed(dp, schemas: dict, registry=None) -> None:
                         f"producer {a!r} computes partial agg "
                         f"{_agg_sig(pagg)} but the merger finalizes "
                         f"{_agg_sig(ch.agg)}", pagg, where)
+                if pagg.sole_producer and len(ch.producers) != 1:
+                    raise PlanVerifyError(
+                        "sole-producer",
+                        f"producer {a!r} is marked the channel's sole "
+                        f"producer but it has {len(ch.producers)}: a "
+                        "finalized sketch cannot be merged", pagg, where)
             # broker-side matview matcher and agent-side maintainers key
             # standing state off the SAME canonicalized prefix, and every
             # producer's fragment is a clone of ONE logical subgraph.

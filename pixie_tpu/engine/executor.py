@@ -1129,12 +1129,14 @@ class _FinalizedCol:
 
 
 @_contextlib.contextmanager
-def sketch_finalize_span(uda, state):
+def sketch_finalize_span(uda, state, finalized: bool = False):
     """A `sketch_finalize` span around the finalize of a quantile sketch's
     host state (its histograms as `groups`, their bytes as
     `sketch_state_bytes`) into its column, wherever that runs: the
-    merger's `finalize_partial`, a local aggregate's host finalize.  No
-    span for any other aggregate."""
+    merger's `finalize_partial`, a local aggregate's host finalize.  Where
+    the producer `finalized` the sketch on its device, `state` is its bins
+    and the span is around their formatting, with `sketch_state_bytes` 0.
+    No span for any other aggregate."""
     from pixie_tpu import trace
     from pixie_tpu.udf.udf import QuantilesUDA, QuantileUDA
 
@@ -1143,7 +1145,7 @@ def sketch_finalize_span(uda, state):
         return
     state = np.asarray(state)
     with trace.span("sketch_finalize", groups=int(state.shape[0]),
-                    sketch_state_bytes=int(state.nbytes)):
+                    sketch_state_bytes=0 if finalized else int(state.nbytes)):
         yield
 
 
@@ -2896,13 +2898,18 @@ class PlanExecutor:
         def read_back(out: dict) -> dict:
             # `out` was pulled from the device: the chain's span counts the
             # bytes of quantile-sketch state in it as `sketch_state_bytes`
-            # (0 where the sketches were finalized on the device)
+            # (0 where the sketches were finalized on the device), and the
+            # sketch columns finalized there as `sketch_device_finalized`
             if sketched:
+                done = [name for name in sketches
+                        if isinstance(out.get(name), _FinalizedCol)]
                 span["sketch_state_bytes"] = span.get(
                     "sketch_state_bytes", 0) + sum(
                     np.asarray(leaf).nbytes for name in sketches
-                    if not isinstance(out.get(name), _FinalizedCol)
+                    if name not in done
                     for leaf in jax.tree.leaves(out.get(name)))
+                span["sketch_device_finalized"] = span.get(
+                    "sketch_device_finalized", 0) + len(done)
             return out
 
         def join(seen: dict, form: str, rows: int) -> str:
@@ -2960,7 +2967,11 @@ class PlanExecutor:
             # path qualifies too: its per-feed states are already in-mesh
             # merged (replicated), and the merge+finalize jit runs under
             # GSPMD like any other consumer.
-            device_merge_ok = (backend == "device"
+            # A partial that finalizes its sketches (_partial_agg_batch)
+            # takes the same merge + finalize execution on the CPU arm: the
+            # router's two arms then both ship finalized sketches.
+            device_merge_ok = ((backend == "device"
+                                or getattr(self, "_sole_finalize", False))
                                and not getattr(self, "_defer_active", False))
             # Single-feed fusion: when the snapshot metadata predicts exactly
             # one feed (the interactive warm-query shape — 1M rows coalesce
@@ -3107,9 +3118,9 @@ class PlanExecutor:
                                         for name, uda, _dt in init_specs}
                         rt = {name: uda.reduce_ops()
                               for name, uda, _dt in init_specs}
-                        # the distributed partial path ships RAW state (it must
-                        # stay mergeable across agents): device-merge the feeds
-                        # but never finalize
+                        # the distributed partial path ships RAW state where
+                        # it must stay mergeable across agents: device-merge
+                        # the feeds but never finalize
                         fin_ok = not getattr(self, "_partial_wire", False)
                         spec_key = ("mfz", fin_ok, tuple(
                             (name, type(uda).__qualname__,
@@ -3149,9 +3160,15 @@ class PlanExecutor:
 
     def _partial_agg_batch(self, op: AggOp):
         """Distributed partial path: seen groups as VALUES + raw UDA state
-        (see pixie_tpu.parallel.partial.PartialAggBatch)."""
+        (see pixie_tpu.parallel.partial.PartialAggBatch).  The sole
+        producer of its channel (`op.sole_producer`) finalizes its
+        device-finalizable aggregates (the quantile sketches) where its
+        chain runs and ships their bins instead: no other producer's state
+        is added to them.  An aggregate with none ships raw state as any
+        other partial does."""
         self._defer_active = self.defer_agg_pull
-        self._partial_wire = True  # ship raw state; no device finalize
+        self._sole_finalize = self._finalizes_on_producer(op)
+        self._partial_wire = not self._sole_finalize
         try:
             keys, udas, state_np, seen_name, in_types, val_dicts = self._agg_state(op)
         except GroupKeyFallback:
@@ -3159,6 +3176,7 @@ class PlanExecutor:
         finally:
             self._defer_active = False
             self._partial_wire = False
+            self._sole_finalize = False
         if val_dicts:
             raise Internal(
                 "dict-valued aggregates must ship rows, not partial state "
@@ -3190,6 +3208,13 @@ class PlanExecutor:
             )
         return self._finish_partial_batch(keys, udas, state_np, seen_name,
                                           in_types)
+
+    def _finalizes_on_producer(self, op: AggOp) -> bool:
+        """A partial of its channel's sole producer with a device-
+        finalizable aggregate (a quantile sketch) finalizes it where its
+        chain runs: no other producer's state is added to it."""
+        return op.sole_producer and any(
+            self.registry.uda(ae.fn).device_finalize for ae in op.values)
 
     @staticmethod
     def _partial_layout_fp(keys, udas, in_types, seen_name):
@@ -3233,13 +3258,19 @@ class PlanExecutor:
                 else:
                     key_cols[k.name] = col
         states = {}
+        finalized = set()
         for out_name, _uda, _vb in udas:
             if out_name == seen_name:
                 continue
-            states[out_name] = jax.tree.map(lambda x: np.asarray(x)[gids], state_np[out_name])
+            st = state_np[out_name]
+            if isinstance(st, _FinalizedCol):
+                finalized.add(out_name)
+                st = st.col
+            states[out_name] = jax.tree.map(lambda x: np.asarray(x)[gids], st)
         return PartialAggBatch(
             key_cols=key_cols, key_dtypes=key_dtypes, states=states,
             in_types={k: v for k, v in in_types.items()},
+            finalized=frozenset(finalized),
         )
 
     # ------------------------------------------------- multi-query gang
@@ -3395,28 +3426,30 @@ class PlanExecutor:
                                       + len(per_member[0]))
             pull = []
             for s, parts in zip(setups, per_member):
+                fin = self._finalizes_on_producer(s.op)
                 if not parts:  # empty scan: identity state per member
-                    pull.append({name: uda.init(s.num_groups, in_dt)
-                                 for name, uda, in_dt in s.init_specs})
-                elif len(parts) == 1:
-                    pull.append(parts[0])
+                    pull.append(({}, {name: uda.init(s.num_groups, in_dt)
+                                      for name, uda, in_dt in s.init_specs}))
+                elif len(parts) == 1 and not fin:
+                    pull.append(({}, parts[0]))
                 else:
-                    # same per-member device merge the unbatched partial
-                    # path runs (finalize_ok False: raw state must stay
-                    # mergeable across agents) — shared cache key included
+                    # same per-member device merge (+ finalize) the
+                    # unbatched partial path runs — shared cache key
+                    # included
                     rt = {name: uda.reduce_ops()
                           for name, uda, _dt in s.init_specs}
                     by_name = {name: uda for name, uda, _dt in s.init_specs}
-                    spec_key = ("mfz", False, tuple(
+                    spec_key = ("mfz", fin, tuple(
                         (name, type(uda).__qualname__,
                          getattr(uda, "q", None))
                         for name, uda, _dt in s.init_specs))
-                    _finals, rest = _merge_finalize_fn(
-                        spec_key, rt, by_name, finalize_ok=False)(*parts)
-                    pull.append(rest)
+                    pull.append(_merge_finalize_fn(
+                        spec_key, rt, by_name, finalize_ok=fin)(*parts))
             pulled = transfer.pull(pull)
         out = {}
-        for s, state_np in zip(setups, pulled):
+        for s, (finals, state_np) in zip(setups, pulled):
+            state_np = {**state_np,
+                        **{k: _FinalizedCol(v) for k, v in finals.items()}}
             out[s.op.id] = self._finish_partial_batch(
                 s.keys, s.udas, state_np, s.seen_name, s.in_types)
         self.stats["mq_fused"] = self.stats.get("mq_fused", 0) + len(setups)

@@ -185,7 +185,8 @@ class StreamQuery:
         node = frag.add(src)
         for op in chain:
             node = frag.add(dataclasses.replace(op, id=-1), parents=[node])
-        partial = dataclasses.replace(agg, id=-1, partial=True)
+        partial = dataclasses.replace(agg, id=-1, partial=True,
+                                      sole_producer=False)
         node = frag.add(partial, parents=[node])
         frag.add(ResultSinkOp(channel=self.CHANNEL, payload="agg_state"), parents=[node])
 

@@ -505,6 +505,8 @@ class MatViewManager:
         agg = copy.copy(pref.agg)
         agg.id = -1
         agg.partial = True
+        # standing state is folded into again: it stays raw
+        agg.sole_producer = False
         p.add(agg, parents=[node])
         p.add(ResultSinkOp(channel="mv", payload="agg_state"), parents=[agg])
         ex = PlanExecutor(p, self.store, self.registry, mesh=mesh,

@@ -110,6 +110,7 @@ def view_key(prefix: ViewPrefix) -> str:
     agg_sig = _op_sig(prefix.agg)
     agg_sig.pop("partial", None)
     agg_sig.pop("finalize", None)
+    agg_sig.pop("sole_producer", None)
     canon = {
         "table": prefix.head.table,
         "tablet": prefix.head.tablet,

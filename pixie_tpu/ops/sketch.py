@@ -488,47 +488,90 @@ class LogHistogram:
         val = np.power(self.gamma, i - 0.5)
         return np.where(np.asarray(idx) <= 0, 0.0, val)
 
-    def quantile(self, hist: np.ndarray, qs: list[float]) -> np.ndarray:
-        """Host-side finalize: quantiles per group. hist: [G, width] → [G, len(qs)]."""
+    def bin_values(self, idx: np.ndarray) -> np.ndarray:
+        """Bin indices as `quantile_bins` or `quantile_bins_device` give
+        them → quantile values (host): NaN for an empty group's -1."""
+        idx = np.asarray(idx)
+        return np.where(idx < 0, np.nan, self.bin_value(idx))
+
+    def quantile_bins(self, hist: np.ndarray, qs: list[float]) -> np.ndarray:
+        """Host rank rule: [G, width] → [G, len(qs)] int32, per group and q
+        the first bin whose cumulative count reaches q * total (in f64),
+        -1 for an empty group."""
         h = np.asarray(hist, dtype=np.float64)
-        totals = h.sum(axis=-1, keepdims=True)
+        totals = h.sum(axis=-1)
         cum = np.cumsum(h, axis=-1)
-        out = np.empty((h.shape[0], len(qs)), dtype=np.float64)
+        out = np.empty((h.shape[0], len(qs)), dtype=np.int32)
         for j, q in enumerate(qs):
-            target = np.clip(q, 0.0, 1.0) * totals[:, 0]
+            target = np.clip(q, 0.0, 1.0) * totals
             # Per-row searchsorted: first bin where cum >= target.
             idx = (cum < target[:, None]).sum(axis=-1)
-            idx = np.minimum(idx, h.shape[1] - 1)
-            out[:, j] = self.bin_value(idx)
-        out[totals[:, 0] == 0] = np.nan
+            out[:, j] = np.minimum(idx, h.shape[1] - 1)
+        out[totals == 0] = -1
         return out
 
-    def quantile_device(self, hist: jax.Array, qs: list[float]) -> jax.Array:
-        """DEVICE finalize (same rank rule as `quantile`): [G, width] →
-        [G, len(qs)] f64.  Rationale: the histogram is the big part of an
-        agg's state ([G, 514] f32 — ~2 MB at G≈1024, per sketch, per feed)
-        while the [G, nq] RESULT is kilobytes: reading back the result
-        instead of the state is one small wave, so finalize belongs
-        device-side.
-        """
-        # f32 for the [G, width] cumsum/compare (TPU f64 is software-emulated
-        # and a f64 cumsum becomes a serialized scan — measured ~4x
-        # whole-query regression).  The final power runs in f64 over the
-        # tiny [G, nq] result, matching the host finalize (`quantile`)
-        # exactly while group counts stay below 2^24 (above that, f32
-        # cum/target rounding near a rank boundary can pick the adjacent
-        # bin — a sub-bucket-width deviation).
-        h = hist.astype(jnp.float32)
-        totals = h.sum(axis=-1, keepdims=True)
-        cum = jnp.cumsum(h, axis=-1)
-        qv = jnp.asarray(qs, dtype=jnp.float32)
-        target = jnp.clip(qv, 0.0, 1.0)[None, :] * totals  # [G, nq]
-        idx = (cum[:, None, :] < target[:, :, None]).sum(axis=-1)
-        idx = jnp.minimum(idx, h.shape[-1] - 1)
-        val = jnp.power(jnp.float64(self.gamma),
-                        idx.astype(jnp.float64) - 1.5)
-        out = jnp.where(idx <= 0, 0.0, val)
-        return jnp.where(totals > 0, out, jnp.nan)
+    def quantile(self, hist: np.ndarray, qs: list[float]) -> np.ndarray:
+        """Host-side finalize: quantiles per group. hist: [G, width] → [G, len(qs)]."""
+        return self.bin_values(self.quantile_bins(hist, qs))
+
+    def quantile_bins_device(self, hist: jax.Array,
+                             qs: list[float]) -> jax.Array:
+        """DEVICE finalize: `quantile_bins` on the device, the same bins
+        for every group of fewer than 2^31 rows: [G, width] → [G, len(qs)]
+        int32.  The histogram is the big part of an agg's state ([G, 514]
+        f32, per sketch) while the [G, nq] bins are kilobytes: reading
+        back these instead of the state is one small wave.  The host turns
+        them into values (`bin_values`), so a value is the host's own to
+        the bit (XLA's f64 power is not numpy's in the last place).
+
+        The cumulative counts are int32, exact to 2^31 rows a group (f32
+        is exact only to 2^24), and the host's `cum < q * total` over
+        integer counts is `cum < _ceil_rank(q, total)`, exactly."""
+        cum = jnp.cumsum(hist.astype(jnp.int32), axis=-1)
+        totals = cum[:, -1]
+        rank = jnp.stack([_ceil_rank(q, totals) for q in qs], axis=-1)
+        idx = (cum[:, None, :] < rank[:, :, None]).sum(axis=-1,
+                                                       dtype=jnp.int32)
+        idx = jnp.minimum(idx, hist.shape[-1] - 1)
+        return jnp.where(totals[:, None] > 0, idx, -1)
+
+
+def _ceil_rank(q: float, totals: jax.Array) -> jax.Array:
+    """ceil(clip(q, 0, 1) * total) where the product is the f64 one, rounded
+    to nearest as the host's is, in integer arithmetic alone: [G] int32 →
+    [G] int32.  q is n / 2**k exactly (a float is a dyadic rational), so
+    q * total is n * total / 2**k, whose whole part and fraction are
+    integers; the f64 product is the whole part where the fraction is at
+    most half an f64 ulp of it (ties go to the even whole part), and above
+    it otherwise."""
+    n, d = min(max(float(q), 0.0), 1.0).as_integer_ratio()
+    k = d.bit_length() - 1
+    if k >= 96:  # q * total < 2**-12: every nonempty group's rank is 1
+        return (totals > 0).astype(jnp.int32)
+    t = totals.astype(jnp.uint64)
+    if k < 32:  # n <= 2**k: the product fits in 63 bits
+        prod = t * np.uint64(n)
+        whole = prod >> np.uint64(k)
+        frac_hi = jnp.zeros_like(prod)
+        frac_lo = prod & np.uint64((1 << k) - 1)
+    else:  # the product is hi * 2**32 + lo mod 2**32, hi < 2**53
+        lo = t * np.uint64(n & 0xFFFFFFFF)
+        hi = t * np.uint64(n >> 32) + (lo >> np.uint64(32))
+        whole = hi >> np.uint64(k - 32)
+        frac_hi = hi & np.uint64((1 << (k - 32)) - 1)
+        frac_lo = lo & np.uint64(0xFFFFFFFF)
+    # half an ulp of whole (< 2**31) is 2**(e - 53), e = floor(log2(whole)):
+    # 2**s in units of 2**-k; any fraction lifts a whole part of 0
+    e = 31 - jax.lax.clz(whole.astype(jnp.uint32)).astype(jnp.int32)
+    s = jnp.where(whole > 0, k + e - 53, -1)
+    one = jnp.uint64(1)
+    bit_lo = jnp.left_shift(one, jnp.clip(s, 0, 31).astype(jnp.uint64))
+    bit_hi = jnp.left_shift(one, jnp.clip(s - 32, 0, 63).astype(jnp.uint64))
+    above = jnp.where(
+        s < 0, (frac_hi > 0) | (frac_lo > 0),
+        jnp.where(s < 32, (frac_hi > 0) | (frac_lo > bit_lo),
+                  (frac_hi > bit_hi) | ((frac_hi == bit_hi) & (frac_lo > 0))))
+    return (whole + above.astype(jnp.uint64)).astype(jnp.int32)
 
 
 def sketch_form(n: int, num_groups: int,

@@ -245,6 +245,9 @@ class DistributedPlanner:
                 partial = copy.copy(agg)
                 partial.id = -1
                 partial.partial = True
+                # one producer (a mesh agent is one): nothing is added to its
+                # state downstream, so it may ship its sketches finalized
+                partial.sole_producer = len(prods) == 1
                 ap = agent_plans[a.name]
                 ap.add(partial, parents=[cp])
                 ap.add(

@@ -36,6 +36,10 @@ class PartialAggBatch:
     states: dict
     #: uda out_name -> input DataType (None for nullary)
     in_types: dict
+    #: out_names whose `states` entry the producer already finalized (the
+    #: sole producer's quantile sketches as [G, nq] bins, `uda.
+    #: finalize_device`'s output): formatted, never added to
+    finalized: frozenset = frozenset()
 
     @property
     def num_groups(self) -> int:
@@ -96,6 +100,11 @@ def combine_partials(
     already happened on each producer's mesh.  The result is still raw state
     (use finalize_partial), which is what lets the streaming executor carry
     open-window state across polls and keep merging into it.
+
+    A finalized column (`PartialAggBatch.finalized`: only a channel's sole
+    producer ships one) is never added: parts that share no group, as
+    that producer's chunks, concatenate, and parts that share one raise
+    Internal (the planner marked a channel of two producers).
     """
     parts = [p for p in partials if p.num_groups > 0]
     if not parts:
@@ -128,8 +137,22 @@ def combine_partials(
 
     key_cols = {k: cols_cat[k][first_idx] for k in keys}
 
+    finalized = frozenset().union(*(p.finalized for p in parts))
+    if finalized and g != len(inverse):
+        raise Internal(
+            f"combine_partials: {sorted(finalized)} arrived finalized from "
+            "parts that share a group; only a channel's sole producer may "
+            "finalize")
     states: dict = {}
     for ae in agg.values:
+        if ae.out_name in finalized:
+            if not all(ae.out_name in p.finalized for p in parts):
+                raise Internal(
+                    f"combine_partials: {ae.out_name!r} is finalized in "
+                    "some parts and raw in others")
+            states[ae.out_name] = np.concatenate(
+                [p.states[ae.out_name] for p in parts])[first_idx]
+            continue
         uda = registry.uda(ae.fn)
         ops_tree = uda.reduce_ops()
         # Concatenate each leaf across producers, then segment-reduce by the
@@ -160,6 +183,7 @@ def combine_partials(
         key_dtypes=dict(first.key_dtypes),
         states=states,
         in_types=dict(first.in_types),
+        finalized=finalized,
     )
 
 
@@ -173,6 +197,7 @@ def slice_partial(pb: PartialAggBatch, idx: np.ndarray) -> PartialAggBatch:
             for name, tree in pb.states.items()
         },
         in_types=dict(pb.in_types),
+        finalized=pb.finalized,
     )
 
 
@@ -212,8 +237,11 @@ def finalize_partial(
         # finalize_host is host-pure by contract (no instance state from
         # init) — calling uda.init here would dispatch a device op with a
         # poll-varying group-count shape, i.e. a fresh XLA compile per poll.
-        with sketch_finalize_span(uda, pb.states[ae.out_name]):
-            col = uda.finalize_host(pb.states[ae.out_name])
+        st = pb.states[ae.out_name]
+        done = ae.out_name in pb.finalized
+        with sketch_finalize_span(uda, st, finalized=done):
+            col = (uda.finalize_from_device(st) if done
+                   else uda.finalize_host(st))
         out_dt = uda.out_type(pb.in_types.get(ae.out_name))
         vals = np.asarray(col)
         out_dtypes[ae.out_name] = out_dt

@@ -68,7 +68,8 @@ def _op_desc(op) -> str:
             f"{v.out_name}={v.fn}({v.arg or ''})" for v in op.values
         )
         flags = "".join(
-            f" [{f}]" for f in ("windowed", "partial", "finalize")
+            f" [{f}]" for f in ("windowed", "partial", "finalize",
+                                "sole_producer")
             if getattr(op, f)
         )
         return f"Agg by={op.groups} {vals}{flags}"

@@ -214,7 +214,8 @@ def _merge_sibling_aggs(fused: Plan) -> Plan:
         ps = fused.parents(op)
         if len(ps) != 1:
             continue
-        key = (ps[0].id, tuple(op.groups), op.partial, op.finalize)
+        key = (ps[0].id, tuple(op.groups), op.partial, op.finalize,
+               op.sole_producer)
         sibs.setdefault(key, []).append(op)
 
     replace: dict[int, AggOp] = {}
@@ -239,7 +240,8 @@ def _merge_sibling_aggs(fused: Plan) -> Plan:
             continue
         merged = AggOp(groups=list(ops[0].groups), values=vals,
                        windowed=False, partial=ops[0].partial,
-                       finalize=ops[0].finalize)
+                       finalize=ops[0].finalize,
+                       sole_producer=ops[0].sole_producer)
         for o in ops:
             replace[o.id] = merged
     if not replace:

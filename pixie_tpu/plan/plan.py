@@ -179,6 +179,9 @@ class AggOp:
     partial/finalize flags mirror the reference's split for distributed partial
     aggregation; in the TPU engine `partial` means "emit device state", and
     `finalize` means "merge states via mesh collective, then finalize".
+    `sole_producer` marks a partial whose channel has exactly one producer
+    (the distributed planner sets it): no other agent's state is added to
+    its own, so it may ship its quantile sketches finalized.
     """
 
     id: int = -1
@@ -187,6 +190,7 @@ class AggOp:
     windowed: bool = False
     partial: bool = False
     finalize: bool = False
+    sole_producer: bool = False
 
     kind = "agg"
 
@@ -199,6 +203,7 @@ class AggOp:
             "windowed": self.windowed,
             "partial": self.partial,
             "finalize": self.finalize,
+            "sole_producer": self.sole_producer,
         }
 
 
@@ -436,6 +441,7 @@ def _op_from_dict(d: dict):
             windowed=d.get("windowed", False),
             partial=d.get("partial", False),
             finalize=d.get("finalize", False),
+            sole_producer=d.get("sole_producer", False),
         )
     if k == "limit":
         return LimitOp(n=d["n"])

@@ -30,6 +30,7 @@ Kinds:
   json         — control messages ({} metadata only)
   host_batch   — HostBatch: dtypes, dictionaries, columns
   partial_agg  — PartialAggBatch: key values + flattened UDA state leaves
+                 (`finalized` names those its producer finalized)
 """
 from __future__ import annotations
 
@@ -349,6 +350,8 @@ def encode_partial_agg(pb, extra_meta: dict | None = None) -> bytes:
         "states": states_meta,
         "key_order": list(pb.key_cols),
     }
+    if pb.finalized:
+        meta["finalized"] = sorted(pb.finalized)
     if extra_meta:
         meta.update(extra_meta)
     return _frame("partial_agg", meta, bufs)
@@ -495,6 +498,7 @@ def decode_frame(data: bytes):
                 k: (DT(v) if v is not None else None)
                 for k, v in meta["in_types"].items()
             },
+            finalized=frozenset(meta.get("finalized", ())),
         )
         pb.wire_meta = meta  # type: ignore[attr-defined]
         return kind, pb

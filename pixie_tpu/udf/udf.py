@@ -449,7 +449,12 @@ class QuantileUDA(UDA):
     def finalize_device(self, state):
         from pixie_tpu.ops.sketch import LogHistogram
 
-        return LogHistogram().quantile_device(state, [self.q])[:, 0]
+        return LogHistogram().quantile_bins_device(state, [self.q])[:, 0]
+
+    def finalize_from_device(self, pulled_np) -> np.ndarray:
+        from pixie_tpu.ops.sketch import LogHistogram
+
+        return LogHistogram().bin_values(pulled_np)
 
 
 class QuantilesUDA(UDA):
@@ -493,10 +498,12 @@ class QuantilesUDA(UDA):
     def finalize_device(self, state):
         from pixie_tpu.ops.sketch import LogHistogram
 
-        return LogHistogram().quantile_device(state, list(self.QS))
+        return LogHistogram().quantile_bins_device(state, list(self.QS))
 
     def finalize_from_device(self, pulled_np) -> np.ndarray:
-        return self._format(np.asarray(pulled_np))
+        from pixie_tpu.ops.sketch import LogHistogram
+
+        return self._format(LogHistogram().bin_values(pulled_np))
 
 
 # -------------------------------------------------------------------- registry

@@ -249,6 +249,50 @@ def test_mutation_matview_prefix_divergence_rejected():
     assert ei.value.invariant == "matview-prefix-divergence"
 
 
+def test_mutation_sole_producer_on_a_shared_channel_rejected():
+    """One of a channel's two producers is marked its sole producer: it
+    would ship finalized sketches that the merger cannot add to the other
+    producer's state."""
+    dp = _split(AGG_SRC)
+    victim = sorted(dp.agent_plans)[1]
+
+    def mark(ops):
+        for op in ops:
+            if isinstance(op, AggOp):
+                op.sole_producer = True
+        return ops
+
+    dp.agent_plans[victim] = _rebuild_chain(dp.agent_plans[victim], mark)
+    with pytest.raises(PlanVerifyError) as ei:
+        verify_distributed(dp, SCHEMAS)
+    assert ei.value.invariant == "sole-producer"
+
+
+@pytest.mark.parametrize("n_agents,n_devices", [(1, None), (1, 4), (2, None),
+                                               (3, 4)])
+def test_split_marks_the_partial_of_a_sole_producer(n_agents, n_devices):
+    """The planner marks each producer's partial agg `sole_producer` where
+    its channel has one producer (a mesh agent is one) and never where it
+    has more; the mark survives the agent plan's wire form, and the split
+    verifies."""
+    spec = _spec(n_agents)
+    for a in spec.agents:
+        if a.has_data_store:
+            a.n_devices = n_devices
+    q = compile_pxl(AGG_SRC, SCHEMAS, now=NOW)
+    dp = DistributedPlanner(spec).plan(q.plan)
+    verify_distributed(dp, SCHEMAS)
+    partials = [op for p in dp.agent_plans.values()
+                for op in Plan.from_dict(json.loads(json.dumps(
+                    p.to_dict()))).ops()
+                if isinstance(op, AggOp)]
+    assert len(partials) == n_agents
+    assert all(op.partial and op.sole_producer == (n_agents == 1)
+               for op in partials)
+    (ch,) = [ch for ch in dp.channels.values() if ch.kind == "agg_state"]
+    assert not ch.agg.sole_producer
+
+
 def test_unmutated_splits_verify_clean():
     for src in (AGG_SRC, LIMIT_SRC, JOIN_SRC):
         verify_distributed(_split(src), SCHEMAS)

@@ -447,3 +447,93 @@ class TestPsumMergeParity:
             lh.init(G), jnp.asarray(gid), jnp.asarray(bins),
             jnp.asarray(mask), G))
         np.testing.assert_array_equal(want, merged, err_msg=form)
+
+
+# ------------------------------------------------------------ the finalize
+
+#: every quantile the bundled scripts ask: px.quantiles' five, p50, p99
+FINAL_QS = [0.01, 0.10, 0.50, 0.90, 0.99]
+
+
+def _at_rank(lh, totals, q):
+    """Two histograms per total: the host's rank for q, less one and
+    itself, in bin 3 and the rest in bin 400, so that a rule one rank off
+    moves the quantile from one of these bins to the other."""
+    rank = np.ceil(np.float64(q) * np.asarray(totals, np.float64))
+    rows = []
+    for total, r in zip(totals, rank):
+        for low in (max(r - 1, 0), r):
+            h = np.zeros(lh.width, np.float32)
+            h[3], h[400] = low, total - low
+            rows.append(h)
+    return np.stack(rows)
+
+
+def _device_is_host(lh, hist, qs):
+    bins = np.asarray(jax.jit(lambda h: lh.quantile_bins_device(h, qs))(
+        jnp.asarray(hist)))
+    np.testing.assert_array_equal(bins, lh.quantile_bins(hist, qs))
+    np.testing.assert_array_equal(lh.bin_values(bins), lh.quantile(hist, qs))
+    return bins
+
+
+@pytest.mark.parametrize("q,totals", [
+    # the first totals at which an f32 target's ceil is not the host's
+    (0.99, [264_799, 264_899]), (0.90, [1_572_869]), (0.01, [13_107_201])])
+def test_device_finalize_is_the_hosts_where_f32_ranks_were_not(lh, q,
+                                                               totals):
+    """The device's bins and values equal the host finalize's to the bit
+    at totals where q * total rounded in f32 gives another rank."""
+    for t in totals:
+        assert (np.ceil(np.float32(q) * np.float32(t))
+                != np.ceil(np.float64(q) * t))
+    bins = _device_is_host(lh, _at_rank(lh, totals, q), [q])
+    assert set(bins[:, 0]) == {3, 400}
+
+
+def test_device_finalize_is_the_hosts_over_random_totals(lh):
+    """Totals from 1 to 2^24 for each of the scripts' quantiles, at the
+    host's rank and one below it, and spread over random bins."""
+    rng = np.random.default_rng(11)
+    totals = rng.integers(1, 1 << 24, 400)
+    hist = [_at_rank(lh, totals, q) for q in FINAL_QS]
+    spread = np.zeros((400, lh.width), np.float32)
+    for row, total in zip(spread, totals):
+        cuts = np.sort(rng.integers(0, total + 1, 6))
+        row[np.sort(rng.choice(lh.width, 7, replace=False))] = np.diff(
+            np.concatenate([[0], cuts, [total]]))
+    _device_is_host(lh, np.concatenate(hist + [spread]), FINAL_QS)
+
+
+def test_device_finalize_of_an_empty_group_is_nan(lh):
+    hist = np.zeros((3, lh.width), np.float32)
+    hist[1, 7] = 5
+    bins = _device_is_host(lh, hist, FINAL_QS)
+    assert (bins[[0, 2]] == -1).all() and (bins[1] == 7).all()
+    values = lh.bin_values(bins)
+    assert np.isnan(values[[0, 2]]).all() and not np.isnan(values[1]).any()
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_device_finalize_compiles_for_a_v5e(lh, monkeypatch, chips):
+    """The service map's finalize (px.quantiles and a p99 over 16,384
+    histograms), as its one chip runs it and as the 2x2 mesh's merge runs
+    it over a replicated state: lowered and compiled for a described v5e
+    (no chip needed), its 64-bit integer rank arithmetic included."""
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from pixie_tpu.udf.udf import QuantilesUDA, QuantileUDA
+
+    monkeypatch.setenv("TPU_LOG_DIR", "disabled")
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    mesh = Mesh(np.array(topo.devices[:chips]), ("agents",))
+    fin = jax.jit(lambda h: (QuantilesUDA().finalize_device(h),
+                             QuantileUDA(0.99).finalize_device(h)))
+    state = jax.ShapeDtypeStruct((16384, lh.width), jnp.float32,
+                                 sharding=NamedSharding(mesh, P()))
+    shapes = jax.eval_shape(fin, state)
+    assert [(s.shape, s.dtype) for s in shapes] == [
+        ((16384, 5), jnp.int32), ((16384,), jnp.int32)]
+    assert fin.lower(state).compile().as_text()

@@ -55,11 +55,12 @@ def fresh_ring(monkeypatch):
 
 
 @contextlib.contextmanager
-def serving(store, monkeypatch):
-    """One Broker and one single-device Agent `pem0` over `store`: tracing
-    and the router's model on (the model fresh), the native whole-plan loop
-    and standing views off, so that every query runs its jitted chain under
-    a routing decision.  Yields the client."""
+def serving(store, monkeypatch, agents: int = 1):
+    """One Broker and one single-device Agent `pem0` over `store` (and
+    `pem1`... beside it, as many as `agents` says, each a producer of its
+    tables): tracing and the router's model on (the model fresh), the
+    native whole-plan loop and standing views off, so that every query runs
+    its jitted chain under a routing decision.  Yields the client."""
     wanted = {"PL_TRACING_ENABLED": True, "PX_AUTOTUNE": True,
               "PX_WHOLEPLAN_NATIVE": False, "PL_MATVIEW_ENABLED": False}
     before = {k: flags.get(k) for k in wanted}
@@ -68,14 +69,15 @@ def serving(store, monkeypatch):
     monkeypatch.setattr(spmd, "default_mesh", lambda: None)
     autotune.MODEL.reset_for_testing()
     broker = Broker(hb_expiry_s=5.0, query_timeout_s=30.0).start()
-    agent = Agent("pem0", "127.0.0.1", broker.port, store=store,
-                  heartbeat_s=1.0).start()
+    started = [Agent(f"pem{i}", "127.0.0.1", broker.port, store=store,
+                     heartbeat_s=1.0).start() for i in range(agents)]
     client = Client("127.0.0.1", broker.port, timeout_s=30.0)
     try:
         yield client
     finally:
         client.close()
-        agent.stop()
+        for agent in started:
+            agent.stop()
         broker.stop()
         autotune.MODEL.reset_for_testing()
         for k, v in before.items():
@@ -353,33 +355,46 @@ def test_chain_span_says_the_sketchs_form(
         assert c.attributes["sketch_form"] == form, c.attributes
 
 
+@pytest.mark.parametrize("producers", [1, 2])
 def test_a_past_cap_chain_counts_the_sketch_state_it_reads_back(
-        monkeypatch, fresh_ring):
-    """A p50 over 8,192 slots traced for the TPU (the tiles kernel): each
-    chain span says `sketch_state_bytes`, the [8192, 514] f32 state the
-    agent reads back to ship as partial state, on either arm; the broker's
-    merger finalizes the live groups' histograms under a `sketch_finalize`
-    span that says their count and bytes."""
+        monkeypatch, fresh_ring, producers):
+    """A p50 over 8,192 slots traced for the TPU (the tiles kernel), on
+    either arm: each chain span says `sketch_state_bytes`, the sketch state
+    the agent reads back, and `sketch_device_finalized`, the sketch columns
+    it finalized where it ran.  The channel's sole producer finalizes its
+    sketch and reads back no state; each of two producers reads back the
+    [8192, 514] f32 state to ship as partial state.  The broker's merger
+    finalizes the live groups under a `sketch_finalize` span that says
+    their count and the bytes of state it finalized: 0 where its producer
+    shipped the sketch finalized."""
     from pixie_tpu.ops import groupby
     from pixie_tpu.ops.sketch import LogHistogram
 
     monkeypatch.setattr(groupby, "dispatch_backend", lambda: "tpu")
     width = LogHistogram().width
-    with serving(_mkstore(1, time.time_ns(), 3072), monkeypatch) as client:
+    sole = producers == 1
+    with serving(_mkstore(1, time.time_ns(), 3072), monkeypatch,
+                 agents=producers) as client:
         for _ in range(autotune.COLD_PROBE_PERIOD):
             out = client.execute_script(SKETCH_QUERY.format(ms=1))
     groups = next(iter(out.values())).num_rows
-    chains = _chains(_agent_spans())
-    assert [c.attributes["arm"] for c in chains] == ["cpu"] * 3 + ["device"]
+    chains = _chains([s for s in trace.recent() if s.service != "broker"])
+    assert len(chains) == autotune.COLD_PROBE_PERIOD * producers
+    if sole:
+        assert [c.attributes["arm"] for c in chains] == (
+            ["cpu"] * 3 + ["device"])
     for c in chains:
         assert c.attributes["sketch_form"] == "tiles"
-        assert c.attributes["sketch_state_bytes"] == 8192 * width * 4
+        assert c.attributes["sketch_state_bytes"] == (
+            0 if sole else 8192 * width * 4)
+        assert c.attributes["sketch_device_finalized"] == int(sole)
     finals = [s for s in trace.recent() if s.name == "sketch_finalize"]
     assert len(finals) == autotune.COLD_PROBE_PERIOD
     for s in finals:
         assert s.service == "broker"
-        assert s.attributes == {"groups": groups,
-                                "sketch_state_bytes": groups * width * 4}
+        assert s.attributes == {
+            "groups": groups,
+            "sketch_state_bytes": 0 if sole else groups * width * 4}
 
 
 def test_a_chain_without_a_sketch_says_no_sketch_form(monkeypatch,
@@ -954,12 +969,14 @@ SERVICE_MAP_READERS = ("sketch_state_bytes_per_query", "sketch_finalize_ms",
                        "sketch_kernel_roofline")
 
 
-def _service_map_window(tr: trace.Tracer) -> dict:
+def _service_map_window(tr: trace.Tracer, finalized: bool = False) -> dict:
     """Two service-map queries and a status table, from t=1000 ms, each a
     trace of an `exec` root, its chain spans (a device-arm one with the
     sketch's state read back and its group count) and the broker's
     `sketch_finalize` spans; the trace of the traced span's device: the
-    tiles kernel in two entries, 20 ms in all, beside its sort's 30 ms."""
+    tiles kernel in two entries, 20 ms in all, beside its sort's 30 ms.
+    `finalized`: the agent finalized the sketch where its chain ran, so
+    its chains read back no state and the broker's spans format bins."""
     import json
 
     def span(name, t0_ms, dur_ms, trace_id="", parent="", on=tr, **attrs):
@@ -978,10 +995,12 @@ def _service_map_window(tr: trace.Tracer) -> dict:
             span("scan(http_events)->partial_agg", t0 + 1 + i, 150,
                  root.trace_id, root.span_id, engine="device_chain",
                  arm="device", groups=32768, sketch_form="tiles",
-                 sketch_state_bytes=nbytes)
+                 sketch_state_bytes=0 if finalized else nbytes,
+                 sketch_device_finalized=int(finalized))
         for i, ms in enumerate(finals):
             span("sketch_finalize", t0 + 210 + i * 50, ms, root.trace_id,
-                 on=broker, groups=14080)
+                 on=broker, groups=14080,
+                 sketch_state_bytes=0 if finalized else 14080 * 514 * 4)
     with open(os.path.join(os.path.dirname(METRICS_DIR), "configs",
                            "pem_http_edges_512m.json")) as f:
         config = json.load(f)
@@ -1004,26 +1023,28 @@ def _service_map_window(tr: trace.Tracer) -> dict:
             "peaks": {"hbm_bytes_per_s": 819e9, "bf16_flops_per_s": 197e12}}
 
 
+@pytest.mark.parametrize("finalized", [False, True])
 @pytest.mark.parametrize("hi_ms,shares", [(2200, 2.0), (1476, 1.5)])
 def test_service_map_readers_compute_from_hand_made_spans(fresh_ring, hi_ms,
-                                                         shares):
+                                                         shares, finalized):
     """The status table's spans set nothing: the state bytes a service-map
-    query read back (30 MB and 12 + 8 MB: the lower median), its summed
-    finalizes (50 and 30 ms), and the update's device time (its sort's
-    30 ms and the tiles kernel's 20 ms) against the bytes of two updates of
-    4,587,520 rows (the whole table: `latency`, `upid` and `remote_addr`) at
-    32,768 groups (the [G, 544] f32 state once) at 819 GB/s.  A traced span
-    that ends half way through the second query's chain counts half of its
-    bytes."""
+    query read back (30 MB and 12 + 8 MB: the lower median; 0 where the
+    agent finalized the sketch on its device), its summed finalizes (50
+    and 30 ms, the broker's formatting of bins alike), and the update's
+    device time (its sort's 30 ms and the tiles kernel's 20 ms) against the
+    bytes of two updates of 4,587,520 rows (the whole table: `latency`,
+    `upid` and `remote_addr`) at 32,768 groups (the [G, 544] f32 state
+    once) at 819 GB/s.  A traced span that ends half way through the
+    second query's chain counts half of its bytes."""
     from data import column_bytes
 
-    run = _service_map_window(trace.Tracer("pem0"))
+    run = _service_map_window(trace.Tracer("pem0"), finalized)
     run["trace"]["hi_unix_ns"] = hi_ms * MS
     one = (4_587_520 * column_bytes(run["config"], "http_events",
                                     ["latency", "upid", "remote_addr"])
            + 32768 * 544 * 4)
     got = [_reader(n).read(run) for n in SERVICE_MAP_READERS]
-    assert got == pytest.approx([20_000_000.0, 30.0,
+    assert got == pytest.approx([0.0 if finalized else 20_000_000.0, 30.0,
                                  100.0 * shares * one / 819e9 / 0.050])
 
 
