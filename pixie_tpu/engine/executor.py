@@ -750,7 +750,7 @@ class ChainKernel:
 
         return jax.jit(step), out_dtypes, out_dicts
 
-    def make_keyed_rows_step(self, key_builders: list, cards: Optional[list],
+    def make_keyed_rows_step(self, key_builders: list, cards: list,
                              value_builders: list):
         """→ jit fn(cols, n_valid, t_lo, t_hi, limit_remaining, luts)
         → (mask, sort keys, values, consumed): the rows the chain lets
@@ -759,13 +759,17 @@ class ChainKernel:
         the aggregates' value columns, for the sorted aggregate.
         `key_builders`: (build, kind) per group key, kind "dict" (codes; a
         null code, -1, takes its row out of `mask`), "float" (a NaN does)
-        or "int".  With `cards` (every key a dictionary code, their pow2
-        cardinalities) the keys are one mixed-radix id, int32 where the
-        product fits and int64 otherwise, and the masked rows take the
-        product itself; without, one array a key behind a live-first key."""
+        or "int".  `cards` has a key's pow2 cardinality where it is known
+        (a dictionary code, a flag) and None where not: the keys of known
+        cardinality are one mixed-radix id, int32 where the product fits
+        and int64 otherwise, whose masked rows take the product itself,
+        and the others follow it as they are; with none known, one array a
+        key behind a live-first key."""
+        packed = [i for i, c in enumerate(cards) if c]
+        loose = [i for i, c in enumerate(cards) if not c]
         packed_dtype = total = None
-        if cards is not None:
-            total = math.prod(cards)
+        if packed:
+            total = math.prod(cards[i] for i in packed)
             packed_dtype = jnp.int32 if total < (1 << 31) else jnp.int64
 
         def step(cols, n_valid, t_lo, t_hi, limit_remaining, luts):
@@ -787,8 +791,11 @@ class ChainKernel:
                 elif k.dtype == jnp.bool_:
                     k = k.astype(jnp.int32)
                 keys.append(k)
-            if packed_dtype is not None:
-                keys = [_gb.combine_codes(keys, cards, packed_dtype)[0]]
+            if packed:
+                keys = [_gb.combine_codes([keys[i] for i in packed],
+                                          [cards[i] for i in packed],
+                                          packed_dtype)[0],
+                        *(keys[i] for i in loose)]
             values = []
             for build in value_builders:
                 v = rows(build(env))
@@ -2264,13 +2271,14 @@ class PlanExecutor:
                     "dictionary")
             key_specs.append((g, sv.dtype, sv.dictionary))
             key_builders.append((sv.build, kind))
-        # every key a dictionary code: one mixed-radix id rides the sorts
-        # where the product of the pow2 cardinalities fits 62 bits
-        cards = None
-        if all(d is not None for _g, _dt, d in key_specs):
-            cards = [next_pow2(max(d.size, 1)) for _g, _dt, d in key_specs]
-            if math.prod(cards) >= (1 << 62):
-                cards = None
+        # the keys of known cardinality (dictionary codes, flags) ride the
+        # sorts as one mixed-radix id where the product of their pow2
+        # cardinalities fits 62 bits, the others after it as they are
+        cards = [next_pow2(max(d.size, 1)) if d is not None
+                 else 2 if dt == DT.BOOLEAN else None
+                 for _g, dt, d in key_specs]
+        if math.prod(c for c in cards if c) >= (1 << 62):
+            cards = [None] * len(cards)
         udas, in_types = [], {}
         val_dicts: dict[str, Dictionary] = {}
         value_names: list[str] = []
@@ -2312,7 +2320,7 @@ class PlanExecutor:
             udas.append((ae.out_name, uda, vi, in_dt))
         rows_step = kern.make_keyed_rows_step(key_builders, cards,
                                               value_builders)
-        n_keys = 1 if cards is not None else len(key_specs)
+        n_keys = any(cards) + sum(not c for c in cards)
         scans = [u for u in udas if u[1].segment_only]
         rest = [u for u in udas if not u[1].segment_only]
 
@@ -2382,6 +2390,10 @@ class PlanExecutor:
             op, sig, dtypes, dicts, chain, time_col, visible)
         t_lo, t_hi = _time_bounds(head)
         compile_s0 = self.stats["compile_s"]
+        from pixie_tpu.udf.udf import QuantilesUDA, QuantileUDA
+
+        sketches = [name for name, uda, _vi, _dt in udas
+                    if isinstance(uda, (QuantileUDA, QuantilesUDA))]
         with self._timed(
             self._chain_label(head, chain, "sorted_agg"),
             ([head.id] if head.id >= 0 else []) + [o.id for o in chain]
@@ -2414,6 +2426,12 @@ class PlanExecutor:
                     found = _take_front(found, front, gb)
                     if rest_step is not None:
                         found["state"].update(rest_step(*after, gb))
+                    if sketches:
+                        # the form the sketch's update takes over the runs,
+                        # which is what `rest_step` dispatched on, as the
+                        # dense chains' spans name theirs
+                        rec["span"]["sketch_form"] = _sketch.sketch_form(
+                            mask.shape[0], gb)
                     pulled = transfer.pull(found)
                     state, key_cols = pulled["state"], pulled["keys"]
             else:  # no rows at all: identity states, no group
@@ -2424,13 +2442,18 @@ class PlanExecutor:
                 agg_form="sorted", groups_out=groups,
                 d2h_bytes=4 + sum(
                     x.nbytes for x in jax.tree.leaves((state, key_cols))))
+            if sketches:  # the sketch state read back, raw (never finalized)
+                rec["span"]["sketch_state_bytes"] = sum(
+                    x.nbytes for name in sketches
+                    for x in jax.tree.leaves(state[name]))
         self._observe_route(src, rec, compile_s0)
         if not parts:
             codes = [np.empty(0, np.int64)] * len(key_specs)
-        elif cards is not None:
-            codes = _gb.split_codes(key_cols[0][:groups], cards)
         else:
-            codes = [k[:groups] for k in key_cols]
+            cols = iter(k[:groups] for k in key_cols)
+            split = iter(_gb.split_codes(next(cols), [c for c in cards if c])
+                         if any(cards) else ())
+            codes = [next(split) if c else next(cols) for c in cards]
         group_cols, out_dtypes, out_dicts = {}, {}, {}
         for code, (g, dt, d) in zip(codes, key_specs):
             out_dtypes[g] = dt

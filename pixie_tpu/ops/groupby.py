@@ -9,7 +9,10 @@ the kernel is traced for (`dispatch_backend`):
 
   * on the TPU, sums and counts over a dense space are one-hot GEMMs on
     the MXU, chunk by chunk over the live rows (`scan_sum`, `live_chunks`):
-    exact for counts and INT64 (8-bit limbs), F64_SUM_RTOL for f64.  Up to
+    exact for counts and INT64 (8-bit limbs), F64_SUM_RTOL for f64 values
+    summed as a hi/lo float32 pair; a FLOAT64 column's sums are fixed
+    point in those limbs, the truncation's residuals in the hi/lo pair
+    (`masked_segment_fsum`).  Up to
     MATMUL_MAX_GROUPS slots the one-hot is flat (a chunk's rows x slots);
     from there to ONEHOT2_MAX_GROUPS it is factored by the id's own two
     digits (`_onehot2_gemm`: both operands narrow, the same sums cell for
@@ -322,12 +325,12 @@ class SortedRuns(NamedTuple):
 def run_sort_keys(keys: list, mask: jax.Array,
                   sentinel: Optional[int] = None) -> tuple:
     """What `sort_order` sorts the rows of a sorted aggregate by: the rows
-    of `mask` first, then `keys` lexicographically.  `sentinel`, for ONE
-    integer key: a value above every live row's key; the masked rows take
-    it and no key of their own orders them last."""
+    of `mask` first, then `keys` lexicographically.  `sentinel`: a value
+    above every live row's first key, an integer; the masked rows take it
+    there and no key of their own orders them last."""
     if sentinel is not None:
-        (k,) = keys
-        return (jnp.where(mask, k, jnp.asarray(sentinel, k.dtype)),)
+        k, *rest = keys
+        return (jnp.where(mask, k, jnp.asarray(sentinel, k.dtype)), *rest)
     return (jnp.logical_not(mask).astype(jnp.int32), *keys)
 
 
@@ -341,7 +344,10 @@ def sort_order(keys: tuple) -> tuple:
     nine 32-bit ones: PERF.md section 6, PR 36's kernels alone): the value
     columns are gathered by the order instead, and the sort that moves the
     runs' results to the front (`run_end_key`) is this same program
-    again."""
+    again.  It grows faster still with the keys it compares: at 1,048,576
+    rows, compiled for a described v5e, 91.0 s for a 32-bit and a 64-bit
+    key against 602.7 s for seven keys, so the sorted aggregate packs its
+    keys of known cardinality into one (`make_keyed_rows_step`)."""
     n = keys[0].shape[0]
     with jax.named_scope("px.sort_runs"):
         out = jax.lax.sort((*keys, jnp.arange(n, dtype=jnp.int32)),
@@ -465,6 +471,49 @@ def masked_segment_sum(values: jax.Array, gid: jax.Array, num_groups: int, mask:
             v.astype(jnp.float32), gid, num_groups, mask,
             precision=jax.lax.Precision.HIGHEST).astype(d)
     return jax.ops.segment_sum(v, gid, num_segments=num_groups)
+
+
+def _pow2(k: jax.Array) -> jax.Array:
+    """2.0**k as a float64 for an int32 k in [-252, 252]: the product of two
+    float32 powers of two, each exact, so no float64 bit arithmetic is
+    needed where the TPU emulates float64."""
+    half = k // 2
+    one = jnp.float32(1.0)
+    return (jnp.ldexp(one, half).astype(jnp.float64)
+            * jnp.ldexp(one, k - half).astype(jnp.float64))
+
+
+@jax.named_scope("px.groupby_fsum")
+def masked_segment_fsum(values: jax.Array, gid: jax.Array, num_groups: int,
+                        mask: jax.Array) -> jax.Array:
+    """Per-group sums of a FLOAT64 column (px.sum and px.mean over one).
+
+    On the TPU's GEMM forms (`agg_form`) every value is split at the one
+    power of two 2^-k that brings the column's largest magnitude under
+    2^(62 - ceil(log2 n)): its multiples of 2^-k, truncated to an INT64,
+    sum exactly in `masked_segment_sum`'s 8-bit limbs, and what truncation
+    left (under 2^-k a row) sums through the hi/lo split.  A group's error
+    is then the hi/lo split's float32 accumulation of its residuals alone:
+    ~6e-8 of the residuals' sum, never more than the hi/lo split of the
+    values would leave (F64_SUM_RTOL), and far less where the group's
+    values stand above 2^-k, whatever the other groups hold.  The scale
+    comes from the largest magnitude as a float32, so the column must lie
+    within float32's range, as the hi/lo split's must; a column holding
+    NaN or an infinity sums to NaN in every group (the GEMM's zeros times
+    it would too).  Elsewhere, and over sorted runs, `masked_segment_sum`'s
+    own forms, which add in float64."""
+    if isinstance(gid, SortedRuns) or not _use_matmul(values.shape[0],
+                                                      num_groups):
+        return masked_segment_sum(values, gid, num_groups, mask)
+    v = jnp.where(mask, values.astype(jnp.float64), 0.0)
+    top = jnp.max(jnp.abs(v))
+    _, e = jnp.frexp(top.astype(jnp.float32))  # top < 2^e
+    k = (62 - (v.shape[0] - 1).bit_length()) - e
+    q = (v * _pow2(k)).astype(jnp.int64)
+    rest = v - q.astype(jnp.float64) * _pow2(-k)
+    s = (masked_segment_sum(q, gid, num_groups, mask).astype(jnp.float64)
+         * _pow2(-k) + masked_segment_sum(rest, gid, num_groups, mask))
+    return jnp.where(jnp.isfinite(top), s, jnp.nan)
 
 
 @jax.named_scope("px.groupby_count")

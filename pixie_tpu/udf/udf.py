@@ -184,9 +184,11 @@ class SumUDA(UDA):
         return jnp.zeros((num_groups,), dtype=_acc_dtype(in_dtype))
 
     def update(self, state, gid, value, mask, num_groups):
-        from pixie_tpu.ops.groupby import masked_segment_sum
+        from pixie_tpu.ops.groupby import masked_segment_fsum, masked_segment_sum
 
-        return state + masked_segment_sum(value.astype(state.dtype), gid, num_groups, mask)
+        add = (masked_segment_fsum if value.dtype == jnp.float64
+               else masked_segment_sum)
+        return state + add(value.astype(state.dtype), gid, num_groups, mask)
 
     def reduce_ops(self):
         return "add"
@@ -210,10 +212,16 @@ class MeanUDA(UDA):
         }
 
     def update(self, state, gid, value, mask, num_groups):
-        from pixie_tpu.ops.groupby import masked_segment_count, masked_segment_sum
+        from pixie_tpu.ops.groupby import (masked_segment_count,
+                                           masked_segment_fsum,
+                                           masked_segment_sum)
 
+        # a FLOAT64 column sums in fixed point on the TPU; a mean of
+        # integers keeps the hi/lo split (masked_segment_sum)
+        add = (masked_segment_fsum if value.dtype == jnp.float64
+               else masked_segment_sum)
         return {
-            "sum": state["sum"] + masked_segment_sum(value.astype(jnp.float64), gid, num_groups, mask),
+            "sum": state["sum"] + add(value.astype(jnp.float64), gid, num_groups, mask),
             "count": state["count"] + masked_segment_count(gid, num_groups, mask),
         }
 
