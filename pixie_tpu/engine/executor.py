@@ -2343,22 +2343,25 @@ class PlanExecutor:
             return (groups, _gb.run_end_key(runs),
                     {"keys": keys_s, "state": state}, after)
 
-        def rest_step(seg, live, values_s, gb):
+        def rest_step(seg, live, values_s, gb, fin):
             """The aggregates that do not reduce runs (sketches, code
-            histograms), over the runs' exact ids into `gb` slots."""
-            return {
-                name: uda.update(
-                    uda.init(gb, in_dt), seg,
-                    None if vi is None else values_s[vi], live, gb)
-                for name, uda, vi, in_dt in rest}
+            histograms), over the runs' exact ids into `gb` slots; those
+            named in `fin` finalized in the same execution (their bins)."""
+            out = {}
+            for name, uda, vi, in_dt in rest:
+                state = uda.update(uda.init(gb, in_dt), seg,
+                                   None if vi is None else values_s[vi],
+                                   live, gb)
+                out[name] = uda.finalize_device(state) if name in fin else state
+            return out
 
         bundle = (kern, rows_step, jax.jit(reduce_step),
-                  jax.jit(rest_step, static_argnums=3) if rest else None,
+                  jax.jit(rest_step, static_argnums=(3, 4)) if rest else None,
                   key_specs, cards, udas, in_types, val_dicts)
         _cache_put(sig, bundle)
         return bundle
 
-    def _sorted_group_reduce(self, op: AggOp):
+    def _sorted_group_reduce(self, op: AggOp, finalize: bool):
         """The aggregate for a group space the dense form does not serve
         (GroupKeyFallback), on whichever arm the router picks for its
         chain.  The chain's rows, keys and value columns stay where the
@@ -2366,14 +2369,18 @@ class PlanExecutor:
         equal keys is reduced by a segmented scan and a second sort finds
         the runs' results (ops/groupby.sort_order, runs_of, run_end_key:
         the analog of the reference's unbounded hash map,
-        exec/agg_node.h:55-140, with no scatter and no dense state).  The
-        host reads the number of groups, then their keys and states and
-        nothing else.
+        exec/agg_node.h:55-140, with no scatter and no dense state).  Where
+        `finalize`, the device-finalizable aggregates (the quantile
+        sketches) are finalized in the execution that updates them, as the
+        dense form's are.  The host reads the number of groups, then their
+        keys, the finalized columns' bins and the other states, and nothing
+        else.
 
         Returns (group_cols, dtypes, dicts, udas, in_types, state_np, G,
         val_dicts): the leaves of state_np hold at least G slots, in the
-        order of group_cols' rows; val_dicts maps dict-valued picker
-        outputs to the dictionary their code-state decodes through.
+        order of group_cols' rows, a finalized column as a `_FinalizedCol`;
+        val_dicts maps dict-valued picker outputs to the dictionary their
+        code-state decodes through.
         """
         self.stats["sorted_agg_fallbacks"] = self.stats.get("sorted_agg_fallbacks", 0) + 1
         head, chain = self._upstream_chain(self.plan.parents(op)[0])
@@ -2394,6 +2401,8 @@ class PlanExecutor:
 
         sketches = [name for name, uda, _vi, _dt in udas
                     if isinstance(uda, (QuantileUDA, QuantilesUDA))]
+        fin = tuple(name for name, uda, _vi, _dt in udas
+                    if finalize and uda.device_finalize)
         with self._timed(
             self._chain_label(head, chain, "sorted_agg"),
             ([head.id] if head.id >= 0 else []) + [o.id for o in chain]
@@ -2425,7 +2434,7 @@ class PlanExecutor:
                     gb = next_pow2(max(groups, 1))
                     found = _take_front(found, front, gb)
                     if rest_step is not None:
-                        found["state"].update(rest_step(*after, gb))
+                        found["state"].update(rest_step(*after, gb, fin))
                     if sketches:
                         # the form the sketch's update takes over the runs,
                         # which is what `rest_step` dispatched on, as the
@@ -2435,6 +2444,7 @@ class PlanExecutor:
                     pulled = transfer.pull(found)
                     state, key_cols = pulled["state"], pulled["keys"]
             else:  # no rows at all: identity states, no group
+                fin = ()
                 state = transfer.pull({name: uda.init(1, in_dt)
                                        for name, uda, _vi, in_dt in udas})
             rec["rows_out"] = groups
@@ -2442,10 +2452,14 @@ class PlanExecutor:
                 agg_form="sorted", groups_out=groups,
                 d2h_bytes=4 + sum(
                     x.nbytes for x in jax.tree.leaves((state, key_cols))))
-            if sketches:  # the sketch state read back, raw (never finalized)
-                rec["span"]["sketch_state_bytes"] = sum(
-                    x.nbytes for name in sketches
-                    for x in jax.tree.leaves(state[name]))
+            if sketches:  # the sketch state read back raw (0 if finalized)
+                rec["span"].update(
+                    sketch_state_bytes=sum(
+                        x.nbytes for name in sketches if name not in fin
+                        for x in jax.tree.leaves(state[name])),
+                    sketch_device_finalized=len(fin))
+            for name in fin:
+                state[name] = _FinalizedCol(state[name])
         self._observe_route(src, rec, compile_s0)
         if not parts:
             codes = [np.empty(0, np.int64)] * len(key_specs)
@@ -2467,7 +2481,7 @@ class PlanExecutor:
 
     def _run_agg_sorted(self, op: AggOp) -> HostBatch:
         (group_cols, in_dtypes, in_dicts, udas, in_types, state_np, G,
-         val_dicts) = self._sorted_group_reduce(op)
+         val_dicts) = self._sorted_group_reduce(op, finalize=True)
         dtypes: dict[str, DT] = {}
         dicts: dict[str, Dictionary] = {}
         cols: dict[str, np.ndarray] = {}
@@ -2477,13 +2491,15 @@ class PlanExecutor:
             if g in in_dicts:
                 dicts[g] = in_dicts[g]
         for out_name, uda, _vn in udas:
-            if getattr(uda, "needs_dict", False):
+            st = state_np[out_name]
+            if isinstance(st, _FinalizedCol):
+                full = uda.finalize_from_device(st.col)
+            elif getattr(uda, "needs_dict", False):
                 # model-fit UDA: finalize over the input DICTIONARY (unique
                 # values + multiplicities), emitting fresh strings
-                full = uda.finalize_dict(state_np[out_name],
-                                         val_dicts[out_name])
+                full = uda.finalize_dict(st, val_dicts[out_name])
             else:
-                full = uda.finalize_host(state_np[out_name])
+                full = uda.finalize_host(st)
             vals = np.asarray(full)[:G]
             out_dt = uda.out_type(in_types[out_name]) if not uda.nullary else uda.out_type(None)
             if out_name in val_dicts and not getattr(uda, "needs_dict", False):
@@ -2502,11 +2518,14 @@ class PlanExecutor:
 
     def _sorted_partial_batch(self, op: AggOp):
         """Distributed partial for the sorted path: group key VALUES + dense
-        state sliced to the seen groups (same wire shape as _partial_agg_batch)."""
+        state sliced to the seen groups (same wire shape as
+        _partial_agg_batch, whose sole producer's rule it follows: the
+        sketches' bins in the place of their state)."""
         from pixie_tpu.parallel.partial import PartialAggBatch
 
         (group_cols, in_dtypes, in_dicts, udas, in_types, state_np, G,
-         val_dicts) = self._sorted_group_reduce(op)
+         val_dicts) = self._sorted_group_reduce(
+            op, finalize=self._finalizes_on_producer(op))
         if val_dicts:
             raise Internal(
                 "dict-valued aggregates must ship rows, not partial state "
@@ -2522,13 +2541,16 @@ class PlanExecutor:
                     key_cols[g] = in_dicts[g].decode_array(group_cols[g])
                 else:
                     key_cols[g] = group_cols[g]
-        states = {
-            out_name: jax.tree.map(lambda x: np.asarray(x)[:G], state_np[out_name])
-            for out_name, _uda, _vn in udas
-        }
+        states, finalized = {}, set()
+        for out_name, _uda, _vn in udas:
+            st = state_np[out_name]
+            if isinstance(st, _FinalizedCol):
+                finalized.add(out_name)
+                st = st.col
+            states[out_name] = jax.tree.map(lambda x: np.asarray(x)[:G], st)
         return PartialAggBatch(
             key_cols=key_cols, key_dtypes=key_dtypes, states=states,
-            in_types=dict(in_types),
+            in_types=dict(in_types), finalized=frozenset(finalized),
         )
 
     def _agg_setup(self, op: AggOp):

@@ -9,7 +9,7 @@ pods, ~41k live (window, edge) groups in the `-5m` range; the served path
 equals the plain reference (benchmarks/references/pgsql_flow.py) at a small
 size on either arm and with the kernels traced for the TPU (the sorted
 aggregate's sketch in the tiles kernel, interpreted), and the sorted
-chain's span names its sketch's form and the state it read back; each
+chain's span names its sketch's form and finalizes it on the device; each
 control fails its own number; a FLOAT64 column's group sums on the TPU are
 fixed point, each group held to its own size; the packed key and the window
 sort as the keys apart; the view's readers compute from hand-made spans and read
@@ -132,9 +132,10 @@ def test_served_path_equals_reference(seed, backend, monkeypatch):
     the router's two arms and with the kernels traced for the TPU.  The
     flow graph's first aggregate is the sorted one: its chain span names
     the form its sketch took over the runs' slots (the tiles kernel past
-    4,096 groups on the TPU, the scatter on XLA-CPU) and the whole
-    [slots, 514] f32 state it read back; the chart's dense chain finalizes
-    its sketch where it runs and reads back none."""
+    4,096 groups on the TPU, the scatter on XLA-CPU); the agent is the
+    channel's sole producer, so that chain finalizes its sketch where it
+    runs and reads back none of the [slots, 514] f32 state, as the chart's
+    dense chain does."""
     route(monkeypatch, backend)
     config = small_config()
     tables = datagen.generate(config, seed)
@@ -160,8 +161,8 @@ def test_served_path_equals_reference(seed, backend, monkeypatch):
             assert len(ref[0]) > 1000 and a["agg_form"] == "sorted"
             slots = 1 << (a["groups_out"] - 1).bit_length()
             assert a["groups_out"] > 4096 and slots == 8192
-            assert (a["sketch_form"], a["sketch_state_bytes"]) == (
-                form, slots * HIST_BYTES)
+            assert (a["sketch_form"], a["sketch_state_bytes"],
+                    a["sketch_device_finalized"]) == (form, 0, 1)
         else:
             assert len(ref[0]) == 120 and a["groups"] > 4096
             assert (a["sketch_form"], a["sketch_state_bytes"]) == (form, 0)

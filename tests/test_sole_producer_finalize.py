@@ -1,12 +1,15 @@
 """A partial aggregate whose channel has one producer finalizes its quantile
 sketches where its chain runs and ships their bins, not [G, 514]
 histograms (parallel/distributed.py marks it `sole_producer`;
-engine/executor.py `_partial_agg_batch`; parallel/partial.py passes the
-finalized column through).  Served through a Broker and its Agents on
-loopback, on small seeded stores of the service map's and the http
-widgets' shapes: the answers are the raw path's string for string, two
-producers of one table still ship raw state, a standing view's state stays
-raw, and the merge refuses to add a finalized column.
+engine/executor.py `_partial_agg_batch`, and `_sorted_partial_batch` for
+the sorted form; parallel/partial.py passes the finalized column through).
+Served through a Broker and its Agents on loopback, on small seeded stores
+of the service map's, the http widgets' and the Postgres flow graph's
+shapes: the answers are the raw path's string for string, in the dense and
+the sorted form, two producers of one table still ship raw state, a
+standing view's state stays raw, and the merge refuses to add a finalized
+column.  A local sorted aggregate finalizes on the device too, to the host
+finalize's strings.
 """
 import contextlib
 import time
@@ -15,6 +18,7 @@ import numpy as np
 import pandas as pd
 import pytest
 
+from tests import test_pgsql_flow_config as pgsql
 from tests.test_service_map_config import datagen, route, small_config, traffic
 
 from pixie_tpu import flags, trace
@@ -27,9 +31,20 @@ from pixie_tpu.parallel.partial import (
     finalize_partial,
     slice_partial,
 )
-from pixie_tpu.plan.plan import AggExpr, AggOp
+from pixie_tpu.plan import (
+    AggExpr,
+    AggOp,
+    Call,
+    Column,
+    MapOp,
+    MemorySinkOp,
+    MemorySourceOp,
+    Plan,
+    lit,
+)
 from pixie_tpu.status import Internal
-from pixie_tpu.types import DataType as DT
+from pixie_tpu.table import TableStore
+from pixie_tpu.types import DataType as DT, Relation
 from pixie_tpu.udf import registry as udf_registry
 
 SEED = 2147483659
@@ -171,6 +186,139 @@ def test_two_producers_of_one_table_ship_raw_state(name, config,
     pd.testing.assert_frame_equal(one.drop(columns=floats),
                                   two.drop(columns=floats), check_exact=True)
     np.testing.assert_allclose(one[floats], two[floats], rtol=1e-12)
+
+
+@pytest.fixture
+def pgsql_config(monkeypatch):
+    monkeypatch.setattr(spmd, "default_mesh", lambda: None)
+    old = mdstate.global_manager()
+    config = pgsql.small_config()
+    datagen.install_metadata(config)
+    yield config
+    mdstate.set_global_manager(old)
+
+
+def flow_graph(config: dict) -> str:
+    """The Postgres flow graph, whose first aggregate (per 10 s window and
+    edge) is the sorted one, over the table's newest half."""
+    return traffic.load_script("pgsql_flow_graph")["text"].replace(
+        "__START_TIME__", str(pgsql.start_ns(config, 301)))
+
+
+def sorted_chains(since: int) -> list:
+    return [s for s in trace.recent(since) if s.service.startswith("pem")
+            and s.attributes.get("agg_form") == "sorted"]
+
+
+@pytest.mark.parametrize("backend", ["cpu", "device", "tpu"])
+def test_sorted_sole_producer_ships_bins_with_the_raw_paths_answers(
+        backend, pgsql_config, monkeypatch):
+    """One agent: the flow graph's sorted aggregate finalizes its sketch in
+    the execution that updates it and reads back none of its state, and
+    the answer is the raw path's to the bit, on either arm and with the
+    kernels traced for the TPU."""
+    route(monkeypatch, backend)
+    tables = datagen.generate(pgsql_config, SEED)
+    store = datagen.load_store(pgsql_config, tables)
+    with served([store]) as (client, _agents):
+        since = time.time_ns()
+        got = frame(client.execute_script(flow_graph(pgsql_config))["out"])
+        (chain,) = sorted_chains(since)
+        with raw_partials(monkeypatch):
+            since = time.time_ns()
+            want = frame(client.execute_script(
+                flow_graph(pgsql_config))["out"])
+            (raw,) = sorted_chains(since)
+    pd.testing.assert_frame_equal(got, want, check_exact=True)
+    assert len(got) > 100
+    slots = 1 << (chain.attributes["groups_out"] - 1).bit_length()
+    assert chain.attributes["groups_out"] > 4096
+    assert (chain.attributes["sketch_state_bytes"],
+            chain.attributes["sketch_device_finalized"]) == (0, 1)
+    assert (raw.attributes["sketch_state_bytes"],
+            raw.attributes["sketch_device_finalized"]) == (
+        slots * pgsql.HIST_BYTES, 0)
+    # the bins ([slots, 5] int32) in the place of the [slots, 514] f32 state
+    assert (raw.attributes["d2h_bytes"] - chain.attributes["d2h_bytes"]
+            == slots * (pgsql.HIST_BYTES - 5 * 4))
+
+
+def test_two_producers_of_one_table_ship_raw_sorted_state(pgsql_config,
+                                                          monkeypatch):
+    """The table's rows split over two agents: neither sorted aggregate
+    finalizes, each reads back its runs' raw state, and the broker's merge
+    gives the one agent's answer (the quantiles to the bit)."""
+    route(monkeypatch, "device")
+    tables = datagen.generate(pgsql_config, SEED)
+    halves = [{t: {c: v[i::2] for c, v in cols.items()}
+               for t, cols in tables.items()} for i in range(2)]
+    with served([datagen.load_store(pgsql_config, tables)]) as (client, _a):
+        one = frame(client.execute_script(flow_graph(pgsql_config))["out"])
+    half = dict(pgsql_config, rows=pgsql_config["rows"] // 2)
+    with served([datagen.load_store(half, h) for h in halves]) as (
+            client, _agents):
+        since = time.time_ns()
+        two = frame(client.execute_script(flow_graph(pgsql_config))["out"])
+        chains = sorted_chains(since)
+    assert {c.service for c in chains} == {"pem0", "pem1"}
+    for c in chains:
+        assert c.attributes["sketch_state_bytes"] > 0, c.attributes
+        assert c.attributes["sketch_device_finalized"] == 0, c.attributes
+    floats = [c for c in one.columns if one[c].dtype == np.float64]
+    pd.testing.assert_frame_equal(one.drop(columns=floats),
+                                  two.drop(columns=floats), check_exact=True)
+    np.testing.assert_allclose(one[floats], two[floats], rtol=1e-12)
+
+
+def _local_sorted_plan() -> Plan:
+    """Quantiles over a computed numeric key, which the dense form does
+    not serve (GroupKeyFallback): the sorted aggregate, on one store."""
+    p = Plan()
+    src = p.add(MemorySourceOp(table="events"))
+    m = p.add(MapOp(exprs=[("k", Call("modulo", (Column("id"), lit(5000)))),
+                          ("v", Column("v"))]), parents=[src])
+    agg = p.add(AggOp(groups=["k"], values=[
+        AggExpr("n", "count", None), AggExpr("q", "quantiles", "v"),
+        AggExpr("p99", "p99", "v")]), parents=[m])
+    p.add(MemorySinkOp(name="out"), parents=[agg])
+    return p
+
+
+@pytest.mark.parametrize("backend", ["cpu", "device", "tpu"])
+def test_local_sorted_aggregate_finalizes_on_the_device(backend,
+                                                        monkeypatch):
+    """A local (non-partial) sorted aggregate finalizes its two sketch
+    columns where its chain runs and reads back none of their state; its
+    `px.quantiles` strings and p99 values are the host finalize's."""
+    route(monkeypatch, backend)
+    rng = np.random.default_rng(11)
+    n = 40_000
+    ts = TableStore()
+    ts.create("events", Relation.of(("time_", DT.TIME64NS), ("id", DT.INT64),
+                                    ("v", DT.FLOAT64)),
+              batch_rows=1 << 15).write({
+        "time_": np.arange(n, dtype=np.int64),
+        "id": rng.permutation(n),
+        "v": rng.lognormal(0.0, 2.0, n)})
+    def run() -> tuple:
+        ex = PlanExecutor(_local_sorted_plan(), ts)
+        out = frame(ex.run()["out"])
+        assert ex.stats["sorted_agg_fallbacks"] == 1
+        (span,) = [r["span"] for r in ex.op_stats
+                   if r.get("span", {}).get("agg_form") == "sorted"]
+        return out, (span["sketch_state_bytes"],
+                     span["sketch_device_finalized"])
+
+    got, chain = run()
+    reduce = PlanExecutor._sorted_group_reduce
+    monkeypatch.setattr(
+        PlanExecutor, "_sorted_group_reduce",
+        lambda self, op, finalize: reduce(self, op, finalize=False))
+    want, host = run()
+    pd.testing.assert_frame_equal(got, want, check_exact=True)
+    assert len(got) == 5000 and got["q"].str.startswith('{"p01": ').all()
+    assert chain == (0, 2)
+    assert host == (2 * 8192 * pgsql.HIST_BYTES, 0)
 
 
 VIEW_QUERY = """
